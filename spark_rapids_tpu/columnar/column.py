@@ -339,8 +339,8 @@ class DeviceColumn:
                            char_capacity: Optional[int] = None):
         """Device-layout numpy buffers (constructor order), upload-ready —
         kept separate from the upload so a whole batch's buffers can ride
-        ONE jax.device_put (per-buffer uploads each pay a round trip on
-        remote attachments)."""
+        ONE jax.device_put (per-buffer uploads each pay their own
+        dispatch)."""
         n = len(values)
         assert n <= capacity, (n, capacity)
         if validity is None:
@@ -391,8 +391,7 @@ class DeviceColumn:
     def device_views(self, num_rows: int):
         """The device arrays a host copy needs (leading-rows slices).
         Kept lazy so a whole batch's views can ride ONE jax.device_get —
-        per-buffer fetches each pay a full round trip on remote
-        attachments. Codes-only columns ship just codes+validity and
+        per-buffer fetches each pay a full round trip. Codes-only columns ship just codes+validity and
         decode through the static dictionary on the host; slab columns
         ship the fixed-stride words + lens and unpack host-side (numpy) —
         neither ever runs a device char gather for the fetch."""

@@ -122,9 +122,8 @@ ADAPTIVE_CAPACITY = register(
     "ONE deferred fetch at query end verifies every speculated capacity "
     "covered its actual size and the query transparently re-executes "
     "without speculation on any miss — correctness never depends on the "
-    "cache. On a high-latency host-device link (tunneled attachment: "
-    "100-250ms per round trip) this removes the dominant steady-state "
-    "cost of join-heavy plans. Also the verification substrate of "
+    "cache. Each skipped sync is one blocking host-device round trip "
+    "per join per execution. Also the verification substrate of "
     "spark.rapids.sql.agg.denseKeys, which this conf gates.")
 
 AGG_DENSE_KEYS = register(
@@ -278,8 +277,8 @@ COLLECT_FUSED_FETCH_BYTES = register(
     "collect() fetches results in one device->host round trip (row counts "
     "and full-capacity buffers together) when the padded result size is "
     "under this threshold; larger results use two round trips (counts, "
-    "then exact-length buffers). Tunes the latency/bandwidth trade on "
-    "remote device attachments.")
+    "then exact-length buffers). Trades one round trip's latency "
+    "against fetching padding.")
 
 # --- op enable/disable incl. incompat (ref RapidsConf.scala:339-430) -------
 INCOMPATIBLE_OPS = register(
@@ -320,7 +319,7 @@ ENABLE_CAST_STRING_TO_DATE = register(
     "spark.rapids.sql.castStringToDate.enabled", _to_bool, False,
     "Enable casting strings to dates on the TPU (yyyy-MM-dd prefix form, "
     "roundtrip-validated calendar). Disabled by default like the "
-    "reference's string-to-timestamp taxonomy.")
+    "reference's string-to-timestamp classification.")
 
 # --- file formats (ref RapidsConf.scala:433-474) ---------------------------
 PARQUET_ENABLED = register(
@@ -745,7 +744,7 @@ TRACE_ENABLED = register(
     "spill tier transitions, semaphore waits, kernel-cache events) during "
     "query execution. Implied by a non-empty spark.rapids.tpu.trace.path. "
     "The NVTX-range analogue (NvtxWithMetrics.scala:17-44); see "
-    "docs/observability.md for the span taxonomy.")
+    "docs/observability.md for the span classification.")
 
 TRACE_PATH = register(
     "spark.rapids.tpu.trace.path", str, "",
@@ -1017,27 +1016,20 @@ COMPILE_SHAPE_BUCKETS_GROWTH = register(
 
 COMPILE_SHARED_CACHE_DIR = register(
     "spark.rapids.tpu.compile.sharedCache.dir", str, "",
-    "Directory of the CROSS-PROCESS shared persistent compile cache "
-    "(obs/compilecache.py SharedCompileCache). When set: jax's "
-    "persistent executable cache is pointed at <dir>/xla (explicitly "
-    "including the CPU backend — the opt-in overrides the "
-    "accelerated-only default, safe because the versioned manifest keys "
-    "carry the jax version + backend + machine so a foreign executable "
-    "is never attributed as warm), and every backend compile appends a "
-    "file-locked record to <dir>/manifest.jsonl so a fleet of workers "
-    "compiles each kernel once per CLUSTER, not once per process. "
-    "Hit/miss/steal/write counters surface as srt_sharedcache_* "
-    "Prometheus series ('steal' = this process reused an executable "
-    "another process compiled). Empty (default) disables — the "
-    "per-process behavior is unchanged.")
-
-COMPILE_SHARED_CACHE_MIN_S = register(
-    "spark.rapids.tpu.compile.sharedCache.minCompileSeconds", float, 0.0,
-    "Minimum compile seconds before an executable is persisted into the "
-    "shared cache (jax_persistent_cache_min_compile_time_secs while the "
-    "shared cache is enabled). 0 persists everything — right for "
-    "cluster-wide reuse where even a 50ms compile times N workers x M "
-    "shapes adds up.", validator=_non_negative)
+    "Directory of the CROSS-PROCESS compile manifest "
+    "(obs/compilecache.py SharedCompileCache). When set, every backend "
+    "compile appends a file-locked record to <dir>/manifest.jsonl "
+    "(versioned keys carry the jax version + backend + machine so a "
+    "foreign executable is never attributed as warm), the census of "
+    "what a fleet of workers has compiled. The executables themselves "
+    "live in jax's persistent cache, whose directory is the process "
+    "environment's (JAX_COMPILATION_CACHE_DIR, else <checkout>/"
+    ".jax_cache on an accelerator) and is never re-pointed by this "
+    "conf: workers that share that directory compile each kernel once "
+    "per CLUSTER, not once per process. Hit/miss/steal/write counters "
+    "surface as srt_sharedcache_* Prometheus series ('steal' = this "
+    "process reused an executable another process compiled). Empty "
+    "(default) disables — the per-process behavior is unchanged.")
 
 COMPILE_AOT_MANIFEST = register(
     "spark.rapids.tpu.compile.aot.manifest", str, "",
@@ -1387,7 +1379,7 @@ class TpuConf:
 
     def is_operator_enabled(self, key: str, incompat: bool = False,
                             disabled_by_default: bool = False) -> bool:
-        """Per-operator enable check with the incompat/disabled taxonomy
+        """Per-operator enable check with the incompat/disabled classification
         (reference: GpuOverrides.scala:122-130, RapidsMeta.scala:185-200)."""
         if key in self._settings:
             return self.get_bool(key, True)

@@ -43,8 +43,8 @@ def coalesce_iter(batches, goal: CoalesceGoal, schema: Schema,
     coalescing loop, shared by TpuCoalesceBatchesExec and the fused
     stage's input re-batching (exec/stagecompiler/fusedexec.py).
 
-    Capacity-based accounting: an exact count would cost a device->host
-    scalar sync per batch (~hundreds of ms through remote attachments);
+    Capacity-based accounting: an exact count would cost a blocking
+    device->host scalar sync per batch;
     the bucketed capacity over-estimates by at most 2x, which only makes
     coalesced outputs slightly smaller than the goal.
 

@@ -5,7 +5,7 @@ there, supported here via the fused device kernel).
 The supported generator is ``explode(split(strcol, delim))`` — with a
 single-byte literal delimiter it runs fused on device; anything else
 (multi-byte delimiters, regex split) stays on the CPU with a readable tag
-reason, the reference's fallback taxonomy.
+reason, the reference's fallback classification.
 """
 
 from __future__ import annotations

@@ -1,9 +1,9 @@
 """Whole-stage fusion compiler (ROADMAP item 2).
 
 The converted physical plan dispatches one jitted kernel per operator per
-batch; on a high-latency attachment the python dispatch gap between tiny
-kernels — not device time — is what keeps 12 of 44 bench queries below
-1x (PR 6's device/transfer/dispatch breakdown names it per operator).
+batch, and for chains of tiny kernels the python dispatch gap between
+them — not device time — can dominate (PR 6's device/transfer/dispatch
+breakdown names it per operator).
 This subsystem collapses each fusible pipeline into ONE compiled
 program:
 

@@ -859,6 +859,18 @@ class TpuSortExec(TpuExec):
         return [make(p) for p in child_parts]
 
 
+def _colocated(scalar, batch: DeviceBatch):
+    """``scalar`` on ``batch``'s device. Mesh execution commits each
+    partition's batches to its shard device, so a device scalar carried
+    from one partition's kernel into the next partition's (the limit's
+    running count) must follow the batch or jit rejects the device mix."""
+    if isinstance(scalar, jax.Array) and batch.columns:
+        dev = batch.columns[0].validity.device
+        if scalar.device != dev:
+            return jax.device_put(scalar, dev)
+    return scalar
+
+
 class TpuLocalLimitExec(TpuExec):
     """reference: GpuLocalLimitExec / GpuGlobalLimitExec (limit.scala).
 
@@ -866,8 +878,7 @@ class TpuLocalLimitExec(TpuExec):
     slice-and-decrement kernel per batch — the per-batch row-count readback
     the round-1 version paid (a full device->host round trip each) is gone.
     Later batches past the limit yield empty slices instead of breaking
-    the loop; on a high-latency attachment the extra enqueues are far
-    cheaper than one sync."""
+    the loop: the extra enqueues are cheaper than one sync."""
 
     def __init__(self, child: PhysicalPlan, limit: int):
         super().__init__([child])
@@ -895,7 +906,8 @@ class TpuLocalLimitExec(TpuExec):
                 for i, batch in enumerate(part()):
                     if (i + 1) % 8 == 0 and int(remaining) <= 0:
                         break
-                    out, remaining = self._kernel(batch, remaining)
+                    out, remaining = self._kernel(
+                        batch, _colocated(remaining, batch))
                     yield out
             return run
         return [make(p) for p in child_parts]
@@ -922,7 +934,8 @@ class TpuCollectLimitExec(TpuLocalLimitExec):
                     if (i + 1) % 8 == 0 and int(remaining) <= 0:
                         return
                     i += 1
-                    out, remaining = self._kernel(batch, remaining)
+                    out, remaining = self._kernel(
+                        batch, _colocated(remaining, batch))
                     yield out
         return [run]
 

@@ -25,14 +25,21 @@ class TpuDeviceManager:
     def __init__(self, conf):
         self.conf = conf
         devices = jax.devices()
-        # backend is resolved now: safe point to decide the persistent
-        # compile cache (XLA:CPU AOT reload has SIGILL risk, so CPU-only
-        # processes keep it off — see package __init__)
-        from spark_rapids_tpu import enable_persistent_cache_if_accelerated
-        enable_persistent_cache_if_accelerated()
         self.device = devices[0]
+        # what the backend resolved to, on record: a run that meant the
+        # chip and got XLA:CPU must be able to see that (obs/monitor.py
+        # status, bench.py, chip_smoke.py)
+        self.platform = self.device.platform
+        self.device_kind = self.device.device_kind
         self.num_local_devices = len(devices)
-        self.hbm_total = self._probe_hbm_bytes()
+        # backend is resolved now: safe point to decide the persistent
+        # compile cache (see package __init__)
+        from spark_rapids_tpu import configure_compile_cache
+        self.compile_cache_dir = configure_compile_cache()
+        self.hbm_per_device = {d: self._probe_hbm_bytes(d) for d in devices}
+        # the budget meter is global (one number for every device), so it
+        # is sized for one chip: the smallest
+        self.hbm_total = min(self.hbm_per_device.values())
         self.hbm_budget = int(self.hbm_total * conf.alloc_fraction)
         self._allocated = 0
         self._alloc_lock = threading.Lock()
@@ -98,14 +105,17 @@ class TpuDeviceManager:
             return None
         return dev
 
-    def _probe_hbm_bytes(self) -> int:
-        try:
-            stats = self.device.memory_stats()
-            if stats and "bytes_limit" in stats:
-                return int(stats["bytes_limit"])
-        except Exception:
-            pass
-        # CPU-mesh tests and backends without stats: assume 16 GiB/chip
+    @staticmethod
+    def _probe_hbm_bytes(device) -> int:
+        stats = device.memory_stats()
+        if stats and "bytes_limit" in stats:
+            return int(stats["bytes_limit"])
+        if device.platform == "tpu":
+            raise RuntimeError(
+                f"{device} reports no memory_stats()['bytes_limit']; "
+                "refusing to assume an HBM size on a TPU")
+        # XLA:CPU (the test mesh) reports no memory stats: meter against
+        # a nominal 16 GiB per device
         return 16 << 30
 
     # --- budget accounting (the Rmm pool + event-handler contract,

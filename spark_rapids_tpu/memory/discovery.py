@@ -5,17 +5,25 @@ and claims one per executor in PROCESS_EXCLUSIVE mode so co-located
 executors never share a device
 (sql-plugin/.../ExclusiveModeGpuDiscoveryPlugin.scala:42+ probing via
 setGpuDeviceAndAcquire, GpuDeviceManager.scala:72-96). The TPU analogue:
-enumerate the PJRT devices of this host and claim one with an exclusive
-OS file lock — two executor processes racing for the same chip resolve
-through ``flock``, exactly the role CUDA's exclusive-process compute mode
-plays in the reference.
+enumerate the chips of this host and claim one with an exclusive OS file
+lock — two executor processes racing for the same chip resolve through
+``flock``, exactly the role CUDA's exclusive-process compute mode plays
+in the reference.
+
+A chip belongs to one process: the first ``jax.devices()`` in a process
+takes every chip it can see, and a second process then fails to
+initialise. So everything here runs BEFORE backend initialisation —
+chips are counted from PCI, and a process is narrowed to its one chip
+through environment variables libtpu reads at start-up
+(``one_chip_env``), set by whoever launches it.
 """
 
 from __future__ import annotations
 
+import glob
 import os
 import tempfile
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 
 class DeviceClaim:
@@ -63,9 +71,43 @@ def _try_claim(ordinal: int) -> Optional[DeviceClaim]:
     return DeviceClaim(ordinal, path, fd)
 
 
-def visible_device_ordinals() -> List[int]:
-    import jax
-    return [d.id for d in jax.local_devices()]
+def local_chip_ordinals() -> List[int]:
+    """Ordinals of the TPU chips this process may use or hand to
+    children, learned without initialising a backend. The PCI scan jax
+    itself uses says how many chips the host has; the device nodes
+    (``/dev/accel*``, or ``/dev/vfio/<group>`` from v5e on) say how many
+    this process can open — a sandbox may be given fewer than the host
+    holds — so the count is the smaller. TPU_VISIBLE_CHIPS wins when this
+    process was itself narrowed. Empty when JAX_PLATFORMS keeps the
+    process off the TPU or there is no chip."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return []
+    visible = os.environ.get("TPU_VISIBLE_CHIPS", "")
+    if visible:
+        return [int(c) for c in visible.split(",")]
+    from jax._src import hardware_utils
+    on_pci, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    nodes = (glob.glob("/dev/accel[0-9]*")
+             or glob.glob("/dev/vfio/[0-9]*"))
+    return list(range(min(on_pci, len(nodes))))
+
+
+def one_chip_env(ordinal: int) -> Dict[str, str]:
+    """Environment that narrows a child process to chip ``ordinal`` as a
+    one-chip topology of its own (the variables jax's multi-process test
+    launcher sets, jax/_src/test_multiprocess.py). Must be in the
+    child's environment before it imports jax."""
+    port = 8476 + ordinal
+    return {
+        "TPU_VISIBLE_CHIPS": str(ordinal),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "TPU_PROCESS_PORT": str(port),
+        "CLOUD_TPU_TASK_ID": "0",
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
 
 
 def discover_and_claim(ordinals: Optional[List[int]] = None) -> DeviceClaim:
@@ -73,7 +115,7 @@ def discover_and_claim(ordinals: Optional[List[int]] = None) -> DeviceClaim:
     held by another process (the reference's executor init likewise fails
     fast rather than oversubscribing, Plugin.scala:129-136)."""
     if ordinals is None:
-        ordinals = visible_device_ordinals()
+        ordinals = local_chip_ordinals()
     for o in ordinals:
         claim = _try_claim(o)
         if claim is not None:

@@ -12,7 +12,7 @@ added as the operator surface grows; each is a function
 from __future__ import annotations
 
 import datetime
-from typing import Callable, Dict
+from typing import Callable, Dict, Optional
 
 from spark_rapids_tpu.sql import functions as F
 
@@ -453,31 +453,21 @@ class TpchTables:
     """Load or generate the TPC-H tables as DataFrames."""
 
     @staticmethod
-    def generate(session, sf: float, num_partitions: int = 4):
+    def generate(session, sf: float, num_partitions: int = 4,
+                 seed: Optional[int] = None):
         from spark_rapids_tpu.models import tpch_data as gen
-        return {
-            "lineitem": session.create_dataframe(gen.gen_lineitem(sf),
-                                                 num_partitions),
-            "orders": session.create_dataframe(gen.gen_orders(sf),
-                                               num_partitions),
-            "customer": session.create_dataframe(gen.gen_customer(sf),
-                                                 num_partitions),
-            "supplier": session.create_dataframe(gen.gen_supplier(sf),
-                                                 num_partitions),
-            "part": session.create_dataframe(gen.gen_part(sf),
-                                             num_partitions),
-            "partsupp": session.create_dataframe(gen.gen_partsupp(sf),
-                                                 num_partitions),
-            "nation": session.create_dataframe(gen.gen_nation(), 1),
-            "region": session.create_dataframe(gen.gen_region(), 1),
-        }
+        return {name: session.create_dataframe(
+                    gen.gen_table(name, sf, seed),
+                    1 if name in ("nation", "region") else num_partitions)
+                for name in gen.TABLE_NAMES}
 
     @staticmethod
     def from_parquet(session, path: str):
         import os
+
+        from spark_rapids_tpu.models.tpch_data import TABLE_NAMES
         out = {}
-        for name in ("lineitem", "orders", "customer", "supplier", "part",
-                     "nation", "region"):
+        for name in TABLE_NAMES:
             f = os.path.join(path, f"{name}.parquet")
             if os.path.exists(f):
                 out[name] = session.read.parquet(f)

@@ -10,6 +10,8 @@ range, A/N/R return flags), not dbgen's exact streams.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import numpy as np
 import pandas as pd
 
@@ -204,18 +206,38 @@ ALL_TABLES = {
 }
 
 
-def write_parquet(out_dir: str, sf: float, tables=None) -> None:
+TABLE_NAMES = tuple(ALL_TABLES) + ("nation", "region")
+
+
+def gen_table(name: str, sf: float,
+              seed: Optional[int] = None) -> pd.DataFrame:
+    """One table by name. ``seed`` is a run's seed, from which every
+    table draws its own stream; None keeps each generator's fixed
+    default (the data the tests pin)."""
+    if name == "nation":
+        return gen_nation()
+    if name == "region":
+        return gen_region()
+    if seed is None:
+        return ALL_TABLES[name](sf)
+    return ALL_TABLES[name](
+        sf, seed=seed * len(ALL_TABLES) + list(ALL_TABLES).index(name))
+
+
+def write_parquet(out_dir: str, sf: float, tables=None,
+                  seed: Optional[int] = None, row_groups: int = 1) -> None:
+    """Write tables as Snappy Parquet (pyarrow's default codec), one
+    file each. ``row_groups``: how many row groups a table is cut into
+    (the scan plans one partition per row group), never below 64Ki rows
+    a group — small tables stay whole."""
     import os
     import pyarrow as pa
     import pyarrow.parquet as pq
     os.makedirs(out_dir, exist_ok=True)
-    names = tables or list(ALL_TABLES) + ["nation", "region"]
+    names = tables or TABLE_NAMES
     for name in names:
-        if name == "nation":
-            df = gen_nation()
-        elif name == "region":
-            df = gen_region()
-        else:
-            df = ALL_TABLES[name](sf)
-        pq.write_table(pa.Table.from_pandas(df, preserve_index=False),
-                       os.path.join(out_dir, f"{name}.parquet"))
+        df = gen_table(name, sf, seed)
+        pq.write_table(
+            pa.Table.from_pandas(df, preserve_index=False),
+            os.path.join(out_dir, f"{name}.parquet"),
+            row_group_size=max(-(-len(df) // row_groups), 1 << 16))

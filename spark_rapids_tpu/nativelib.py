@@ -3,9 +3,11 @@
 The reference framework consumes its native components (RMM pool, pinned
 host pool, AddressSpaceAllocator, HashedPriorityQueue, JCudfSerialization)
 through JNI; this module is the equivalent seam: the shared library is
-built from C++ with `make -C native` (invoked lazily on first import when
-missing), loaded over ctypes, and every consumer carries a pure-Python
-fallback so an unbuilt tree still works.
+built from C++ with `make -C native` (invoked lazily on first use),
+loaded over ctypes, and every consumer carries a pure-Python fallback so
+a host without a C++ toolchain still works — but says so: a failed
+build or load is logged with the compiler's output and kept in
+``load_error()``.
 
 Set SPARK_RAPIDS_TPU_DISABLE_NATIVE=1 to force the Python fallbacks.
 """
@@ -13,6 +15,7 @@ Set SPARK_RAPIDS_TPU_DISABLE_NATIVE=1 to force the Python fallbacks.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -25,15 +28,21 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libtpunative.so")
 _lib = None
 _lib_lock = threading.Lock()
 _load_attempted = False
+_load_error: Optional[str] = None
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
+    """Run the (dependency-tracked) native build; None on success, else
+    what went wrong, with make's output."""
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR, "-s"], check=True,
-                       capture_output=True, timeout=120)
-        return os.path.exists(_LIB_PATH)
-    except Exception:  # noqa: BLE001 - any failure means "use fallback"
-        return False
+        proc = subprocess.run(["make", "-C", _NATIVE_DIR, "-s"],
+                              capture_output=True, text=True, timeout=120)
+    except (OSError, subprocess.SubprocessError) as e:
+        return f"make -C {_NATIVE_DIR}: {type(e).__name__}: {e}"
+    if proc.returncode != 0:
+        return (f"make -C {_NATIVE_DIR} exited {proc.returncode}:\n"
+                f"{proc.stdout}{proc.stderr}")
+    return None
 
 
 def _declare(lib) -> None:
@@ -91,7 +100,7 @@ def _declare(lib) -> None:
 
 def get_lib():
     """The loaded native library, or None when unavailable/disabled."""
-    global _lib, _load_attempted
+    global _lib, _load_attempted, _load_error
     if _load_attempted:
         return _lib
     with _lib_lock:
@@ -102,19 +111,29 @@ def get_lib():
             return None
         # make is dependency-tracked: a fresh .so is a no-op, a stale one
         # (older sources) is rebuilt so symbol lookups can't go stale
-        if not _build() and not os.path.exists(_LIB_PATH):
-            return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            _declare(lib)
-            _lib = lib
-        except (OSError, AttributeError):
-            _lib = None
+        _load_error = _build()
+        if _load_error is None:
+            try:
+                lib = ctypes.CDLL(_LIB_PATH)
+                _declare(lib)
+                _lib = lib
+            except (OSError, AttributeError) as e:
+                _load_error = f"loading {_LIB_PATH}: {e}"
+        if _load_error is not None:
+            logging.getLogger(__name__).warning(
+                "native runtime unavailable, using the Python "
+                "fallbacks: %s", _load_error)
     return _lib
 
 
 def native_available() -> bool:
     return get_lib() is not None
+
+
+def load_error() -> Optional[str]:
+    """Why the native library is absent (build or load failure), or None
+    when it loaded, was disabled by the environment, or was not tried."""
+    return _load_error
 
 
 class HostArena:

@@ -8,7 +8,7 @@
   * ``obs.profile`` — per-query plan-tree profile reports
     (``session.profile_report()`` / ``session.profile_json()``).
 
-See docs/observability.md for the span taxonomy and config keys.
+See docs/observability.md for the span names and config keys.
 """
 
 from spark_rapids_tpu.obs.metrics import (  # noqa: F401

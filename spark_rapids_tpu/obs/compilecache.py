@@ -1,11 +1,10 @@
 """Persistent-compile-cache attribution: jax monitoring -> metrics registry.
 
-Round-5 grading burned 559.5s of first-run warmup in XLA compiles with no
-first-class attribution — warmup cost hid inside per-query wall time. jax
-emits monitoring events for both the backend compiler and the persistent
-executable cache (enabled on accelerated backends by
-``enable_persistent_cache_if_accelerated``, package __init__); this module
-mirrors them into the process-wide registry (obs/metrics.py REGISTRY) so
+First-run warmup is mostly XLA compiles, and without first-class
+attribution that cost hides inside per-query wall time. jax emits
+monitoring events for both the backend compiler and the persistent
+executable cache (placed by ``configure_compile_cache``, package
+__init__); this module mirrors them into the process-wide registry (obs/metrics.py REGISTRY) so
 warmup shows up per query in ``session.profile_report()`` (the
 ``compileCache`` summary section, obs/profile.py) and in
 ``tools/trace_summary.py``'s warmup-attribution line:
@@ -100,14 +99,12 @@ class SharedCompileCache:
 
     Two halves:
 
-      * the EXECUTABLES live in jax's persistent compilation cache,
-        pointed at ``<dir>/xla`` — the mechanism that actually lets a
-        fresh process skip the XLA compile. The shared-cache opt-in
-        extends it to the CPU backend (the package default is
-        accelerated-only, see ``enable_persistent_cache_if_accelerated``)
-        because the explicit dir conveys same-fleet intent, and the
-        manifest keys below carry the jax version + backend + machine so
-        accounting never attributes a foreign build as warm;
+      * the EXECUTABLES live in jax's persistent compilation cache — the
+        mechanism that actually lets a fresh process skip the XLA
+        compile. Its directory is the process's own
+        (JAX_COMPILATION_CACHE_DIR, else the package default — see
+        ``configure_compile_cache``); this class never re-points it, so
+        a fleet shares executables by sharing that environment;
       * the MANIFEST (``<dir>/manifest.jsonl``) is the durable fleet
         record: one file-locked appended line per backend compile that
         actually ran, carrying the versioned key, kernel identity, aval
@@ -141,66 +138,32 @@ class SharedCompileCache:
         # replica. Independent of the shared-cache enabled state: a
         # fleet can share warm shapes without sharing an XLA cache dir.
         self.warm_manifest_path = ""
-        # jax cache dir in force before we pointed it at the shared
-        # volume, restored when the shared cache is conf'd back off
-        self._prev_jax_dir = None
-        self._jax_dir_overridden = False
 
     # -- configuration ------------------------------------------------------
     def configure_from_conf(self, conf) -> bool:
         d = str(conf.get("spark.rapids.tpu.compile.sharedCache.dir", "")
                 or "")
-        min_s = float(conf.get(
-            "spark.rapids.tpu.compile.sharedCache.minCompileSeconds",
-            0.0))
         self.configure_warm_manifest(
             str(conf.get("spark.rapids.tpu.fleet.warmManifest", "")
                 or ""))
-        return self.configure(d, min_compile_seconds=min_s)
+        return self.configure(d)
 
     def configure_warm_manifest(self, path: str) -> None:
         """Point (or un-point) the warm-state sidecar at ``path``."""
         with self._lock:
             self.warm_manifest_path = path or ""
 
-    def configure(self, directory: str,
-                  min_compile_seconds: float = 0.0) -> bool:
+    def configure(self, directory: str) -> bool:
         with self._lock:
             if not directory:
-                if self._jax_dir_overridden:
-                    # conf'd back off: restore the per-process policy
-                    try:
-                        import jax
-                        jax.config.update("jax_compilation_cache_dir",
-                                          self._prev_jax_dir)
-                    except Exception:  # noqa: BLE001
-                        pass
-                    self._jax_dir_overridden = False
                 self.enabled = False
                 self.directory = ""
                 return False
             if self.enabled and directory == self.directory:
                 return True
             try:
-                import jax
-                xla_dir = os.path.join(directory, "xla")
-                os.makedirs(xla_dir, exist_ok=True)
-                if not self._jax_dir_overridden:
-                    self._prev_jax_dir = \
-                        jax.config.jax_compilation_cache_dir
-                    self._jax_dir_overridden = True
-                jax.config.update("jax_compilation_cache_dir", xla_dir)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs",
-                    float(min_compile_seconds))
-                try:
-                    # persist tiny executables too: a 50ms kernel x N
-                    # workers x M shapes is exactly the warm-up tax
-                    jax.config.update(
-                        "jax_persistent_cache_min_entry_size_bytes", -1)
-                except Exception:  # noqa: BLE001 — knob absent on old jax
-                    pass
-            except Exception:  # noqa: BLE001 — shared volume problems
+                os.makedirs(directory, exist_ok=True)
+            except OSError:  # shared volume problems: per-process behavior
                 self.enabled = False
                 return False
             self.directory = directory

@@ -9,7 +9,7 @@ journal lands as line-delimited JSON a tool can stream
 (tools/qualification.py consumes it; tools/trace_summary.py summarizes
 it).
 
-Event taxonomy (one JSON object per line; every event carries ``kind``,
+Event kinds (one JSON object per line; every event carries ``kind``,
 ``ts`` epoch seconds, ``seq``, and — between queryStart/queryEnd —
 ``query``):
 

@@ -169,14 +169,18 @@ def status_snapshot() -> Dict[str, Any]:
     if s is not None:
         dm = s.device_manager
         out["device"] = {
-            "platform": str(getattr(dm.device, "platform", "?")),
+            "platform": dm.platform,
+            "deviceKind": dm.device_kind,
             "localDevices": dm.num_local_devices,
+            "compileCacheDir": dm.compile_cache_dir,
             "mesh": str(dict(s.mesh.shape)) if getattr(s, "mesh", None)
             is not None else None,
         }
         cat = s.buffer_catalog
         out["memory"] = {
             "hbmTotalBytes": dm.hbm_total,
+            "hbmBytesPerDevice": {str(d): b for d, b
+                                  in dm.hbm_per_device.items()},
             "hbmBudgetBytes": dm.hbm_budget,
             "allocatedBytes": dm.allocated,
             "deviceStoreBytes": cat.device_store.total_size,

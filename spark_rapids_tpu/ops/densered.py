@@ -1,10 +1,10 @@
 """Dense one-hot matmul reductions: every count and sum of an aggregation
 in ONE MXU pass.
 
-Why: on this TPU attachment every indexed op (gather/scatter/segment_*)
-runs at ~5M elements/s — a q1-shaped aggregation made ~17 such passes per
-batch (~2.3 s at 750k rows). Dense elementwise ops and matmuls run at
-hardware speed. This module re-expresses per-slot reductions as
+Why: XLA:TPU lowers 1-D indexed ops (gather/scatter/segment_*) to
+element-at-a-time loops far below memory speed, and a q1-shaped
+aggregation makes ~17 such passes per batch, while dense elementwise ops
+and matmuls run at hardware speed. This module re-expresses per-slot reductions as
 
     totals[t, k] = sum_n onehot(slot[n] == t) * limbs[n, k]
 

@@ -1,9 +1,12 @@
 """Arithmetic float64 -> IEEE-754 bits (no 64-bit float bitcast).
 
-The TPU AOT compile helper on this attachment rejects any program that
-bitcasts a float64 operand (``f64.view(uint64)``, ``bitcast_convert_type``
-to uint64 *or* 2x uint32, ``frexp``, ``ldexp`` all fail with a compiler
-crash), while 64-bit integer bitcasts and arithmetic compile fine. Sort key
+XLA:TPU has no 64-bit element types: it rewrites them away, and that
+rewrite is not implemented for a bitcast of a float64 operand
+(``bitcast_convert_type`` f64 -> u64 fails with "UNIMPLEMENTED: While
+rewriting computation to not contain X64 element types ... bitcast-convert",
+re-measured on v5e with libtpu 0.0.34; ``f64.view(uint64)``, the 2x uint32
+form, ``frexp`` and ``ldexp`` went the same way when this module was
+written), while 64-bit integer bitcasts and arithmetic compile fine. Sort key
 images (ops/sortops.py) and row hashes (ops/hashing.py) need the exact IEEE
 bit pattern of float columns, so this module reconstructs it with exact
 floating-point arithmetic only:

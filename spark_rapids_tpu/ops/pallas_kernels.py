@@ -19,15 +19,16 @@ lower-triangular matmul accumulates across rows. Counts <= 2048 are exact
 in float32. Off-TPU the jnp twin (two fused cumsums) provides identical
 results.
 
-Toggle: SPARK_RAPIDS_TPU_PALLAS=0 forces the jnp path; =interpret runs
-the kernel in interpreter mode (CPU CI of the kernel itself).
+Toggle: SPARK_RAPIDS_TPU_PALLAS (see ``_mode``): unset/0 runs the jnp
+twins, =1 the Mosaic-compiled kernels on a TPU backend, =interpret the
+kernel bodies in the Pallas interpreter (CPU CI of the kernels).
 """
 
 from __future__ import annotations
 
 import functools
 import os
-from typing import Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,14 +40,17 @@ _BLK = _ROWS * _LANES  # 2048 elements per grid step
 
 
 def _mode() -> str:
-    """auto = the XLA cumsum path. Re-verified round 2: this attachment's
-    chipless AOT compile helper (TpuAotCompiler via remote_compile)
-    rejects Mosaic programs outright — even a standalone
-    compact_permutation probe fails with a compile-helper crash, same
-    class of failure as the float64-bitcast rejection (ops/floatbits.py).
-    The pallas path therefore stays explicit opt-in
-    (SPARK_RAPIDS_TPU_PALLAS=1) for directly attached chips, where Mosaic
-    compiles in-process."""
+    """Which implementation of each kernel runs.
+
+    'jnp' — the XLA twins; the default on every backend, TPU included.
+    Whether any Pallas body should become the TPU default is undecided:
+    that takes an A/B against its twin on the chip, per kernel family.
+    'pallas' — SPARK_RAPIDS_TPU_PALLAS=1 on a TPU backend: the
+    Mosaic-compiled kernels. A family the compiler refuses raises
+    ``PallasKernelRefused`` at its first use (``require_kernels``); the
+    twin is never substituted for an explicitly requested kernel.
+    'interpret' — SPARK_RAPIDS_TPU_PALLAS=interpret: the kernel bodies
+    under the Pallas interpreter (CPU CI of the kernels themselves)."""
     env = os.environ.get("SPARK_RAPIDS_TPU_PALLAS", "auto")
     if env in ("0", "off", "jnp", "auto"):
         return "jnp"
@@ -119,50 +123,68 @@ def _dual_prefix_pallas(keep_i32: jnp.ndarray, interpret: bool):
     buf = jnp.zeros((padded,), jnp.int32).at[:n].set(keep_i32)
     buf = buf.reshape(padded // _LANES, _LANES)
     grid = padded // _BLK
-    kex, dex, tot = pl.pallas_call(
-        _dual_prefix_kernel,
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((padded // _LANES, _LANES), jnp.int32),
-            jax.ShapeDtypeStruct((padded // _LANES, _LANES), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
-        interpret=interpret,
-    )(buf)
+    # traced with x64 OFF: the engine runs jax in 64-bit mode, where the
+    # index maps' grid positions and literals come out i64 and Mosaic
+    # fails to legalize them ("failed to legalize operation
+    # 'func.return'", measured on v5e / libtpu 0.0.34). Everything in
+    # this kernel is 32-bit anyway.
+    with jax.enable_x64(False):
+        kex, dex, tot = pl.pallas_call(
+            _dual_prefix_kernel,
+            grid=(grid,),
+            in_specs=[pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0))],
+            out_specs=[
+                pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0)),
+                pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((padded // _LANES, _LANES), jnp.int32),
+                jax.ShapeDtypeStruct((padded // _LANES, _LANES), jnp.int32),
+                jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            ],
+            scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
+            interpret=interpret,
+        )(buf)
     return kex.reshape(-1)[:n], dex.reshape(-1)[:n], tot[0, 0]
 
 
-_pallas_ok: bool = None  # resolved by the first eager probe
+class PallasKernelRefused(RuntimeError):
+    """SPARK_RAPIDS_TPU_PALLAS=1 asked for a kernel family that this
+    backend's compiler rejects; carries the compiler's message."""
 
 
-def _pallas_available() -> bool:
-    """Eager one-shot compile probe. The caller is usually *inside* a
-    traced per-batch kernel, where a pallas_call just traces in and its
-    compile failure would surface later, at the outer program's compile —
-    so availability must be decided here with a small concrete run (some
-    TPU attachment modes, e.g. remote-compile tunnels, cannot compile
-    Mosaic kernels at all)."""
-    global _pallas_ok
-    if _pallas_ok is None:
+# family -> None once its probe compiled and ran, else what it raised
+_probe_verdicts: Dict[str, Optional[Exception]] = {}
+
+
+def require_kernels(family: str) -> None:
+    """Eager one-shot compile probe of one kernel family (a key of
+    ``KERNEL_PROBES``). The caller is usually *inside* a traced
+    per-batch kernel, where a pallas_call just traces in and a compile
+    failure would surface later, at the outer program's compile, with no
+    hint of which kernel caused it — so each family is proven here with
+    a small concrete run, and a refusal raises here with the compiler's
+    message. Each family gets its own probe because they exercise
+    different Mosaic surfaces (matmul scan, 64-bit tables with scalar
+    while-loops, scalar-indexed fori_loop walks)."""
+    if family not in _probe_verdicts:
         try:
-            probe = jnp.asarray(np.arange(_BLK) % 3 == 0, jnp.int32)
-            kex, _, tot = _dual_prefix_pallas(probe, False)
-            jax.block_until_ready(kex)
-            _pallas_ok = True
-        except Exception:  # noqa: BLE001 — any compile/runtime failure
-            _pallas_ok = False
-            import logging
-            logging.getLogger(__name__).warning(
-                "pallas compaction kernel unavailable on this backend; "
-                "using the XLA cumsum path")
-    return _pallas_ok
+            jax.block_until_ready(KERNEL_PROBES[family]())
+        except Exception as e:  # noqa: BLE001 — re-raised below with context
+            _probe_verdicts[family] = e
+        else:
+            _probe_verdicts[family] = None
+    err = _probe_verdicts[family]
+    if err is not None:
+        raise PallasKernelRefused(
+            f"pallas {family} kernel does not compile on "
+            f"{jax.default_backend()}: {type(err).__name__}: {err}") from err
+
+
+def _probe_compaction():
+    probe = jnp.asarray(np.arange(_BLK) % 3 == 0, jnp.int32)
+    return _dual_prefix_pallas(probe, False)
 
 
 def dual_prefix_counts(keep: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray,
@@ -170,7 +192,8 @@ def dual_prefix_counts(keep: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray,
     """(kept_ex, dead_ex, kept_total) for a bool vector."""
     keep_i32 = keep.astype(jnp.int32)
     mode = _mode()
-    if mode == "pallas" and _pallas_available():
+    if mode == "pallas":
+        require_kernels("compaction")
         return _dual_prefix_pallas(keep_i32, False)
     if mode == "interpret":
         return _dual_prefix_pallas(keep_i32, True)
@@ -348,8 +371,8 @@ def _hash_build_kernel(k: int, T: int, keys_ref, valid_ref, slot_ref,
 
     def insert(e, _):
         e = e.astype(jnp.int32)
-        v = pl.load(valid_ref, (jnp.int32(0), e)) != 0
-        row_keys = [pl.load(keys_ref, (jnp.int32(j), e)) for j in range(k)]
+        v = valid_ref[0, e] != 0
+        row_keys = [keys_ref[j, e] for j in range(k)]
         h = jnp.asarray(_HASH_SEED, jnp.uint64)
         from spark_rapids_tpu.ops.hashing import splitmix64
         for kk in row_keys:
@@ -363,10 +386,10 @@ def _hash_build_kernel(k: int, T: int, keys_ref, valid_ref, slot_ref,
             p, _s, _code = carry
             s = ((h + p.astype(jnp.uint64)) % jnp.uint64(T)).astype(
                 jnp.int32)
-            c = pl.load(cnt_ref, (jnp.int32(0), s))
+            c = cnt_ref[0, s]
             eq = jnp.asarray(True)
             for j in range(k):
-                eq = eq & (pl.load(tab_ref, (jnp.int32(j), s)) == row_keys[j])
+                eq = eq & (tab_ref[j, s] == row_keys[j])
             code = jnp.where(c == 0, jnp.int32(1),
                              jnp.where(eq, jnp.int32(2), jnp.int32(0)))
             return p + jnp.int32(1), s, code
@@ -378,11 +401,11 @@ def _hash_build_kernel(k: int, T: int, keys_ref, valid_ref, slot_ref,
         @pl.when(v)
         def _():
             for j in range(k):
-                pl.store(tab_ref, (jnp.int32(j), s), row_keys[j])
-            rank = pl.load(cnt_ref, (jnp.int32(0), s))
-            pl.store(cnt_ref, (jnp.int32(0), s), rank + 1)
-            pl.store(slot_ref, (jnp.int32(0), e), s)
-            pl.store(rank_ref, (jnp.int32(0), e), rank)
+                tab_ref[j, s] = row_keys[j]
+            rank = cnt_ref[0, s]
+            cnt_ref[0, s] = rank + 1
+            slot_ref[0, e] = s
+            rank_ref[0, e] = rank
         return 0
 
     jax.lax.fori_loop(0, n, insert, 0)
@@ -398,8 +421,8 @@ def _hash_probe_kernel(k: int, T: int, tab_ref, cnt_ref, keys_ref,
 
     def probe(e, _):
         e = e.astype(jnp.int32)
-        v = pl.load(valid_ref, (jnp.int32(0), e)) != 0
-        row_keys = [pl.load(keys_ref, (jnp.int32(j), e)) for j in range(k)]
+        v = valid_ref[0, e] != 0
+        row_keys = [keys_ref[j, e] for j in range(k)]
         h = jnp.asarray(_HASH_SEED, jnp.uint64)
         from spark_rapids_tpu.ops.hashing import splitmix64
         for kk in row_keys:
@@ -413,10 +436,10 @@ def _hash_probe_kernel(k: int, T: int, tab_ref, cnt_ref, keys_ref,
             p, _s, _code = carry
             s = ((h + p.astype(jnp.uint64)) % jnp.uint64(T)).astype(
                 jnp.int32)
-            c = pl.load(cnt_ref, (jnp.int32(0), s))
+            c = cnt_ref[0, s]
             eq = jnp.asarray(True)
             for j in range(k):
-                eq = eq & (pl.load(tab_ref, (jnp.int32(j), s)) == row_keys[j])
+                eq = eq & (tab_ref[j, s] == row_keys[j])
             # 1 = absent (empty slot ends the chain), 2 = found
             code = jnp.where(c == 0, jnp.int32(1),
                              jnp.where(eq, jnp.int32(2), jnp.int32(0)))
@@ -428,7 +451,7 @@ def _hash_probe_kernel(k: int, T: int, tab_ref, cnt_ref, keys_ref,
 
         @pl.when(v & (code == 2))
         def _():
-            pl.store(slot_ref, (jnp.int32(0), e), s)
+            slot_ref[0, e] = s
         return 0
 
     jax.lax.fori_loop(0, n, probe, 0)
@@ -475,44 +498,21 @@ def _hash_probe_pallas(tab: jnp.ndarray, cnt: jnp.ndarray,
 # time). Interpret mode has no such bound.
 _PALLAS_MAX_TABLE = 1 << 17
 
-_hash_pallas_ok: Optional[bool] = None
-
-
-def _hash_pallas_available() -> bool:
-    """Eager one-shot probe of the HASH kernels specifically: uint64
-    tables, scalar while-loops and dynamic ref indexing are a different
-    Mosaic feature surface than the compaction kernel's matmul scan, so
-    _pallas_available() proving the latter says nothing about these —
-    and a deferred failure would surface inside a jitted join probe at
-    query time (the exact mode the compaction probe's docstring warns
-    about)."""
-    global _hash_pallas_ok
-    if _hash_pallas_ok is None:
-        try:
-            keys = jnp.asarray(np.arange(32) % 5, jnp.uint64)
-            valid = jnp.ones((32,), jnp.bool_)
-            slot, _r, tab, cnt = _hash_build_pallas(
-                keys.reshape(1, -1), valid, 64, False)
-            probe = _hash_probe_pallas(tab, cnt, keys.reshape(1, -1),
-                                       valid, 64, False)
-            jax.block_until_ready(probe)
-            _hash_pallas_ok = True
-        except Exception:  # noqa: BLE001 — any compile/runtime failure
-            _hash_pallas_ok = False
-            import logging
-            logging.getLogger(__name__).warning(
-                "pallas hash-table kernels unavailable on this backend; "
-                "keeping the sort-based join/agg paths")
-    return _hash_pallas_ok
+def _probe_hash_table():
+    keys = jnp.asarray(np.arange(32) % 5, jnp.uint64).reshape(1, -1)
+    valid = jnp.ones((32,), jnp.bool_)
+    _slot, _r, tab, cnt = _hash_build_pallas(keys, valid, 64, False)
+    return _hash_probe_pallas(tab, cnt, keys, valid, 64, False)
 
 
 def hash_kernels_mode() -> str:
     """'pallas' | 'interpret' | 'off' — whether the hash-table kernels
-    may replace the sort-based join/agg fallbacks. Rides the same
+    replace the sort-based join/agg paths. Rides the same
     SPARK_RAPIDS_TPU_PALLAS switch as the compaction kernel: default
     (auto/jnp) keeps the sort paths byte-identical."""
     m = _mode()
-    if m == "pallas" and _hash_pallas_available():
+    if m == "pallas":
+        require_kernels("hash_table")
         return "pallas"
     if m == "interpret":
         return "interpret"
@@ -639,8 +639,8 @@ def _hash_agg_kernel(k: int, T: int, kinds, keys_ref, valid_ref, *refs):
 
     def insert(e, _):
         e = e.astype(jnp.int32)
-        v = pl.load(valid_ref, (jnp.int32(0), e)) != 0
-        row_keys = [pl.load(keys_ref, (jnp.int32(j), e)) for j in range(k)]
+        v = valid_ref[0, e] != 0
+        row_keys = [keys_ref[j, e] for j in range(k)]
         h = jnp.asarray(_HASH_SEED, jnp.uint64)
         from spark_rapids_tpu.ops.hashing import splitmix64
         for kk in row_keys:
@@ -654,10 +654,10 @@ def _hash_agg_kernel(k: int, T: int, kinds, keys_ref, valid_ref, *refs):
             p, _s, _code = carry
             s = ((h + p.astype(jnp.uint64)) % jnp.uint64(T)).astype(
                 jnp.int32)
-            c = pl.load(cnt_ref, (jnp.int32(0), s))
+            c = cnt_ref[0, s]
             eq = jnp.asarray(True)
             for j in range(k):
-                eq = eq & (pl.load(tab_ref, (jnp.int32(j), s)) == row_keys[j])
+                eq = eq & (tab_ref[j, s] == row_keys[j])
             code = jnp.where(c == 0, jnp.int32(1),
                              jnp.where(eq, jnp.int32(2), jnp.int32(0)))
             return p + jnp.int32(1), s, code
@@ -669,29 +669,26 @@ def _hash_agg_kernel(k: int, T: int, kinds, keys_ref, valid_ref, *refs):
         @pl.when(v)
         def _():
             for j in range(k):
-                pl.store(tab_ref, (jnp.int32(j), s), row_keys[j])
-            c = pl.load(cnt_ref, (jnp.int32(0), s))
-            rep_old = pl.load(rep_ref, (jnp.int32(0), s))
-            pl.store(rep_ref, (jnp.int32(0), s),
-                     jnp.where(c == 0, e, rep_old))
-            pl.store(cnt_ref, (jnp.int32(0), s), c + 1)
+                tab_ref[j, s] = row_keys[j]
+            c = cnt_ref[0, s]
+            rep_old = rep_ref[0, s]
+            rep_ref[0, s] = jnp.where(c == 0, e, rep_old)
+            cnt_ref[0, s] = c + 1
             # accumulator updates are branch-free (where on loaded
             # values, unconditional store) — nesting pl.when is avoided
             for j, kind in enumerate(kinds):
-                el = pl.load(elig_refs[j], (jnp.int32(0), e)) != 0
-                d = pl.load(data_refs[j], (jnp.int32(0), e))
-                a = pl.load(acc_refs[j], (jnp.int32(0), s))
-                ne = pl.load(nel_refs[j], (jnp.int32(0), s))
+                el = elig_refs[j][0, e] != 0
+                d = data_refs[j][0, e]
+                a = acc_refs[j][0, s]
+                ne = nel_refs[j][0, s]
                 if kind == "sum":
                     upd = a + d
                 elif kind == "min":
                     upd = jnp.where(ne == 0, d, jnp.minimum(a, d))
                 else:  # max
                     upd = jnp.where(ne == 0, d, jnp.maximum(a, d))
-                pl.store(acc_refs[j], (jnp.int32(0), s),
-                         jnp.where(el, upd, a))
-                pl.store(nel_refs[j], (jnp.int32(0), s),
-                         ne + jnp.where(el, 1, 0))
+                acc_refs[j][0, s] = jnp.where(el, upd, a)
+                nel_refs[j][0, s] = ne + jnp.where(el, 1, 0)
         return 0
 
     jax.lax.fori_loop(0, n, insert, 0)
@@ -763,37 +760,18 @@ def _minmax_neutral(dtype, kind: str):
     return jnp.asarray(info.max if kind == "min" else info.min, dtype)
 
 
-_hash_agg_pallas_ok: Optional[bool] = None
-
-
-def _hash_agg_pallas_available() -> bool:
-    """Eager probe of the AGGREGATION kernel specifically: its feature
-    surface adds float accumulators and multi-dtype stores on top of the
-    build kernel's, so _hash_pallas_available() proving build/probe says
-    nothing about it. The probe covers the dtypes the engine actually
-    accumulates in (int64 sums, float64 sums, int32 selections)."""
-    global _hash_agg_pallas_ok
-    if _hash_agg_pallas_ok is None:
-        try:
-            keys = jnp.asarray(np.arange(32) % 5, jnp.uint64).reshape(1, -1)
-            valid = jnp.ones((32,), jnp.bool_)
-            ones = jnp.ones((32,), jnp.bool_)
-            datas = (jnp.arange(32, dtype=jnp.int64),
-                     jnp.arange(32, dtype=jnp.float64),
-                     jnp.arange(32, dtype=jnp.int32))
-            cnt, _rep, accs, _nels = _hash_agg_pallas(
-                ("sum", "sum", "min"),
-                (jnp.int64, jnp.float64, jnp.int32), 64, False,
-                keys, valid, datas, (ones, ones, ones))
-            jax.block_until_ready(accs[0])
-            _hash_agg_pallas_ok = True
-        except Exception:  # noqa: BLE001 — any compile/runtime failure
-            _hash_agg_pallas_ok = False
-            import logging
-            logging.getLogger(__name__).warning(
-                "pallas hash-aggregation kernel unavailable on this "
-                "backend; using the vectorized twin")
-    return _hash_agg_pallas_ok
+def _probe_hash_aggregate():
+    """Covers the dtypes the engine actually accumulates in (int64 sums,
+    float64 sums, int32 selections)."""
+    keys = jnp.asarray(np.arange(32) % 5, jnp.uint64).reshape(1, -1)
+    valid = jnp.ones((32,), jnp.bool_)
+    datas = (jnp.arange(32, dtype=jnp.int64),
+             jnp.arange(32, dtype=jnp.float64),
+             jnp.arange(32, dtype=jnp.int32))
+    _cnt, _rep, accs, _nels = _hash_agg_pallas(
+        ("sum", "sum", "min"), (jnp.int64, jnp.float64, jnp.int32), 64,
+        False, keys, valid, datas, (valid, valid, valid))
+    return accs
 
 
 def hash_grouped_aggregate(images, valid: jnp.ndarray, jobs,
@@ -812,9 +790,10 @@ def hash_grouped_aggregate(images, valid: jnp.ndarray, jobs,
     undefined where its nel == 0; the caller compacts used slots into
     group rows (counts > 0) and masks by nel."""
     mode = mode or hash_kernels_mode()
-    if mode == "pallas" and (table_size > _PALLAS_MAX_TABLE
-                             or not _hash_agg_pallas_available()):
-        mode = "jnp"
+    if mode == "pallas" and table_size > _PALLAS_MAX_TABLE:
+        mode = "jnp"  # table would not fit the single-step VMEM grid
+    if mode == "pallas":
+        require_kernels("hash_aggregate")
     if mode in ("pallas", "interpret"):
         keys = jnp.stack([im.astype(jnp.uint64) for im in images])
         kinds = tuple(kind for kind, _d, _e in jobs)
@@ -888,7 +867,7 @@ def hash_group_ids(images, valid: jnp.ndarray, table_size: int,
 # bit widths > 32 are rejected host-side (fallback reason deltaWide).
 # Same SPARK_RAPIDS_TPU_PALLAS switch as the other kernels: the jnp twin
 # is the default and CI spelling, =interpret runs these kernel bodies on
-# CPU, =1 requires the eager probe below to pass on an attached TPU.
+# CPU, =1 runs them Mosaic-compiled on a TPU (require_kernels).
 
 _BITW_MASK = jnp.uint64(0xFFFFFFFF)
 
@@ -969,9 +948,8 @@ def hybrid_expand(words, out_start, kind, value, bit_start, bw,
     per-run int32 bit-width array (multi-page chunks merge pages with
     differing dictionary index widths into one run table)."""
     mode = mode or _mode()
-    if mode == "pallas" and not decode_pallas_available():
-        mode = "jnp"
     if mode == "pallas":
+        require_kernels("hybrid_expand")
         return _hybrid_expand_pallas(words, out_start, kind, value,
                                      bit_start, bw, n, False)
     if mode == "interpret":
@@ -1039,9 +1017,8 @@ def delta_unpack(words, out_start, bwid, min_delta, bit_start, first,
                  n: int, mode: Optional[str] = None) -> jnp.ndarray:
     """DELTA_BINARY_PACKED stream -> (n,) int64 values."""
     mode = mode or _mode()
-    if mode == "pallas" and not decode_pallas_available():
-        mode = "jnp"
     if mode == "pallas":
+        require_kernels("delta_unpack")
         return _delta_unpack_pallas(words, out_start, bwid, min_delta,
                                     bit_start, first, n, False)
     if mode == "interpret":
@@ -1112,14 +1089,14 @@ def plain_fixed(words, kind: str, n: int,
                 mode: Optional[str] = None) -> jnp.ndarray:
     """Reassemble a PLAIN fixed-width value stream from uploaded u32
     words. ``kind`` in {i32, i64, f32, f64, bool}. f64 goes through a
-    u64 bitcast, which this attachment's remote-compile helper rejects
-    (ops/floatbits.py) — real-pallas mode therefore defers to jnp for
-    f64; interpret/jnp are CPU-safe."""
+    u64 -> f64 bitcast and the TPU has no 64-bit floats to bitcast to
+    (ops/floatbits.py) — compiled-pallas mode therefore defers to jnp
+    for f64; interpret/jnp are CPU-safe."""
     mode = mode or _mode()
-    if mode == "pallas" and (kind == "f64"
-                             or not decode_pallas_available()):
+    if mode == "pallas" and kind == "f64":
         mode = "jnp"
     if mode == "pallas":
+        require_kernels(f"plain_fixed[{kind}]")
         return _plain_fixed_pallas(words, kind, n, False)
     if mode == "interpret":
         return _plain_fixed_pallas(words, kind, n, True)
@@ -1148,8 +1125,7 @@ def _slab_pack_kernel(chars_ref, starts_ref, lens_ref, out_ref):
         ln = lens_ref[r]
 
         def word(w, _):
-            b = pl.load(chars_ref,
-                        (pl.dslice(s + w * 8, 8),)).astype(jnp.uint64)
+            b = chars_ref[pl.ds(s + w * 8, 8)].astype(jnp.uint64)
             b = jnp.where(w * 8 + offs < ln, b, jnp.uint64(0))
             out_ref[r, w] = (b << shifts).sum()
             return 0
@@ -1180,9 +1156,8 @@ def slab_pack(chars_u8, starts, lens, cap: int, stride: int,
     ``chars_u8`` padded by >= stride bytes so every 8-byte load lands in
     bounds."""
     mode = mode or _mode()
-    if mode == "pallas" and not decode_pallas_available():
-        mode = "jnp"
     if mode == "pallas":
+        require_kernels("slab_pack")
         return _slab_pack_pallas(chars_u8, starts, lens, cap, stride,
                                  False)
     if mode == "interpret":
@@ -1191,37 +1166,47 @@ def slab_pack(chars_u8, starts, lens, cap: int, stride: int,
     return _slab_pack_jnp(chars_u8, starts, lens, cap, stride)
 
 
-_decode_pallas_ok: Optional[bool] = None
+def _probe_hybrid_expand():
+    words = jnp.asarray(np.arange(8, dtype=np.uint32))
+    os_ = jnp.asarray(np.array([0, 4, 8], np.int32))
+    kind = jnp.asarray(np.array([0, 1], np.uint8))
+    val = jnp.asarray(np.array([7, 0], np.int32))
+    bs = jnp.asarray(np.array([0, 0], np.int64))
+    bw = jnp.asarray(np.array([0, 4], np.int32))
+    return _hybrid_expand_pallas(words, os_, kind, val, bs, bw, 8, False)
 
 
-def decode_pallas_available() -> bool:
-    """Eager one-shot probe for the decode kernels, mirroring
-    _pallas_available: scalar-indexed fori_loop walks are a different
-    Mosaic surface than the matmul-scan kernels, so they get their own
-    probe (remote-compile attachments reject Mosaic wholesale; a failure
-    here quietly routes decode to the jnp twins)."""
-    global _decode_pallas_ok
-    if _decode_pallas_ok is None:
-        try:
-            words = jnp.asarray(np.arange(8, dtype=np.uint32))
-            os_ = jnp.asarray(np.array([0, 4, 8], np.int32))
-            kind = jnp.asarray(np.array([0, 1], np.uint8))
-            val = jnp.asarray(np.array([7, 0], np.int32))
-            bs = jnp.asarray(np.array([0, 0], np.int64))
-            bw = jnp.asarray(np.array([0, 4], np.int32))
-            out = _hybrid_expand_pallas(words, os_, kind, val, bs, bw, 8,
-                                        False)
-            jax.block_until_ready(out)
-            _decode_pallas_ok = True
-        except Exception:  # noqa: BLE001
-            _decode_pallas_ok = False
-            import logging
-            logging.getLogger(__name__).warning(
-                "pallas parquet-decode kernels unavailable on this "
-                "backend; using the jnp twins")
-    return _decode_pallas_ok
+def _probe_delta_unpack():
+    words = jnp.asarray(np.arange(8, dtype=np.uint32))
+    os_ = jnp.asarray(np.array([0, np.iinfo(np.int32).max], np.int32))
+    bw = jnp.asarray(np.array([4, 0], np.int32))
+    md = jnp.asarray(np.array([1, 0], np.int64))
+    bs = jnp.asarray(np.array([0, 0], np.int64))
+    first = jnp.asarray(np.array([5], np.int64))
+    return _delta_unpack_pallas(words, os_, bw, md, bs, first, 8, False)
 
 
-def decode_kernels_mode() -> str:
-    """Resolved mode for the decode kernel family (shared env switch)."""
-    return _mode()
+def _probe_plain_fixed(kind: str):
+    words = jnp.asarray(np.arange(16, dtype=np.uint32))
+    return _plain_fixed_pallas(words, kind, 8, False)
+
+
+def _probe_slab_pack():
+    chars = jnp.asarray(np.arange(48, dtype=np.uint8))
+    starts = jnp.asarray(np.array([0, 5, 0, 0], np.int64))
+    lens = jnp.asarray(np.array([5, 11, 0, 0], np.int32))
+    return _slab_pack_pallas(chars, starts, lens, 4, 16, False)
+
+
+# One eager compile probe per kernel family (``require_kernels``): each
+# is a tiny concrete run of the family's compiled (non-interpret) entry.
+KERNEL_PROBES: Dict[str, Callable] = {
+    "compaction": _probe_compaction,
+    "hash_table": _probe_hash_table,
+    "hash_aggregate": _probe_hash_aggregate,
+    "hybrid_expand": _probe_hybrid_expand,
+    "delta_unpack": _probe_delta_unpack,
+    **{f"plain_fixed[{kind}]": functools.partial(_probe_plain_fixed, kind)
+       for kind in ("i32", "f32", "i64", "bool")},
+    "slab_pack": _probe_slab_pack,
+}

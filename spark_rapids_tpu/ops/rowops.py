@@ -333,10 +333,9 @@ def concat_batches(batches: Sequence[DeviceBatch],
     # with CONTIGUOUS dynamic_update_slice block copies instead of a
     # row gather — batch i's full padded buffer lands at its dynamic
     # base and batch i+1's copy overwrites i's padding (bases advance by
-    # LIVE counts). Measured ~8x faster than the packed gather for the
-    # same move on v5e (XLA's gather lowering is the engine's ceiling,
-    # docs/roofline_r5.md). Plain string columns (dynamic char extents)
-    # stay on the gather path below.
+    # LIVE counts): contiguous copies run at memory speed where XLA's
+    # 1-D gather lowering does not. Plain string columns (dynamic char
+    # extents) stay on the gather path below.
     def _block_copy(arrs, fill=None):
         dt0 = arrs[0].dtype
         out = jnp.zeros((out_capacity,), dt0) if fill is None else \

@@ -8,8 +8,8 @@ tag-matched RDMA endpoint pairs, partitions live as shards of a
 (partial aggregate -> hash partition -> exchange -> merge) per stage, with
 XLA overlapping compute and communication.
 
-Validated on a virtual 8-device CPU mesh in tests and by the driver's
-``dryrun_multichip``; the same code lays out onto a real pod slice.
+Validated on a virtual 8-device CPU mesh in tests
+(tests/test_distributed.py, tests/test_mesh_exec.py).
 """
 
 from __future__ import annotations
@@ -20,10 +20,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x keeps it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_tpu.columnar import dtypes
@@ -65,10 +62,7 @@ def pick_bounds_from_samples(samples, k: int, n: int):
 
 
 def data_parallel_mesh(n_devices: int) -> Mesh:
-    # mesh construction goes through the version shim layer (the jax
-    # sharding API moves between release trains; shims/loader.py)
-    from spark_rapids_tpu.shims import ShimLoader
-    return ShimLoader.get_shims().make_mesh([n_devices], ("dp",))
+    return Mesh(np.asarray(jax.devices()[:n_devices]), ("dp",))
 
 
 def _hash_pid(batch: DeviceBatch, key_idx: Sequence[int], n: int):
@@ -445,21 +439,14 @@ def distributed_hash_aggregate_step(mesh: Mesh, schema: Schema,
     return jax.jit(fn)
 
 
-def dryrun_multichip_full(n_devices: int) -> None:
-    """Driver-facing multichip validation: every distributed path we ship,
-    executed once on an n-device mesh with tiny shapes. Grows as engine
-    paths gain mesh execution (VERDICT r1 items 2 and 4)."""
-    dryrun_distributed_q1(n_devices)
-    dryrun_session_mesh(n_devices)
-
-
 def dryrun_session_mesh(n_devices: int) -> None:
     """Engine-integrated mesh execution: a group-by aggregate, a shuffled
     hash join, a global sort (range exchange: per-shard sample -> bounds
-    -> all_to_all), and a broadcast join (mesh_broadcast replication) run
-    through the *session* API with every exchange riding the fused
-    shard_map all_to_all over the dp axis, checked against the CPU
-    oracle."""
+    -> all_to_all), a limit over that sort (one running count carried
+    across partitions that live on different devices), and a broadcast
+    join (mesh_broadcast replication) run through the *session* API with
+    every exchange riding the fused shard_map all_to_all over the dp
+    axis, checked against the CPU oracle."""
     import numpy as np
     import pandas as pd
     from spark_rapids_tpu.session import TpuSparkSession
@@ -490,6 +477,9 @@ def dryrun_session_mesh(n_devices: int) -> None:
         def q_sort(sess):
             return sess.create_dataframe(left, n_devices).order_by("v")
 
+        def q_limit(sess):
+            return q_sort(sess).limit(5)
+
         def q_bcast(sess):
             # small build side under the default broadcast threshold:
             # replicated over the mesh via mesh_broadcast
@@ -500,6 +490,7 @@ def dryrun_session_mesh(n_devices: int) -> None:
 
         tpu = q(s).collect().sort_values("tag").reset_index(drop=True)
         tpu_sorted = q_sort(s).collect().reset_index(drop=True)
+        tpu_limit = q_limit(s).collect().reset_index(drop=True)
         s.conf._settings.pop(
             "spark.rapids.sql.autoBroadcastJoinThreshold", None)
         tpu_b = q_bcast(s).collect().sort_values("tag").reset_index(drop=True)
@@ -507,6 +498,7 @@ def dryrun_session_mesh(n_devices: int) -> None:
         s.set_conf("spark.rapids.sql.enabled", False)
         cpu = q(s).collect().sort_values("tag").reset_index(drop=True)
         cpu_sorted = q_sort(s).collect().reset_index(drop=True)
+        cpu_limit = q_limit(s).collect().reset_index(drop=True)
         cpu_b = q_bcast(s).collect().sort_values("tag").reset_index(drop=True)
         assert list(tpu["n"]) == list(cpu["n"]), (tpu, cpu)
         np.testing.assert_allclose(tpu["sv"].to_numpy(dtype=np.float64),
@@ -515,6 +507,9 @@ def dryrun_session_mesh(n_devices: int) -> None:
         np.testing.assert_allclose(
             tpu_sorted["v"].to_numpy(dtype=np.float64),
             cpu_sorted["v"].to_numpy(dtype=np.float64), rtol=1e-9)
+        np.testing.assert_allclose(
+            tpu_limit["v"].to_numpy(dtype=np.float64),
+            cpu_limit["v"].to_numpy(dtype=np.float64), rtol=1e-9)
         assert list(tpu_b["n"]) == list(cpu_b["n"]), (tpu_b, cpu_b)
     finally:
         s.conf._settings = saved
@@ -522,7 +517,7 @@ def dryrun_session_mesh(n_devices: int) -> None:
 
 
 def dryrun_distributed_q1(n_devices: int, rows_per_shard: int = 512) -> None:
-    """The driver's multichip validation: a full distributed TPC-H-Q1-shaped
+    """Multichip validation: a full distributed TPC-H-Q1-shaped
     aggregation step (dp sharding + all-to-all shuffle + merge) on an
     n-device mesh, executed once on tiny shapes."""
     import datetime

@@ -22,10 +22,11 @@ tenant submissions across them:
     the router's journal as ``queryShed`` with replica attribution;
   * **rolling restarts** (``rolling_restart``): quiesce the worker
     (stop placing onto it, ``workerDrain`` event), drain its in-flight
-    jobs under their own deadlines, boot the replacement pre-warmed
-    from the shared warm manifest + shared XLA cache (``workerReady``
-    only after its AOT pass went idle), then atomically swap the handle
-    — zero shed, zero cold compiles on first traffic;
+    jobs under their own deadlines, stop it, boot the replacement on
+    the same chip pre-warmed from the shared warm manifest + jax's
+    persistent cache (``workerReady`` only after its AOT pass went
+    idle), then swap the handle — zero shed, zero cold compiles on
+    first traffic;
   * **crash handling**: a dead worker's in-flight jobs fail with
     ``worker lost``, a ``workerLost`` event carries the replica and the
     failed count, the tenant placements pointing at it are dropped so
@@ -130,7 +131,11 @@ class ProcessWorker:
     every outstanding request with ``{"lost": true}`` and fires the
     ``on_lost`` hook — unless ``stop()`` initiated the exit."""
 
-    def __init__(self, replica: str, spec_path: str):
+    def __init__(self, replica: str, spec_path: str,
+                 env: Optional[Dict[str, str]] = None):
+        """``env``: variables laid over this process's environment for
+        the child — how a worker is narrowed to its one chip before it
+        imports jax (``memory/discovery.one_chip_env``)."""
         self.replica = replica
         self.spec_path = spec_path
         self._ids = itertools.count(1)
@@ -144,7 +149,8 @@ class ProcessWorker:
             [sys.executable, "-m",
              "spark_rapids_tpu.serving.fleet.worker", spec_path],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            text=True, bufsize=1)
+            text=True, bufsize=1,
+            env=dict(os.environ, **env) if env else None)
         threading.Thread(target=self._pump, daemon=True,
                          name=f"fleet-pump-{replica}").start()
 
@@ -176,6 +182,11 @@ class ProcessWorker:
                     cb(msg)
                 except Exception:  # noqa: BLE001 — a callback must not kill the pump
                     pass
+        # stdout closed: the process is exiting. Reap it before anyone is
+        # woken, so ``alive`` already reads False to a starter that was
+        # waiting on ``_ready`` (a worker that died at boot must fail the
+        # launch, not pass it)
+        self.proc.wait()
         with self._lock:
             orphans = list(self._pending.values())
             self._pending.clear()
@@ -419,6 +430,8 @@ class FleetRouter:
         self.fleet_dir: Optional[str] = None
         self.base_conf: Optional[Dict[str, Any]] = None
         self.spec_extras: Optional[Dict[str, Any]] = None
+        # replica id -> the one-chip environment its process runs under
+        self.worker_env: Dict[str, Dict[str, str]] = {}
 
     # -- submission ----------------------------------------------------------
     def submit(self, query: Any, tenant: str = "default",
@@ -619,12 +632,19 @@ class FleetRouter:
     def rolling_restart(self, rid: str, spawn: Callable[[], Any],
                         drain_timeout: float = 60.0,
                         ready_timeout: float = 120.0) -> Dict[str, Any]:
-        """Quiesce -> drain -> boot replacement -> wait warm -> swap ->
-        stop old. ``spawn()`` returns the replacement handle for the
-        SAME replica id (placement stays sticky across the restart)."""
+        """Quiesce -> drain -> stop old -> boot replacement -> wait warm
+        -> swap. ``spawn()`` returns the replacement handle for the SAME
+        replica id (placement stays sticky across the restart). The old
+        worker stops BEFORE its replacement boots: the replacement takes
+        over the same chip, and a chip belongs to one process. The
+        replica is out of rotation for the whole restart either way."""
         from spark_rapids_tpu.obs.events import EVENTS
         inflight = self.quiesce(rid)
         drained = self.wait_drained(rid, drain_timeout)
+        with self._cond:
+            old = self._recs[rid]["handle"]
+        old.set_on_lost(None)  # its exit is planned, not a loss
+        old.stop()
         replacement = spawn()
         t0 = time.monotonic()
         ready, aot = self._wait_ready(replacement, ready_timeout)
@@ -633,14 +653,11 @@ class FleetRouter:
                     ready=ready, waitSeconds=wait_s)
         with self._cond:
             rec = self._recs[rid]
-            old = rec["handle"]
-            old.set_on_lost(None)  # its exit is planned, not a loss
             rec["handle"] = replacement
             rec["state"] = "up"
             rec["depth"] = 0
             replacement.set_on_lost(self._make_lost_cb(rid))
             self._cond.notify_all()
-        old.stop()
         return {"replica": rid, "inflightAtQuiesce": inflight,
                 "drained": drained, "ready": ready,
                 "readyWaitSeconds": wait_s, "aot": aot}
@@ -667,7 +684,7 @@ class FleetRouter:
                 extras["primeQueries"] = recent
             path = warmstate.write_worker_spec(
                 self.fleet_dir, rid, conf, **extras)
-            return ProcessWorker(rid, path)
+            return ProcessWorker(rid, path, env=self.worker_env.get(rid))
 
         return self.rolling_restart(rid, spawn,
                                     drain_timeout=drain_timeout,
@@ -771,31 +788,51 @@ def launch_process_fleet(n: int, fleet_dir: str,
                          overrides: Optional[Any] = None,
                          start_timeout: float = 120.0) -> FleetRouter:
     """Boot N ``fleet/worker.py`` processes over one shared fleet dir
-    (``warmstate``: shared XLA cache + warm manifest + per-replica
+    (``warmstate``: compile manifest + warm manifest + per-replica
     event logs) and return the router over them. Workers boot in
     parallel; a worker that fails to start raises after the others are
-    stopped."""
+    stopped.
+
+    On a TPU host each worker is handed exactly one chip through its
+    environment, counted without initialising a backend in this process
+    (which would take every chip for the router): asking for more
+    workers than chips raises at once."""
+    from spark_rapids_tpu.memory import discovery
+    from spark_rapids_tpu.serving.fleet import warmstate
+    n = int(n)
+    chips = discovery.local_chip_ordinals()
+    if chips and n > len(chips):
+        raise RuntimeError(
+            f"asked for {n} fleet workers but this host has "
+            f"{len(chips)} TPU chip(s) {chips}: a chip belongs to one "
+            "process")
     os.makedirs(fleet_dir, exist_ok=True)
     workers: Dict[str, ProcessWorker] = {}
-    from spark_rapids_tpu.serving.fleet import warmstate
-    for i in range(int(n)):
+    worker_env: Dict[str, Dict[str, str]] = {}
+    for i in range(n):
         rid = f"r{i}"
         conf = warmstate.worker_conf(base_conf, fleet_dir, rid)
         path = warmstate.write_worker_spec(fleet_dir, rid, conf,
                                            **(spec_extras or {}))
-        workers[rid] = ProcessWorker(rid, path)
+        if chips:
+            worker_env[rid] = discovery.one_chip_env(chips[i])
+        workers[rid] = ProcessWorker(rid, path, env=worker_env.get(rid))
     failed = [rid for rid, h in workers.items()
               if not h.wait_started(start_timeout)]
     if failed:
-        detail = "; ".join(
-            f"{rid}: {workers[rid].fatal or 'start timeout'}"
-            for rid in failed)
+        def why(h: ProcessWorker) -> str:
+            if h.fatal:
+                return h.fatal
+            rc = h.proc.poll()
+            return "start timeout" if rc is None else f"exited {rc} at boot"
+        detail = "; ".join(f"{rid}: {why(workers[rid])}" for rid in failed)
         for h in workers.values():
             h.kill()
         raise RuntimeError(f"fleet workers failed to start: {detail}")
     router = FleetRouter(workers, spillover_depth=spillover_depth,
                          overrides=overrides)
     router.fleet_dir = fleet_dir
+    router.worker_env = worker_env
     router.base_conf = dict(base_conf or {})
     router.spec_extras = dict(spec_extras or {})
     return router

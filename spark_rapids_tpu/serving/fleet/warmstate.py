@@ -2,10 +2,13 @@
 
 A fleet shares warmth through ``spark.rapids.tpu.fleet.dir``:
 
-  ``<dir>/compilecache/``      the shared persistent compile cache
-                               (obs/compilecache.py points jax's
-                               ``jax_compilation_cache_dir`` at its
-                               ``xla/`` subdir) — the EXECUTABLES;
+  ``<dir>/compilecache/``      the shared compile manifest
+                               (obs/compilecache.py). The EXECUTABLES
+                               are not here: they live in jax's
+                               persistent cache, whose directory every
+                               worker inherits from the router's
+                               environment (JAX_COMPILATION_CACHE_DIR,
+                               else the package's fixed default);
   ``<dir>/warm.jsonl``         the warm-state manifest: one flock-
                                serialized REPLAYABLE record per real
                                compile anywhere in the fleet (kernel,
@@ -19,7 +22,7 @@ A fleet shares warmth through ``spark.rapids.tpu.fleet.dir``:
   ``<dir>/worker-<rid>.json``  the spec file a worker process boots from.
 
 The division of labor: any replica's FIRST compile of a shape lands the
-executable in the shared XLA cache and a replayable record in
+executable in jax's persistent cache and a replayable record in
 ``warm.jsonl``; every OTHER replica's first touch of that shape is a
 persistent-cache steal (no compile), and a REPLACEMENT replica replays
 the whole manifest via ``serving/prewarm.py`` BEFORE taking traffic —
@@ -76,8 +79,7 @@ def write_worker_spec(fleet_dir: str, replica: str,
                       **extras: Any) -> str:
     """Write ``<dir>/worker-<rid>.json``, the argv[1] of
     ``python -m spark_rapids_tpu.serving.fleet.worker``. Extras land
-    top-level in the spec (e.g. ``jaxPlatforms="cpu"`` for chipless
-    test containers, ``schedulerWorkers=2``)."""
+    top-level in the spec (e.g. ``schedulerWorkers=2``)."""
     os.makedirs(fleet_dir, exist_ok=True)
     spec = {"replica": replica, "conf": conf}
     spec.update(extras)

@@ -102,10 +102,6 @@ class _WorkerServer:
 
     # -- bootstrap -----------------------------------------------------------
     def start(self) -> None:
-        platforms = self.spec.get("jaxPlatforms")
-        if platforms:
-            import jax
-            jax.config.update("jax_platforms", platforms)
         # real-compile accounting BEFORE the session exists: the
         # rolling-restart invariant ("replacement performs zero real XLA
         # compiles") is asserted against these counters, so the AOT

@@ -82,10 +82,9 @@ class TpuSparkSession:
         # first-class in profile reports
         from spark_rapids_tpu.obs import compilecache
         compilecache.install()
-        # cross-process shared compile cache + AOT pre-warm from history
-        # (ROADMAP item 3): configured at session START so the pre-warm
-        # pass overlaps everything the first query does, and jax's
-        # persistent cache points at the shared dir before any compile
+        # cross-process compile manifest + AOT pre-warm from history:
+        # configured at session START so the pre-warm pass overlaps
+        # everything the first query does
         compilecache.SHARED.configure_from_conf(conf)
         from spark_rapids_tpu.serving import prewarm as _prewarm
         _prewarm.maybe_start_from_conf(conf)

@@ -53,3 +53,28 @@ def test_timeout_kills_worker_and_next_query_unaffected(tmp_path):
     # the query AFTER the timeout ran normally on a fresh worker
     assert "tpu_s" in q["_selftest.fast2"], q
     assert q["_selftest.fast2"]["timed_compiles"] == 0
+
+
+def test_nothing_scored_exits_nonzero_and_names_the_device(tmp_path):
+    """A sweep in which no query scored is a failed run, not a finished
+    one with an "error" field; and every record says which device its
+    seconds came from (here the CPU the tests run on)."""
+    env = dict(
+        os.environ,
+        JAX_PLATFORMS="cpu",
+        BENCH_SUITE="_selftest",
+        BENCH_QUERIES="_selftest.no_such_query",
+        BENCH_ITERS="1",
+        BENCH_DETAIL_FILE=str(tmp_path / "detail.json"),
+        BENCH_LOAD_WAIT_S="0",
+    )
+    out = subprocess.run(
+        [sys.executable, BENCH], env=env, capture_output=True, text=True,
+        timeout=300)
+    assert out.returncode == 1, (out.returncode, out.stderr[-2000:])
+    payload = json.loads(out.stdout.strip().splitlines()[-1])
+    assert payload["n_scored"] == 0 and "error" in payload
+    assert payload["device"] == {"platform": "cpu", "kind": "cpu",
+                                 "count": 8}
+    with open(tmp_path / "detail.json") as f:
+        assert json.load(f)["device"] == payload["device"]

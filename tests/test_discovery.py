@@ -77,3 +77,20 @@ def test_all_claimed_raises():
             discovery.discover_and_claim([5])
     finally:
         hold.kill()
+
+
+def test_chips_counted_from_the_environment_without_a_backend(monkeypatch):
+    """What a launcher may hand out is decided before any backend
+    exists: nothing off the TPU platform, and exactly the chips this
+    process was itself narrowed to."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2,3")
+    assert discovery.local_chip_ordinals() == []
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    assert discovery.local_chip_ordinals() == [2, 3]
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS")
+    assert discovery.local_chip_ordinals() == []  # this host has no chip
+    env = discovery.one_chip_env(3)
+    assert env["TPU_VISIBLE_CHIPS"] == "3"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"

@@ -297,7 +297,6 @@ class TestByteIdenticalOff:
         prog = (
             "import sys\n"
             "sys.path.insert(0, %r)\n"
-            "import jax; jax.config.update('jax_platforms', 'cpu')\n"
             "import pandas as pd\n"
             "from spark_rapids_tpu.session import TpuSparkSession\n"
             "from spark_rapids_tpu.serving.scheduler import "
@@ -319,6 +318,24 @@ class TestByteIdenticalOff:
             env=dict(os.environ, JAX_PLATFORMS="cpu"))
         assert out.returncode == 0, out.stderr[-1000:]
         assert "FLEET_FREE" in out.stdout
+
+
+class TestOneProcessPerChip:
+    def test_more_workers_than_chips_is_refused_at_once(
+            self, tmp_path, monkeypatch):
+        """A chip belongs to one process: the launcher counts chips
+        without a backend and refuses before spawning anything."""
+        from spark_rapids_tpu.memory import discovery
+        from spark_rapids_tpu.serving.fleet import router as fr
+        monkeypatch.setattr(discovery, "local_chip_ordinals", lambda: [0])
+        spawned = []
+        monkeypatch.setattr(fr, "ProcessWorker",
+                            lambda *a, **kw: spawned.append(a))
+        t0 = time.monotonic()
+        with pytest.raises(RuntimeError, match=r"1 TPU chip\(s\)"):
+            fr.launch_process_fleet(2, str(tmp_path / "fleet"))
+        assert time.monotonic() - t0 < 1.0 and not spawned
+        assert not (tmp_path / "fleet").exists()
 
 
 # ---------------------------------------------------------------------------
@@ -405,14 +422,19 @@ class TestProcessFleet:
             fleet.shutdown()
 
     def test_rolling_restart_zero_real_compiles_zero_shed(
-            self, tmp_path):
+            self, tmp_path, monkeypatch):
         """Acceptance pin (the fleet face of test_zero_warmup.py): the
-        replacement worker boots from the shared warm manifest + shared
-        XLA cache and replays the router's recent queries BEFORE taking
+        replacement worker boots from the shared warm manifest + jax's
+        persistent cache and replays the router's recent queries BEFORE taking
         traffic, so its first real query performs ZERO real XLA
         compiles — and the restart itself sheds nothing."""
         spec = {"kind": "suite", "suite": "tpch", "query": "q6",
                 "sf": 0.01}
+        # the executables ride jax's persistent cache, which workers
+        # inherit from the launcher's environment (XLA:CPU keeps it off
+        # unless asked)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "xla"))
         fleet = _boot_fleet(2, tmp_path / "fleet")
         try:
             warm = fleet.submit(spec, tenant="alice", want_result=True)

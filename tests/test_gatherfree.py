@@ -16,7 +16,9 @@ from spark_rapids_tpu.ops import rowops
 
 
 def _strs(df: pd.DataFrame, col: str = "s"):
-    return df[col].where(df[col].notna(), None).tolist()
+    # pandas 3's str dtype spells a missing value nan and .where(..., None)
+    # keeps it nan, so normalize element-wise
+    return [None if pd.isna(v) else v for v in df[col].tolist()]
 
 
 # ---------------------------------------------------------------------------

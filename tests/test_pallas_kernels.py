@@ -247,6 +247,23 @@ def test_hash_kernels_mode_env(monkeypatch):
     assert pk.hash_kernels_mode() == "off"
 
 
+def test_requested_kernel_that_does_not_compile_raises(monkeypatch):
+    """SPARK_RAPIDS_TPU_PALLAS=1 means the compiled kernels or an error,
+    never the twin in their place: a family the backend's compiler
+    refuses raises at first use, with the compiler's message. (XLA:CPU
+    refuses every non-interpret pallas_call, which stands in for Mosaic
+    refusing one.)"""
+    monkeypatch.setattr(pk, "_mode", lambda: "pallas")
+    monkeypatch.setattr(pk, "_probe_verdicts", {})
+    keep = np.arange(64) % 3 == 0
+    for use in (lambda: pk.dual_prefix_counts(keep), pk.hash_kernels_mode):
+        for _ in range(2):  # the verdict is cached, the refusal is not
+            with pytest.raises(pk.PallasKernelRefused,
+                               match="interpret mode"):
+                use()
+    assert set(pk._probe_verdicts) == {"compaction", "hash_table"}
+
+
 def test_hash_kernels_exec_wiring_interpret(monkeypatch, session, rng):
     """End-to-end coverage of the exec GLUE, not just the kernel
     primitives: under SPARK_RAPIDS_TPU_PALLAS=interpret a real join

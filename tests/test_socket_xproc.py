@@ -31,8 +31,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _CHILD = r"""
 import json, os, struct, sys, threading, time
 sys.path.insert(0, %(repo)r)
-import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np, pandas as pd
 from spark_rapids_tpu.columnar.batch import DeviceBatch
 from spark_rapids_tpu.shuffle.socket_transport import SocketTransport

@@ -40,17 +40,13 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.fixture(autouse=True)
 def _clean_zero_warmup_state():
     """These layers are process-global; every test leaves them off."""
-    import jax
-
     from spark_rapids_tpu.obs.compilecache import SHARED
     from spark_rapids_tpu.serving import prewarm
-    cache_dir_before = jax.config.jax_compilation_cache_dir
     yield
     prewarm.cancel_active()
     kernelcache.set_build_hook(None)
     kernelcache.configure_shape_buckets(False)
     SHARED.reset_for_tests()
-    jax.config.update("jax_compilation_cache_dir", cache_dir_before)
 
 
 # ---------------------------------------------------------------------------
@@ -486,7 +482,6 @@ class TestSharedCompileCache:
         prog = (
             "import os, sys\n"
             "sys.path.insert(0, %r)\n"
-            "import jax; jax.config.update('jax_platforms', 'cpu')\n"
             "from spark_rapids_tpu.obs.compilecache import SHARED\n"
             "SHARED.configure(sys.argv[1])\n"
             "tag = sys.argv[2]\n"
@@ -582,8 +577,6 @@ class TestStatusSurfacing:
 _FRESH_PROG = r"""
 import json, os, sys
 sys.path.insert(0, sys.argv[4])
-import jax
-jax.config.update("jax_platforms", "cpu")
 shared, manifest, evlog = sys.argv[1], sys.argv[2], sys.argv[3]
 from spark_rapids_tpu.session import TpuSparkSession
 b = TpuSparkSession.builder().config(
@@ -622,9 +615,13 @@ print(json.dumps({
 """
 
 
-def _run_fresh(args):
+def _run_fresh(args, xla_cache):
+    # the executables ride jax's own cache, placed by the environment
+    # (XLA:CPU keeps it off otherwise); the shared dir holds the manifest
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=xla_cache)
     r = subprocess.run([sys.executable, "-c", _FRESH_PROG] + args,
-                       capture_output=True, text=True, timeout=300)
+                       env=env, capture_output=True, text=True,
+                       timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
     return json.loads(r.stdout.strip().splitlines()[-1])
 
@@ -636,11 +633,15 @@ def test_second_sweep_in_fresh_process_compiles_nothing(tmp_path):
     compiles — every backend-compile event is a persistent-cache load,
     and the pre-warmer replays history before/alongside the query."""
     shared = str(tmp_path / "cache")
+    xla_cache = str(tmp_path / "xla")
     evlog = str(tmp_path / "ev.jsonl")
     manifest = str(tmp_path / "aot.json")
 
-    first = _run_fresh([shared, "", evlog, _REPO])
+    first = _run_fresh([shared, "", evlog, _REPO], xla_cache)
     assert first["real_compiles"] > 0  # cold cluster genuinely compiles
+    # executables went where the environment said and nowhere else
+    assert len(os.listdir(xla_cache)) >= first["real_compiles"]
+    assert os.listdir(shared) == ["manifest.jsonl"]
 
     cr = _load_tool("compile_report")
     entries = cr._load_entries(evlog)
@@ -648,7 +649,7 @@ def test_second_sweep_in_fresh_process_compiles_nothing(tmp_path):
     assert man["replayable"] >= 1
     json.dump(man, open(manifest, "w"))
 
-    second = _run_fresh([shared, manifest, "", _REPO])
+    second = _run_fresh([shared, manifest, "", _REPO], xla_cache)
     assert second["real_compiles"] == 0, (
         "fresh process recompiled despite shared cache + AOT replay: "
         f"{second['real_kernels']}")

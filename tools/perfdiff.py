@@ -2,13 +2,15 @@
 
 The CI gate the bench trajectory lacked: given a BASE and a NEW sweep,
 report per-query speedup deltas above a noise threshold and the geomean
-drift, and exit 1 when NEW regresses. Accepts any of the three artifact
-shapes the harness produces:
+drift, and exit 1 when NEW regresses. The artifacts are what a run of
+``bench.py`` leaves in its working directory (none is kept in the repo:
+a record is only as good as the device it names, so compare two runs
+made on the same chip). Accepts any of these shapes:
 
   * ``BENCH_DETAIL.json`` — ``{"queries": {name: {"speedup": ...}}}``
     (the per-query sidecar ``bench.py`` writes);
-  * ``BENCH_r*.json`` — the driver wrapper ``{"parsed": summary,
-    "tail": stderr}``; per-query speedups are recovered from the tail's
+  * a wrapper ``{"parsed": summary, "tail": stderr}`` around a captured
+    run; per-query speedups are recovered from the tail's
     ``bench: <q> tpu=..s cpu=..s speedup=..x`` lines, the geomean from
     ``parsed.value``;
   * a bare summary line — ``{"metric": ..., "value": geomean}``
@@ -69,7 +71,7 @@ wall share (``sync_s``/``tpu_s``) grew more than ``--sync-threshold``
 absolute, exits 1 — the device went idle on host orchestration more
 than it used to. ``--ignore-syncs`` disables.
 
-And it gates **roofline class** (docs/roofline.md): pass ``--roofline
+And it gates **roofline class** (tools/roofline.py): pass ``--roofline
 OLD.json NEW.json`` with two ``tools/roofline.py`` artifacts and any
 common query whose dominant kernel's HBM-utilization class dropped
 (high > elementwise [3-12%] > low [0.5-3%] > gather-built [<0.5%])
@@ -239,7 +241,7 @@ def losers_from_doc(doc: Dict[str, Any],
 
 # HBM-utilization classes of a query's dominant kernel, ranked: the
 # gather-built kernels sit under 0.5% of HBM peak, healthy elementwise
-# data movement in the 3-12% band (docs/roofline.md). The roofline gate
+# data movement in the 3-12% band. The roofline gate
 # fails when a common query's class RANK drops between two
 # tools/roofline.py artifacts — intra-class GB/s noise never gates.
 ROOFLINE_CLASSES = [("gather", 0.5), ("low", 3.0),
