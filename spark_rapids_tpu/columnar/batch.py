@@ -24,6 +24,7 @@ from spark_rapids_tpu.columnar import dtype as dtypes
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.columnar.dtype import DType
 from spark_rapids_tpu.obs.syncledger import sync_scope
+from spark_rapids_tpu.obs.trace import TRACER
 
 def _host_nbytes(tree) -> int:
     """Bytes landed by a completed device->host fetch (numpy leaves)."""
@@ -98,6 +99,94 @@ class Schema:
             names.append(str(name))
             dts.append(_pandas_col_dtype(df.iloc[:, i]))
         return Schema(names, dts)
+
+
+def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
+                        cap: int, dict_encode: bool,
+                        dict_state: Optional[dict], dict_numerics: bool,
+                        blocked_chars: int):
+    """The host half of ``DeviceBatch.from_pandas`` (its ``upload.build``
+    span): every column's device-layout buffers, dictionary probe and
+    char slab. Returns (host_bufs, dict_metas, slab_metas), one entry a
+    column."""
+    from spark_rapids_tpu.columnar.column import (
+        host_dict_encode_stateful, np_build_slab, slab_stride_for,
+    )
+    # per-column factorize hints precomputed by the scan pipeline's
+    # decode workers (sources._attach_dict_hints), keyed by column
+    # name; only trusted when the frame was not re-chunked since
+    hints = getattr(df, "attrs", None)
+    hints = hints.get("srt_dict_fact") if hints else None
+    # build every column's device-layout buffers host-side, then ship
+    # the whole batch in ONE device_put (per-buffer uploads each pay
+    # their own dispatch)
+    host_bufs = []
+    dict_metas = []
+    slab_metas = []
+    # positional iteration: join outputs may carry duplicate column names
+    for i, dt in enumerate(schema.dtypes):
+        values, validity = _pandas_to_numpy(df.iloc[:, i], dt)
+        bufs = DeviceColumn.build_host_buffers(values, validity, dt, cap)
+        fact = hints.get(str(df.columns[i])) if hints else None
+        if fact is not None and len(fact[0]) != n:
+            fact = None
+        # ``dict_numerics=False`` (file-scan uploads): only string
+        # columns are dictionary-probed — the numeric probe+encode is
+        # an element-wise pass per column per batch on the upload hot
+        # path, and integer grouping keys ride the dense-key path
+        # (spark.rapids.sql.agg.denseKeys) instead of dictionaries
+        enc = host_dict_encode_stateful(values, validity, dt, cap,
+                                        dict_state, i, fact=fact) \
+            if dict_encode and (dict_numerics or dt.is_string) else None
+        if enc is not None and dt.is_string:
+            # only pay the slab scan when a dictionary was actually
+            # built (high-cardinality columns already bailed at the
+            # probe): NUL-bearing data must not be dictionary-encoded
+            # (see string_host_buffers_have_nul)
+            from spark_rapids_tpu.columnar.column import (
+                string_host_buffers_have_nul,
+            )
+            if string_host_buffers_have_nul(bufs, n):
+                enc = None
+                if dict_state is not None:
+                    dict_state[i] = False  # close for the whole scan
+        if enc is not None:
+            codes, vals = enc
+            bufs = bufs + (codes,)
+            dict_metas.append(vals)
+            slab_metas.append(0)
+        else:
+            dict_metas.append(None)
+            stride = 0
+            if blocked_chars > 0 and dt.is_string:
+                chars_b, _v, offs_b = bufs[0], bufs[1], bufs[2]
+                max_len = int((offs_b[1:n + 1] - offs_b[:n]).max()) \
+                    if n else 0
+                stride = slab_stride_for(max_len, blocked_chars)
+                if stride and dict_state is not None:
+                    # per-scan stride registry (the slab twin of the
+                    # dictionary registry): LATER batches pad to the
+                    # widest stride seen so far. A later batch can
+                    # still WIDEN the stride (one new program shape),
+                    # but strides are pow2-bucketed so churn is
+                    # bounded at log2(maxStride/8) widenings per
+                    # column per scan
+                    prev = int(dict_state.get(("slab", i), 0) or 0)
+                    if prev < 0:
+                        stride = 0  # column exceeded maxStride earlier
+                    else:
+                        stride = max(stride, prev)
+                        dict_state[("slab", i)] = stride
+                if not stride and dict_state is not None \
+                        and dt.is_string and blocked_chars > 0:
+                    dict_state[("slab", i)] = -1
+                if stride:
+                    words, lens = np_build_slab(chars_b, offs_b, cap,
+                                                stride)
+                    bufs = (words, bufs[1], lens)
+            slab_metas.append(stride)
+        host_bufs.append(bufs)
+    return host_bufs, dict_metas, slab_metas
 
 
 @jax.tree_util.register_pytree_node_class
@@ -191,91 +280,25 @@ class DeviceBatch:
         lane-contiguous row gathers and packed chars only materialize if
         an operator genuinely reads them (spark.rapids.sql.dict.
         blockedChars)."""
-        from spark_rapids_tpu.columnar.column import (
-            host_dict_encode_stateful, np_build_slab, slab_stride_for,
-        )
         if schema is None:
             schema = Schema.from_pandas(df)
         n = len(df)
         cap = capacity if capacity is not None else bucket_capacity(n)
-        # per-column factorize hints precomputed by the scan pipeline's
-        # decode workers (sources._attach_dict_hints), keyed by column
-        # name; only trusted when the frame was not re-chunked since
-        hints = getattr(df, "attrs", None)
-        hints = hints.get("srt_dict_fact") if hints else None
-        # build every column's device-layout buffers host-side, then ship
-        # the whole batch in ONE device_put (per-buffer uploads each pay
-        # their own dispatch)
-        host_bufs = []
-        dict_metas = []
-        slab_metas = []
-        # positional iteration: join outputs may carry duplicate column names
-        for i, dt in enumerate(schema.dtypes):
-            values, validity = _pandas_to_numpy(df.iloc[:, i], dt)
-            bufs = DeviceColumn.build_host_buffers(values, validity, dt, cap)
-            fact = hints.get(str(df.columns[i])) if hints else None
-            if fact is not None and len(fact[0]) != n:
-                fact = None
-            # ``dict_numerics=False`` (file-scan uploads): only string
-            # columns are dictionary-probed — the numeric probe+encode is
-            # an element-wise pass per column per batch on the upload hot
-            # path, and integer grouping keys ride the dense-key path
-            # (spark.rapids.sql.agg.denseKeys) instead of dictionaries
-            enc = host_dict_encode_stateful(values, validity, dt, cap,
-                                            dict_state, i, fact=fact) \
-                if dict_encode and (dict_numerics or dt.is_string) else None
-            if enc is not None and dt.is_string:
-                # only pay the slab scan when a dictionary was actually
-                # built (high-cardinality columns already bailed at the
-                # probe): NUL-bearing data must not be dictionary-encoded
-                # (see string_host_buffers_have_nul)
-                from spark_rapids_tpu.columnar.column import (
-                    string_host_buffers_have_nul,
-                )
-                if string_host_buffers_have_nul(bufs, n):
-                    enc = None
-                    if dict_state is not None:
-                        dict_state[i] = False  # close for the whole scan
-            if enc is not None:
-                codes, vals = enc
-                bufs = bufs + (codes,)
-                dict_metas.append(vals)
-                slab_metas.append(0)
-            else:
-                dict_metas.append(None)
-                stride = 0
-                if blocked_chars > 0 and dt.is_string:
-                    chars_b, _v, offs_b = bufs[0], bufs[1], bufs[2]
-                    max_len = int((offs_b[1:n + 1] - offs_b[:n]).max()) \
-                        if n else 0
-                    stride = slab_stride_for(max_len, blocked_chars)
-                    if stride and dict_state is not None:
-                        # per-scan stride registry (the slab twin of the
-                        # dictionary registry): LATER batches pad to the
-                        # widest stride seen so far. A later batch can
-                        # still WIDEN the stride (one new program shape),
-                        # but strides are pow2-bucketed so churn is
-                        # bounded at log2(maxStride/8) widenings per
-                        # column per scan
-                        prev = int(dict_state.get(("slab", i), 0) or 0)
-                        if prev < 0:
-                            stride = 0  # column exceeded maxStride earlier
-                        else:
-                            stride = max(stride, prev)
-                            dict_state[("slab", i)] = stride
-                    if not stride and dict_state is not None \
-                            and dt.is_string and blocked_chars > 0:
-                        dict_state[("slab", i)] = -1
-                    if stride:
-                        words, lens = np_build_slab(chars_b, offs_b, cap,
-                                                    stride)
-                        bufs = (words, bufs[1], lens)
-                slab_metas.append(stride)
-            host_bufs.append(bufs)
+        with TRACER.span("upload.build", rows=n,
+                         columns=len(schema.dtypes)) as sp:
+            host_bufs, dict_metas, slab_metas = _build_host_columns(
+                df, schema, n, cap, dict_encode, dict_state, dict_numerics,
+                blocked_chars)
+            nbytes = 0
+            if sp is not None:
+                nbytes = sum(int(getattr(b, "nbytes", 0))
+                             for bufs in host_bufs for b in bufs)
+                sp.set(bytes=nbytes)
         # ``device``: explicit placement for sharded scans (mesh execution
         # uploads partition i to mesh device i so data is born distributed)
-        dev = jax.device_put((host_bufs, np.asarray(n, np.int32)),
-                             device=device)
+        with TRACER.span("upload.put", bytes=nbytes):
+            dev = jax.device_put((host_bufs, np.asarray(n, np.int32)),
+                                 device=device)
         dev_bufs, num_rows = dev
         cols = []
         for dt, bufs, dvals, stride in zip(schema.dtypes, dev_bufs,

@@ -1011,7 +1011,7 @@ class TpuRangeExec(TpuExec):
         schema = self.output_schema()
 
         @functools.partial(jax.jit, static_argnums=(2,))
-        def kernel(lo, n, capacity):
+        def srt_range(lo, n, capacity):  # the program's name on the device
             from spark_rapids_tpu.columnar.column import DeviceColumn
             from spark_rapids_tpu.columnar import dtypes
             idx = jnp.arange(capacity, dtype=jnp.int64)
@@ -1026,8 +1026,8 @@ class TpuRangeExec(TpuExec):
                 hi = min(total, (i + 1) * per)
                 n = max(hi - lo, 0)
                 cap = bucket_capacity(max(per, 1), growth)
-                yield kernel(jnp.asarray(lo, jnp.int64),
-                             jnp.asarray(n, jnp.int32), cap)
+                yield srt_range(jnp.asarray(lo, jnp.int64),
+                                jnp.asarray(n, jnp.int32), cap)
             return run
         return [make(i) for i in range(self.num_partitions)]
 

@@ -161,8 +161,10 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                                           bstart)
         self._totals = cached_jit(sig + "|totals", lambda: jax.jit(totals))
         if jt == "full":
+            # a lambda of its own: cached_jit names the jitted function
+            # after the family, and the module's function keeps its name
             self._match_flags = cached_jit(sig + "|mf", lambda: jax.jit(
-                join_ops.build_match_flags))
+                lambda *a: join_ops.build_match_flags(*a)))
             self._unmatched = cached_jit(sig + "|unm", lambda: jax.jit(
                 lambda b, m, ss: join_ops.unmatched_build_batch(
                     b, m, ss, swap_sides=False),

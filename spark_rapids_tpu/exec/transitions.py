@@ -200,15 +200,22 @@ def upload_partition(ctx: ExecContext, part: Partition, schema: Schema,
                     # rollback reproduces the old path exactly)
                     chunk = df
                 else:
-                    chunk = df.iloc[lo:lo + max_rows].reset_index(drop=True)
-                    hints = getattr(df, "attrs", {}).get("srt_dict_fact")
-                    if hints:
-                        # re-chunked split: slice the worker's factorize
-                        # hints positionally so they survive (from_pandas
-                        # drops length-mismatched hints)
-                        chunk.attrs["srt_dict_fact"] = {
-                            nm: (codes[lo:lo + max_rows], u)
-                            for nm, (codes, u) in hints.items()}
+                    # a sibling of scan.upload, not a child: the copy of
+                    # every byte of a re-chunked split happens out here
+                    with TRACER.span("scan.chunk", partition=i) as _sp:
+                        chunk = df.iloc[lo:lo + max_rows].reset_index(
+                            drop=True)
+                        hints = getattr(df, "attrs", {}).get(
+                            "srt_dict_fact")
+                        if hints:
+                            # re-chunked split: slice the worker's
+                            # factorize hints positionally so they survive
+                            # (from_pandas drops length-mismatched hints)
+                            chunk.attrs["srt_dict_fact"] = {
+                                nm: (codes[lo:lo + max_rows], u)
+                                for nm, (codes, u) in hints.items()}
+                        if _sp is not None:
+                            _sp.set(rows=len(chunk))
                 with TRACER.span("scan.upload", partition=i,
                                  rows=len(chunk)):
                     import time as _time

@@ -21,6 +21,12 @@ Design constraints:
   * Span nesting is tracked per-thread (``depth``/``parent`` ride the event
     args) so reports and tests can validate structure without re-deriving
     it from timestamps.
+  * One query, one identifier: the session publishes the journal's query
+    id (``q-<n>``) in a thread-local (``set_query``), the scan prefetcher
+    hands it to its pool threads (``bind_query``), and every event carries
+    it as ``query`` beside ``depth``/``parent``. A query's start
+    (``begin_query``) drops the events of queries that have ended and
+    keeps those of queries still running on other threads.
   * Optional ``jax.profiler.TraceAnnotation`` passthrough
     (``spark.rapids.tpu.trace.jaxAnnotations``): the same spans appear in a
     captured jax/XLA profiler trace alongside the compiler's own events.
@@ -95,6 +101,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._events: List[Dict[str, Any]] = []
         self._tls = threading.local()
+        self._running: set = set()  # ids of the queries between set/end
         self._epoch = time.perf_counter()
         # cap so a forgotten enabled tracer cannot grow without bound over
         # a long session (~100 bytes/event -> ~50 MB worst case)
@@ -116,6 +123,43 @@ class Tracer:
             self._events = []
             self._dropped = 0
         self._epoch = time.perf_counter()
+
+    # -- the query an event belongs to ---------------------------------------
+    def begin_query(self) -> None:
+        """A query starts on this thread, its id not yet known: drop the
+        events of queries that have ended (and those of no query) and keep
+        the events of queries still running on other threads. For one
+        client that is ``clear()``: the buffer then holds the query that
+        just started and nothing older."""
+        self._tls.query = None
+        with self._lock:
+            if not self._running:
+                self._events = []
+                self._dropped = 0
+                self._epoch = time.perf_counter()
+            else:
+                self._events = [e for e in self._events
+                                if e["args"].get("query") in self._running]
+
+    def set_query(self, qid: str) -> None:
+        """Publish the running query's id for this thread's events."""
+        self._tls.query = qid
+        with self._lock:
+            self._running.add(qid)
+
+    def end_query(self, qid: str) -> None:
+        """The query has ended: its events stay until the next
+        ``begin_query``, and this thread's later events (the collect's
+        concat) still carry its id."""
+        with self._lock:
+            self._running.discard(qid)
+
+    def bind_query(self, qid: Optional[str]) -> None:
+        """A helper thread (the scan decode pool) works for ``qid``."""
+        self._tls.query = qid
+
+    def current_query(self) -> Optional[str]:
+        return getattr(self._tls, "query", None)
 
     # -- recording ----------------------------------------------------------
     def _stack(self) -> List[Span]:
@@ -146,6 +190,9 @@ class Tracer:
 
     def _emit(self, name: str, t0: float, dur: Optional[float],
               args: Dict[str, Any], phase: str = "X") -> None:
+        qid = self.current_query()
+        if qid is not None:
+            args.setdefault("query", qid)
         ev = {"name": name, "ph": phase, "pid": os.getpid(),
               "tid": threading.get_ident(),
               "ts": round((t0 - self._epoch) * 1e6, 1),

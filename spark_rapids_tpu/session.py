@@ -512,100 +512,106 @@ class TpuSparkSession:
         from spark_rapids_tpu.obs.trace import TRACER
 
         conf = self.conf
-        ctx = ExecContext(conf, self)
-        # gather-free execution flags (docs/gatherfree.md): per-value hash
-        # tables, exchange-boundary dictionary merge, codes-on-the-wire
-        from spark_rapids_tpu.columnar import dictionary as _dictionary
-        _dictionary.configure_from_conf(conf)
-        # per-query tracer window: configure from conf, clear so an
-        # exported file holds exactly this query (a speculation re-run is
-        # part of the same query and keeps its spans)
+        # per-query tracer window: configure from conf, then drop the
+        # events of queries that have ended, so an exported file holds
+        # this query (a speculation re-run is part of the same query and
+        # keeps its spans) and whatever still runs on other threads
         trace_path = str(conf.get("spark.rapids.tpu.trace.path", "") or "")
         trace_on = (conf.get_bool("spark.rapids.tpu.trace.enabled", False)
                     or bool(trace_path))
         TRACER.configure(trace_on, conf.get_bool(
             "spark.rapids.tpu.trace.jaxAnnotations", False))
         if trace_on:
-            TRACER.clear()
-        # reset NOW, not on the success path: a failed query must not
-        # leave the previous query's profile/metrics masquerading as "the
-        # last executed query" in a post-mortem
-        self.last_query_metrics = {}
-        self.last_node_times = {}
-        self.last_plan = None
-        self.last_profile = None
-        self.last_aqe = None
-        # process-wide registry snapshot: the profile reports this query's
-        # DELTA of spill/fetch/compile activity
-        global_before = (obs_metrics.REGISTRY.values()
-                         if ctx.metrics_enabled else None)
-        # truncation counters snapshot: the profile's observability
-        # section reports this query's DELTA, not the process totals.
-        # The 5th element is the compile-ledger seq watermark: the
-        # profile's ``compiles`` section covers entries recorded after
-        # it; the 6th is the sync-ledger watermark feeding the profile's
-        # ``syncs`` section + occupancy estimate
-        from spark_rapids_tpu.obs.compileledger import LEDGER as _LEDGER
-        from spark_rapids_tpu.obs.syncledger import SYNC_LEDGER as _SYNCS
-        obs_before = (TRACER.dropped, obs_events.EVENTS.dropped,
-                      obs_events.EVENTS.rotations,
-                      obs_events.EVENTS.rotate_failures,
-                      _LEDGER.seq, _SYNCS.seq) \
-            if ctx.metrics_enabled else None
-        if ctx.metrics_enabled:
-            # the scan pipeline's peak gauge is state, not flow: reset it
-            # per query so the profile's queueDepthPeak is THIS query's
-            # peak, not the process's all-time high (obs/profile.py)
-            obs_metrics.REGISTRY.gauge("scan.prefetch.queueDepthPeak") \
-                .set(0)
-        t_query0 = time.perf_counter()
-        # durable event journal (obs/events.py): the query window opens
-        # HERE so planning failures are on record too; the failure path
-        # below dumps the always-on flight recorder into the log
-        obs_events.EVENTS.configure_from_conf(conf)
-        # compile ledger (obs/compileledger.py): per-cause attribution of
-        # every backend compile this query triggers
-        from spark_rapids_tpu.obs.compileledger import LEDGER
-        LEDGER.configure_from_conf(conf)
-        # host-sync ledger (obs/syncledger.py): per-site attribution of
-        # every device<->host blocking point, plus the opt-in transfer-
-        # guard coverage audit (spark.rapids.tpu.debug.transferGuard)
-        from spark_rapids_tpu.obs import syncledger as _syncledger
-        _SYNCS.configure_from_conf(conf)
-        _guard_mode = str(conf.get(
-            "spark.rapids.tpu.debug.transferGuard", "off") or "off")
-        _syncledger.set_guard_mode(
-            _guard_mode if _guard_mode in ("log", "disallow") else None)
-        # zero-warm-up layer: coarse secondary-dimension shape buckets
-        # (one compile serves a dimension range), the cross-process
-        # shared compile cache (one compile per CLUSTER) and the AOT
-        # pre-warm pass (history compiles before traffic). All three
-        # default off/empty = byte-identical engine behavior.
-        from spark_rapids_tpu.obs import compilecache as _compilecache
-        from spark_rapids_tpu.serving import prewarm as _prewarm
-        from spark_rapids_tpu.utils import kernelcache as _kernelcache
-        _kernelcache.configure_shape_buckets_from_conf(conf)
-        _compilecache.SHARED.configure_from_conf(conf)
-        _prewarm.maybe_start_from_conf(conf)
-        # live monitoring service (obs/monitor.py): starts/stops the
-        # embedded HTTP server on conf change and keeps the progress
-        # tracker's single hot-path flag in lockstep. Off (the default)
-        # this is two conf reads and ctx.progress stays None.
-        from spark_rapids_tpu.obs import monitor as obs_monitor
-        from spark_rapids_tpu.obs.progress import PROGRESS
-        obs_monitor.maybe_serve(conf)
-        tenant, job_desc = self._job_group
-        qid = obs_events.EVENTS.query_start(
-            tenant=tenant,
-            confFingerprint=obs_events.conf_fingerprint(conf._settings))
-        qp = None
-        if PROGRESS.enabled:
-            qp = PROGRESS.begin(qid, tenant=tenant, description=job_desc)
-            ctx.progress = qp
-        # per-thread execution scope: register/release of per-query
-        # resources (transients, shuffle ids) resolves to THIS context
-        # while the query runs on this thread
-        self._exec_scope.ctx = ctx
+            TRACER.begin_query()
+        with TRACER.span("query.begin"):
+            ctx = ExecContext(conf, self)
+            # gather-free execution flags (docs/gatherfree.md): per-value hash
+            # tables, exchange-boundary dictionary merge, codes-on-the-wire
+            from spark_rapids_tpu.columnar import dictionary as _dictionary
+            _dictionary.configure_from_conf(conf)
+            # reset NOW, not on the success path: a failed query must not
+            # leave the previous query's profile/metrics masquerading as "the
+            # last executed query" in a post-mortem
+            self.last_query_metrics = {}
+            self.last_node_times = {}
+            self.last_plan = None
+            self.last_profile = None
+            self.last_aqe = None
+            # process-wide registry snapshot: the profile reports this query's
+            # DELTA of spill/fetch/compile activity
+            global_before = (obs_metrics.REGISTRY.values()
+                             if ctx.metrics_enabled else None)
+            # truncation counters snapshot: the profile's observability
+            # section reports this query's DELTA, not the process totals.
+            # The 5th element is the compile-ledger seq watermark: the
+            # profile's ``compiles`` section covers entries recorded after
+            # it; the 6th is the sync-ledger watermark feeding the profile's
+            # ``syncs`` section + occupancy estimate
+            from spark_rapids_tpu.obs.compileledger import LEDGER as _LEDGER
+            from spark_rapids_tpu.obs.syncledger import SYNC_LEDGER as _SYNCS
+            obs_before = (TRACER.dropped, obs_events.EVENTS.dropped,
+                          obs_events.EVENTS.rotations,
+                          obs_events.EVENTS.rotate_failures,
+                          _LEDGER.seq, _SYNCS.seq) \
+                if ctx.metrics_enabled else None
+            if ctx.metrics_enabled:
+                # the scan pipeline's peak gauge is state, not flow: reset it
+                # per query so the profile's queueDepthPeak is THIS query's
+                # peak, not the process's all-time high (obs/profile.py)
+                obs_metrics.REGISTRY.gauge("scan.prefetch.queueDepthPeak") \
+                    .set(0)
+            t_query0 = time.perf_counter()
+            # durable event journal (obs/events.py): the query window opens
+            # HERE so planning failures are on record too; the failure path
+            # below dumps the always-on flight recorder into the log
+            obs_events.EVENTS.configure_from_conf(conf)
+            # compile ledger (obs/compileledger.py): per-cause attribution of
+            # every backend compile this query triggers
+            from spark_rapids_tpu.obs.compileledger import LEDGER
+            LEDGER.configure_from_conf(conf)
+            # host-sync ledger (obs/syncledger.py): per-site attribution of
+            # every device<->host blocking point, plus the opt-in transfer-
+            # guard coverage audit (spark.rapids.tpu.debug.transferGuard)
+            from spark_rapids_tpu.obs import syncledger as _syncledger
+            _SYNCS.configure_from_conf(conf)
+            _guard_mode = str(conf.get(
+                "spark.rapids.tpu.debug.transferGuard", "off") or "off")
+            _syncledger.set_guard_mode(
+                _guard_mode if _guard_mode in ("log", "disallow") else None)
+            # zero-warm-up layer: coarse secondary-dimension shape buckets
+            # (one compile serves a dimension range), the cross-process
+            # shared compile cache (one compile per CLUSTER) and the AOT
+            # pre-warm pass (history compiles before traffic). All three
+            # default off/empty = byte-identical engine behavior.
+            from spark_rapids_tpu.obs import compilecache as _compilecache
+            from spark_rapids_tpu.serving import prewarm as _prewarm
+            from spark_rapids_tpu.utils import kernelcache as _kernelcache
+            _kernelcache.configure_shape_buckets_from_conf(conf)
+            _compilecache.SHARED.configure_from_conf(conf)
+            _prewarm.maybe_start_from_conf(conf)
+            # live monitoring service (obs/monitor.py): starts/stops the
+            # embedded HTTP server on conf change and keeps the progress
+            # tracker's single hot-path flag in lockstep. Off (the default)
+            # this is two conf reads and ctx.progress stays None.
+            from spark_rapids_tpu.obs import monitor as obs_monitor
+            from spark_rapids_tpu.obs.progress import PROGRESS
+            obs_monitor.maybe_serve(conf)
+            tenant, job_desc = self._job_group
+            qid = obs_events.EVENTS.query_start(
+                tenant=tenant,
+                confFingerprint=obs_events.conf_fingerprint(conf._settings))
+            if trace_on:
+                # every span of this thread carries the journal's id from
+                # here on (query.begin too: a span is stamped as it closes)
+                TRACER.set_query(qid)
+            qp = None
+            if PROGRESS.enabled:
+                qp = PROGRESS.begin(qid, tenant=tenant, description=job_desc)
+                ctx.progress = qp
+            # per-thread execution scope: register/release of per-query
+            # resources (transients, shuffle ids) resolves to THIS context
+            # while the query runs on this thread
+            self._exec_scope.ctx = ctx
         try:
             # transfer-guard audit: untracked device->host transfers
             # outside any sync_scope are logged (or raise) while the
@@ -613,7 +619,7 @@ class TpuSparkSession:
             with _syncledger.guard_context(_guard_mode):
                 plan, outs, ctx = self._plan_and_run(
                     logical, ctx, conf, obs_metrics, global_before,
-                    t_query0, trace_on, trace_path, obs_before)
+                    t_query0, obs_before)
         except BaseException as e:
             wall_s = round(time.perf_counter() - t_query0, 6)
             err = f"{type(e).__name__}: {e}"[:300]
@@ -649,19 +655,24 @@ class TpuSparkSession:
         finally:
             self._exec_scope.ctx = None
             _syncledger.set_guard_mode(None)
-        wall_s = round(time.perf_counter() - t_query0, 6)
-        rows_out = self._count_rows(outs)
-        obs_events.EVENTS.query_end(
-            status="success", wall_s=wall_s, rowsReturned=rows_out,
-            **self._coverage_fields(plan, ctx))
-        self._note_tenant(tenant, "success", wall_s, rows_out)
-        if qp is not None:
-            PROGRESS.finish(qp, "success")
-        self._sweep_adaptive_caches()
+            if trace_on:
+                TRACER.end_query(qid)
+        with TRACER.span("query.finish"):
+            wall_s = round(time.perf_counter() - t_query0, 6)
+            rows_out = self._count_rows(outs)
+            obs_events.EVENTS.query_end(
+                status="success", wall_s=wall_s, rowsReturned=rows_out,
+                **self._coverage_fields(plan, ctx))
+            self._note_tenant(tenant, "success", wall_s, rows_out)
+            if qp is not None:
+                PROGRESS.finish(qp, "success")
+            self._sweep_adaptive_caches()
+        if trace_on and trace_path:
+            TRACER.export_chrome(trace_path)
         return plan, outs
 
     def _plan_and_run(self, logical, ctx, conf, obs_metrics, global_before,
-                      t_query0, trace_on, trace_path, obs_before=None):
+                      t_query0, obs_before=None):
         """The planning + execution body of ``_execute``, factored out so
         the event journal's failure path wraps it in one place. Returns
         (plan, outputs, final ExecContext) — a speculation re-run swaps
@@ -669,39 +680,37 @@ class TpuSparkSession:
         actually executed."""
         import time
 
-        from spark_rapids_tpu.obs import events as obs_events
         from spark_rapids_tpu.obs.trace import TRACER
-        from spark_rapids_tpu.sql.overrides import (
-            TpuOverrides, TransitionOverrides, assert_is_on_tpu,
-        )
 
-        # record rename provenance (alias -> source names) from the
-        # LOGICAL plan — physical projections can fuse away, but the
-        # logical tree always carries `.alias(...)` / USING-join renames.
-        # Advisory input to the dense-key join's stats resolution; bounds
-        # are device-verified there, so staleness only loosens them.
-        self._note_rename_aliases(logical)
-        # column pruning (narrowing projects above filters / semi-anti
-        # build sides), then projection pushdown: mark file scans with the
-        # query's referenced column subset before planning (sql/pushdown.py)
-        from spark_rapids_tpu.sql.pushdown import (
-            annotate_scan_pruning, prune_filter_columns,
-        )
-        logical = prune_filter_columns(logical)
-        annotate_scan_pruning(logical)
-        planner = Planner(conf)
-        # tiny-query overhead-floor fast path: single-partition planning
-        # + semaphore/shrink-sync/bookkeeping elision (docs/gatherfree.md);
-        # mesh execution keeps the general plan (data is born distributed)
-        if getattr(self, "mesh", None) is None:
-            planner.note_input_size(logical)
-        ctx.small_query = planner.small_query
-        ctx.small_query_keep_sem = planner.small_query_keep_sem
-        if isinstance(logical, lp.LogicalLimit):
-            # root-position limit plans as one CollectLimit operator
-            cpu_plan = planner.plan_collect_limit(logical)
-        else:
-            cpu_plan = planner.plan(logical)
+        with TRACER.span("plan.logical"):
+            # record rename provenance (alias -> source names) from the
+            # LOGICAL plan — physical projections can fuse away, but the
+            # logical tree always carries `.alias(...)` / USING-join renames.
+            # Advisory input to the dense-key join's stats resolution; bounds
+            # are device-verified there, so staleness only loosens them.
+            self._note_rename_aliases(logical)
+            # column pruning (narrowing projects above filters / semi-anti
+            # build sides), then projection pushdown: mark file scans with
+            # the query's referenced column subset before planning
+            # (sql/pushdown.py)
+            from spark_rapids_tpu.sql.pushdown import (
+                annotate_scan_pruning, prune_filter_columns,
+            )
+            logical = prune_filter_columns(logical)
+            annotate_scan_pruning(logical)
+            planner = Planner(conf)
+            # tiny-query overhead-floor fast path: single-partition planning
+            # + semaphore/shrink-sync/bookkeeping elision (docs/gatherfree.md);
+            # mesh execution keeps the general plan (data is born distributed)
+            if getattr(self, "mesh", None) is None:
+                planner.note_input_size(logical)
+            ctx.small_query = planner.small_query
+            ctx.small_query_keep_sem = planner.small_query_keep_sem
+            if isinstance(logical, lp.LogicalLimit):
+                # root-position limit plans as one CollectLimit operator
+                cpu_plan = planner.plan_collect_limit(logical)
+            else:
+                cpu_plan = planner.plan(logical)
         # adaptive query execution (sql/adaptive/): cut the plan into
         # stages at hash-exchange boundaries, materialize map sides,
         # re-optimize the remainder from the observed sizes. Off (the
@@ -715,8 +724,75 @@ class TpuSparkSession:
             if has_adaptive_stages(cpu_plan):
                 return self._run_adaptive(cpu_plan, ctx, conf,
                                           obs_metrics, global_before,
-                                          t_query0, trace_on, trace_path,
-                                          obs_before)
+                                          t_query0, obs_before)
+        with TRACER.span("plan.rewrite") as sp:
+            plan, outs, cache_key, plan_cache_hit = self._rewrite_plan(
+                cpu_plan, logical, conf, ctx)
+            if sp is not None:
+                sp.set(plan_cache_hit=plan_cache_hit)
+        if outs is not None:  # result-cache hit: nothing to execute
+            self._finish_query(plan, ctx, conf, obs_metrics,
+                               global_before, t_query0, obs_before)
+            return plan, outs, ctx
+        if ctx.speculate and any(
+                type(n).__name__ in ("TpuWriteExec", "CpuWriteExec")
+                for n in plan.walk()):
+            # writes commit files DURING the drain; a speculation miss
+            # detected after it would have committed truncated output and
+            # the re-execution would collide with the committed path.
+            # Capacity syncs stay exact under write commands.
+            ctx.speculate = False
+        try:
+            with TRACER.span("Query", speculative=bool(ctx.speculate)):
+                outs = self._drain(plan, ctx, conf)
+            if ctx.spec_pending and not self._verify_speculation(ctx):
+                # a speculated capacity did not cover its actual size:
+                # the speculative output may be truncated. Re-execute the
+                # same physical plan without speculation (the cache
+                # entries that missed were dropped above, so the next
+                # execution re-learns them with the exact sync).
+                self.capacity_spec_reruns += 1
+                # ratios learned from a misspeculated run may be garbage
+                # (a dense-group miss collapses group counts)
+                for sig in ctx.ratio_writes:
+                    self.agg_ratio_cache.pop(sig, None)
+                self.release_active_shuffles(ctx)
+                self.release_transient_buffers(ctx)
+                prev_progress = ctx.progress
+                small = ctx.small_query
+                keep_sem = ctx.small_query_keep_sem
+                ctx = ExecContext(conf, self, speculate=False)
+                ctx.progress = prev_progress  # same query, same record
+                ctx.small_query = small
+                ctx.small_query_keep_sem = keep_sem
+                # re-point this thread's execution scope at the fresh
+                # context so the re-run's registrations release with IT
+                self._exec_scope.ctx = ctx
+                with TRACER.span("Query", speculative=False,
+                                 rerun=True):
+                    outs = self._drain(plan, ctx, conf)
+        finally:
+            self.release_active_shuffles(ctx)
+            self.release_transient_buffers(ctx)
+        if cache_key is not None:
+            # opt-in result cache: remember (plan, outputs) for identical
+            # dashboard-style re-submissions (deterministic reads only)
+            self._serving().result_cache.maybe_put(
+                cache_key, cpu_plan, plan, outs, conf, self._job_group[0])
+        self._finish_query(plan, ctx, conf, obs_metrics, global_before,
+                           t_query0, obs_before)
+        return plan, outs, ctx
+
+    def _rewrite_plan(self, cpu_plan, logical, conf, ctx):
+        """The ``plan.rewrite`` span of ``_plan_and_run``: serving-cache
+        look-ups, the tag+convert rewrite onto TPU operators and the
+        plan's journal events. Returns (plan, the result cache's outputs
+        or None, serving cache key, whether the plan cache hit)."""
+        from spark_rapids_tpu.obs import events as obs_events
+        from spark_rapids_tpu.sql.overrides import (
+            TpuOverrides, TransitionOverrides, assert_is_on_tpu,
+        )
+
         # cross-query serving caches (serving/caches.py), keyed by
         # (plan digest, conf fingerprint, source data versions):
         #   * result cache (opt-in): identical dashboard-style query ->
@@ -738,10 +814,7 @@ class TpuSparkSession:
                     rows=self._count_rows(outs))
                 if self.capture_plans:
                     self.captured_plans.append(plan)
-                self._finish_query(plan, ctx, conf, obs_metrics,
-                                   global_before, t_query0, trace_on,
-                                   trace_path, obs_before)
-                return plan, outs, ctx
+                return plan, outs, cache_key, False
         plan = caches.plan_cache.get(cache_key, conf, tenant) \
             if cache_key is not None else None
         plan_cache_hit = plan is not None
@@ -793,60 +866,10 @@ class TpuSparkSession:
                     "cpuFallback", op=meta.plan.name,
                     describe=meta.plan.describe()[:200],
                     reasons=list(meta.reasons))
-        # final output to host
-        outs: List[pd.DataFrame] = []
-        if ctx.speculate and any(
-                type(n).__name__ in ("TpuWriteExec", "CpuWriteExec")
-                for n in plan.walk()):
-            # writes commit files DURING the drain; a speculation miss
-            # detected after it would have committed truncated output and
-            # the re-execution would collide with the committed path.
-            # Capacity syncs stay exact under write commands.
-            ctx.speculate = False
-        try:
-            with TRACER.span("Query", speculative=bool(ctx.speculate)):
-                outs = self._drain(plan, ctx, conf)
-            if ctx.spec_pending and not self._verify_speculation(ctx):
-                # a speculated capacity did not cover its actual size:
-                # the speculative output may be truncated. Re-execute the
-                # same physical plan without speculation (the cache
-                # entries that missed were dropped above, so the next
-                # execution re-learns them with the exact sync).
-                self.capacity_spec_reruns += 1
-                # ratios learned from a misspeculated run may be garbage
-                # (a dense-group miss collapses group counts)
-                for sig in ctx.ratio_writes:
-                    self.agg_ratio_cache.pop(sig, None)
-                self.release_active_shuffles(ctx)
-                self.release_transient_buffers(ctx)
-                prev_progress = ctx.progress
-                small = ctx.small_query
-                keep_sem = ctx.small_query_keep_sem
-                ctx = ExecContext(conf, self, speculate=False)
-                ctx.progress = prev_progress  # same query, same record
-                ctx.small_query = small
-                ctx.small_query_keep_sem = keep_sem
-                # re-point this thread's execution scope at the fresh
-                # context so the re-run's registrations release with IT
-                self._exec_scope.ctx = ctx
-                with TRACER.span("Query", speculative=False,
-                                 rerun=True):
-                    outs = self._drain(plan, ctx, conf)
-        finally:
-            self.release_active_shuffles(ctx)
-            self.release_transient_buffers(ctx)
-        if cache_key is not None:
-            # opt-in result cache: remember (plan, outputs) for identical
-            # dashboard-style re-submissions (deterministic reads only)
-            caches.result_cache.maybe_put(cache_key, cpu_plan, plan,
-                                          outs, conf, tenant)
-        self._finish_query(plan, ctx, conf, obs_metrics, global_before,
-                           t_query0, trace_on, trace_path, obs_before)
-        return plan, outs, ctx
+        return plan, None, cache_key, plan_cache_hit
 
     def _run_adaptive(self, cpu_plan, ctx, conf, obs_metrics,
-                      global_before, t_query0, trace_on, trace_path,
-                      obs_before):
+                      global_before, t_query0, obs_before):
         """Adaptive branch of ``_plan_and_run``: the executor owns
         per-stage conversion + materialization + re-planning; this wraps
         it with the same event/metrics/profile bookkeeping as the legacy
@@ -889,46 +912,45 @@ class TpuSparkSession:
         if ctx.progress is not None:
             ctx.progress.set_plan(plan)
         self._finish_query(plan, ctx, conf, obs_metrics, global_before,
-                           t_query0, trace_on, trace_path, obs_before)
+                           t_query0, obs_before)
         return plan, outs, ctx
 
     def _finish_query(self, plan, ctx, conf, obs_metrics, global_before,
-                      t_query0, trace_on, trace_path, obs_before):
+                      t_query0, obs_before):
         """Shared post-run bookkeeping of both execution paths:
         per-operator SQL metrics of the last executed query (the
         reference surfaces these in the Spark UI, GpuExec.scala:61-67),
-        the memory runtime's counters, the profile report and the trace
-        export."""
+        the memory runtime's counters and the profile report. The trace
+        export waits for ``_execute``'s last span."""
         import time
 
         from spark_rapids_tpu.obs.trace import TRACER
-        if ctx.metrics_enabled:
-            cat = self.buffer_catalog
-            mem = {
-                "allocatedBytes": self.device_manager.allocated,
-                "spillCount": self.memory_event_handler.spill_count,
-                "deviceStoreBytes": cat.device_store.total_size,
-                "hostStoreBytes": cat.host_store.total_size,
-                "diskStoreBytes": cat.disk_store.total_size,
-            }
-            for k, v in mem.items():
-                ctx.registry.gauge(k, op="memory").set(v)
-            # per-tier resident bytes into the process-wide registry
-            cat.publish_metrics()
-        self.last_query_metrics = ctx.metrics
-        self.last_node_times = ctx.node_times  # profiler (syncEachOp)
-        self.last_plan = plan
-        self.last_profile = None
-        if ctx.metrics_enabled:
-            from spark_rapids_tpu.obs.profile import build_profile
-            delta = obs_metrics.registry_delta(
-                global_before, obs_metrics.REGISTRY.values())
-            self.last_profile = build_profile(
-                plan, ctx, delta,
-                wall_s=time.perf_counter() - t_query0,
-                obs_before=obs_before)
-        if trace_on and trace_path:
-            TRACER.export_chrome(trace_path)
+        with TRACER.span("query.finish"):
+            if ctx.metrics_enabled:
+                cat = self.buffer_catalog
+                mem = {
+                    "allocatedBytes": self.device_manager.allocated,
+                    "spillCount": self.memory_event_handler.spill_count,
+                    "deviceStoreBytes": cat.device_store.total_size,
+                    "hostStoreBytes": cat.host_store.total_size,
+                    "diskStoreBytes": cat.disk_store.total_size,
+                }
+                for k, v in mem.items():
+                    ctx.registry.gauge(k, op="memory").set(v)
+                # per-tier resident bytes into the process-wide registry
+                cat.publish_metrics()
+            self.last_query_metrics = ctx.metrics
+            self.last_node_times = ctx.node_times  # profiler (syncEachOp)
+            self.last_plan = plan
+            self.last_profile = None
+            if ctx.metrics_enabled:
+                from spark_rapids_tpu.obs.profile import build_profile
+                delta = obs_metrics.registry_delta(
+                    global_before, obs_metrics.REGISTRY.values())
+                self.last_profile = build_profile(
+                    plan, ctx, delta,
+                    wall_s=time.perf_counter() - t_query0,
+                    obs_before=obs_before)
 
     # --- observability ------------------------------------------------------
     def _coverage_fields(self, plan, ctx=None) -> dict:
@@ -1087,15 +1109,21 @@ class TpuSparkSession:
                         amap.setdefault(out_name, set()).add(e.name)
 
     def _drain(self, plan, ctx, conf) -> List[pd.DataFrame]:
+        from spark_rapids_tpu.obs.trace import TRACER
         outs: List[pd.DataFrame] = []
+        # the recursive partition planning (split planning, Parquet
+        # footers, prefetcher start) closes before the first pull
+        with TRACER.span("plan.partitions") as sp:
+            parts = plan.executed_partitions(ctx)
+            if sp is not None:
+                sp.set(partitions=len(parts))
         if plan.columnar_output:
             # drain every partition's device batches first, then convert
             # with to_pandas_many: TWO device->host round trips for the
             # whole result set instead of two per output partition
             from spark_rapids_tpu.columnar.batch import DeviceBatch
-            final = plan
             batches: List[DeviceBatch] = []
-            for part in final.executed_partitions(ctx):
+            for part in parts:
                 try:
                     batches.extend(part())
                 finally:
@@ -1125,7 +1153,7 @@ class TpuSparkSession:
                     ctx.metric_add("Collect", "fetchTime",
                                    _time.perf_counter() - _t0)
         else:
-            for part in plan.executed_partitions(ctx):
+            for part in parts:
                 for df in part():
                     outs.append(df)
         return outs
@@ -1606,7 +1634,12 @@ class DataFrame:
         # null-mask-preserving concat: partition frames can mix masked
         # and plain dtypes across partitions (exec/cpu.py)
         from spark_rapids_tpu.exec.cpu import concat_host_frames
-        return concat_host_frames(outs, self.schema)
+        from spark_rapids_tpu.obs.trace import TRACER
+        with TRACER.span("collect.concat") as sp:
+            out = concat_host_frames(outs, self.schema)
+            if sp is not None:
+                sp.set(rows=len(out))
+        return out
 
     toPandas = collect
 

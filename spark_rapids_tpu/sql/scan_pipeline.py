@@ -156,6 +156,9 @@ class ScanPrefetcher:
         # scan (exact aggregates live in the REGISTRY timers/counters)
         self._budget_stalled = False
         self._stall_events = 0
+        # built on the query's thread: the pool threads' scan.decode*
+        # spans carry the id of the query they decode for
+        self._query = TRACER.current_query() if TRACER.enabled else None
 
     _EVENT_CAP = 16
 
@@ -166,6 +169,8 @@ class ScanPrefetcher:
             with self._lock:
                 if self._cancelled:
                     return None
+            if TRACER.enabled:
+                TRACER.bind_query(self._query)
             with _DECODE_TIME.time():
                 with TRACER.span("scan.decode", split=i,
                                  file=path or "<memory>"):

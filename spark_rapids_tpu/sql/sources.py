@@ -16,6 +16,7 @@ import pandas as pd
 
 from spark_rapids_tpu.columnar.batch import Schema
 from spark_rapids_tpu.exec.base import ExecContext, Partition
+from spark_rapids_tpu.obs.trace import TRACER
 
 
 _DATA_UID_COUNTER = itertools.count(1)
@@ -315,9 +316,35 @@ class ParquetSource(DataSource):
                 keep.append((p, rg, pvals))
         return keep, len(self.splits) - len(keep)
 
+    def _read_row_group(self, path: str, rg: int):
+        """One row group as an Arrow table: read, decompress and page
+        decode are one call into Arrow's C++, so one span."""
+        with TRACER.span("scan.decode.read", file=path,
+                         row_group=rg) as sp:
+            table = self._pq.ParquetFile(path).read_row_group(
+                rg, columns=self.columns)
+            if sp is not None:
+                sp.set(bytes=table.nbytes)
+        return table
+
+    def _append_partition_values(self, df: pd.DataFrame,
+                                 pvals) -> pd.DataFrame:
+        """The hive partition keys of the split's path as columns."""
+        for k in self._pkeys:
+            v = (_infer_partition_value(pvals[k])
+                 if k in pvals else None)
+            dt = self._pkey_dtypes[k]
+            if v is not None and not dt.is_string:
+                v = dt.np_dtype.type(v)
+            elif v is not None:
+                v = str(v)
+            df[k] = pd.Series([v] * len(df),
+                              dtype=dt.pandas_nullable
+                              if not dt.is_string else object)
+        return df
+
     def cpu_partitions(self, ctx: ExecContext,
                        filters=None) -> List[Partition]:
-        pq = self._pq
         splits = self.splits
         if filters:
             splits, pruned = self.prune_splits(filters)
@@ -339,21 +366,15 @@ class ParquetSource(DataSource):
 
         def decode_task(path: str, rg: int, pvals):
             def decode():
-                f = pq.ParquetFile(path)
-                table = f.read_row_group(rg, columns=self.columns)
-                df = _arrow_decode(table, direct)
-                for k in self._pkeys:
-                    v = (_infer_partition_value(pvals[k])
-                         if k in pvals else None)
-                    dt = self._pkey_dtypes[k]
-                    if v is not None and not dt.is_string:
-                        v = dt.np_dtype.type(v)
-                    elif v is not None:
-                        v = str(v)
-                    df[k] = pd.Series([v] * len(df),
-                                      dtype=dt.pandas_nullable
-                                      if not dt.is_string else object)
-                return _attach_dict_hints(df) if pipelined else df
+                table = self._read_row_group(path, rg)
+                with TRACER.span("scan.decode.convert") as sp:
+                    df = self._append_partition_values(
+                        _arrow_decode(table, direct), pvals)
+                    if pipelined:
+                        df = _attach_dict_hints(df)
+                    if sp is not None:
+                        sp.set(rows=len(df))
+                return df
             return decode
         if not splits:
             def empty():
@@ -389,7 +410,6 @@ class ParquetSource(DataSource):
             if ctx.session else None
         columns = list(self.columns)
         dtypes_by_name = dict(zip(self.schema.names, self.schema.dtypes))
-        pkeys, pkey_dtypes = list(self._pkeys), dict(self._pkey_dtypes)
 
         def decode_task(path: str, rg: int, pvals):
             def decode():
@@ -406,20 +426,13 @@ class ParquetSource(DataSource):
                 # classic decode_task (partition-value columns appended)
                 df = raw
                 if df is None:
-                    f = self._pq.ParquetFile(path)
-                    table = f.read_row_group(rg, columns=columns)
-                    df = _arrow_decode(table, direct)
-                for k in pkeys:
-                    v = (_infer_partition_value(pvals[k])
-                         if k in pvals else None)
-                    dt = pkey_dtypes[k]
-                    if v is not None and not dt.is_string:
-                        v = dt.np_dtype.type(v)
-                    elif v is not None:
-                        v = str(v)
-                    df[k] = pd.Series([v] * len(df),
-                                      dtype=dt.pandas_nullable
-                                      if not dt.is_string else object)
+                    table = self._read_row_group(path, rg)
+                with TRACER.span("scan.decode.convert") as sp:
+                    if df is None:
+                        df = _arrow_decode(table, direct)
+                    df = self._append_partition_values(df, pvals)
+                    if sp is not None:
+                        sp.set(rows=len(df))
                 return df
             return decode
         if not splits:

@@ -12,73 +12,13 @@ per input shape (capacity bucket).
 from __future__ import annotations
 
 import os
+import re
 import threading
 from typing import Any, Callable, Dict
 
 _CACHE: Dict[str, Any] = {}
 _LOCK = threading.Lock()
 _STATS = {"hits": 0, "misses": 0}
-
-# SRT_KERNEL_PROFILE=1: wrap every cached kernel so each call forces
-# device completion and records (calls, seconds) per signature. True
-# per-KERNEL wall attribution — finer than the per-operator syncEachOp —
-# at the cost of one blocking fetch per call; compare kernels
-# by their EXCESS over that baseline. Diagnostics only, never default.
-_PROFILE = os.environ.get("SRT_KERNEL_PROFILE", "") == "1"
-_PROF: Dict[str, list] = {}
-
-
-def _force_complete(out) -> None:
-    """Wait for the kernel's result by fetching ONE element of its
-    smallest leaf — fetching a whole buffer would add its transfer
-    time to the measurement and misattribute it as kernel compute."""
-    import jax
-    leaves = [leaf for leaf in jax.tree_util.tree_leaves(out)
-              if hasattr(leaf, "shape")]
-    if not leaves:
-        return
-    leaf = min(leaves, key=lambda x: getattr(x, "nbytes", 1 << 60))
-    if getattr(leaf, "nbytes", 0) > 4096 and leaf.ndim >= 1:
-        leaf = leaf.reshape(-1)[:1]
-    jax.device_get(leaf)
-
-
-def _tree_bytes(tree) -> int:
-    import jax
-    return sum(int(leaf.nbytes)
-               for leaf in jax.tree_util.tree_leaves(tree)
-               if hasattr(leaf, "nbytes"))
-
-
-def _wrap_profiled(signature: str, fn):
-    import time
-
-    def wrapped(*a, **kw):
-        t0 = time.perf_counter()
-        out = fn(*a, **kw)
-        _force_complete(out)
-        dt = time.perf_counter() - t0
-        nb = _tree_bytes((a, kw)) + _tree_bytes(out)
-        with _LOCK:
-            ent = _PROF.setdefault(signature, [0, 0.0, 0])
-            ent[0] += 1
-            ent[1] += dt
-            ent[2] += nb
-        return out
-    return wrapped
-
-
-def kernel_profile() -> Dict[str, list]:
-    """signature -> [calls, total_seconds, arg+result_bytes] recorded
-    under SRT_KERNEL_PROFILE=1 (reset with kernel_profile_reset)."""
-    with _LOCK:
-        return {k: list(v) for k, v in _PROF.items()}
-
-
-def kernel_profile_reset() -> None:
-    with _LOCK:
-        _PROF.clear()
-
 
 # Observability handles, resolved once: the hit path runs per kernel
 # fetch (per batch per operator) and must stay one lock + one counter add
@@ -188,22 +128,52 @@ def cache_snapshot() -> Dict[str, Any]:
         return dict(_CACHE)
 
 
+def kernel_family(signature: str) -> str:
+    """The signature's text before the first ``|`` (``aggupd``, ``join``,
+    ``concat``...), cut to an identifier: the name of the host span
+    around a kernel's dispatch and of its program on the device."""
+    return re.sub(r"\W", "_", signature.split("|", 1)[0]) or "kernel"
+
+
+def name_program(fn, family: str):
+    """Name the device program of a ``jax.jit``-wrapped Python function
+    ``jit_srt_<family>`` (a lambda's is ``jit__lambda_``, which puts no
+    device second down to an operator). jax reads the wrapped function's
+    name when it first traces, so this runs before the first call. The
+    name is a pure function of the family — no counter, no shape — or the
+    persistent compile cache, which hashes it, stops hitting across
+    processes. Returns ``fn``."""
+    inner = getattr(fn, "__wrapped__", None)
+    if inner is not None:
+        try:
+            inner.__name__ = inner.__qualname__ = "srt_" + family
+        except (AttributeError, TypeError):
+            pass
+    return fn
+
+
 def _wrap_ledgered(signature: str, fn):
-    """Compile-ledger dispatch context (obs/compileledger.py): every call
-    of a cached kernel publishes its signature + argument references to a
-    thread-local for the call's duration, so a backend compile fired
-    inside it knows its kernel identity and input shape signature. The
-    steady-state (no-compile) overhead is one flag check, two
-    thread-local stores and a try/finally; with the ledger disabled it is
-    the flag check alone."""
+    """Dispatch context of a cached kernel. The ``dispatch.<family>``
+    span times the host side of every call (an asynchronous dispatch:
+    the device's own time by family is read from the profiler trace,
+    where ``name_program`` put the family). Compile ledger
+    (obs/compileledger.py): every call publishes its signature +
+    argument references to a thread-local for the call's duration, so a
+    backend compile fired inside it knows its kernel identity and input
+    shape signature. The steady-state (no-compile) overhead is two flag
+    checks, two thread-local stores and a try/finally; with the ledger
+    disabled it is the flag checks alone."""
     from spark_rapids_tpu.obs import compileledger as _cl
+    span = "dispatch." + kernel_family(signature)
 
     def wrapped(*a, **kw):
         if not _cl.LEDGER.enabled:
-            return fn(*a, **kw)
+            with _TRACER.span(span):
+                return fn(*a, **kw)
         d = _cl.dispatch_begin(signature, a, kw)
         try:
-            out = fn(*a, **kw)
+            with _TRACER.span(span):
+                out = fn(*a, **kw)
         finally:
             entries = _cl.dispatch_end(d)
         if entries and _cl.LEDGER.capture_cost:
@@ -220,10 +190,11 @@ def cached_jit(signature: str, builder: Callable[[], Any]):
 
     Hit/miss/build-time counters feed the process-wide observability
     registry (obs/metrics.py REGISTRY, names kernelCache.*); when the
-    tracer is on, hits emit instant events and builds emit spans (the
-    XLA executable compile itself happens lazily at first call — the
-    build span covers kernel CONSTRUCTION, backend_compile listeners
-    cover compilation, see bench.py). Every cached kernel is wrapped
+    tracer is on, builds emit spans (the XLA executable compile itself
+    happens lazily at first call — the build span covers kernel
+    CONSTRUCTION, backend_compile listeners cover compilation). The
+    built kernel's program is named after the signature's family
+    (``name_program``), tracing or not. Every cached kernel is wrapped
     with the compile-ledger dispatch context so the backend compiles it
     eventually triggers attribute to this signature + the calling plan
     operator (obs/compileledger.py)."""
@@ -235,18 +206,14 @@ def cached_jit(signature: str, builder: Callable[[], Any]):
             _STATS["misses"] += 1
     if fn is not None:
         _HITS.add(1)
-        if _TRACER.enabled:
-            _TRACER.instant("kernelcache.hit", signature=signature[:160])
         return fn
     _MISSES.add(1)
     import time
     t0 = time.perf_counter()
     with _TRACER.span("kernelcache.build", signature=signature[:160]):
-        fn = builder()
+        fn = name_program(builder(), kernel_family(signature))
     _BUILD_TIME.record(time.perf_counter() - t0)
     fn = _wrap_ledgered(signature, fn)
-    if _PROFILE:
-        fn = _wrap_profiled(signature, fn)
     with _LOCK:
         fn = _CACHE.setdefault(signature, fn)
     hook = _BUILD_HOOK

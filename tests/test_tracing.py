@@ -253,3 +253,288 @@ def test_disabled_metrics_no_wrapping(session):
             LEDGER.configure(True)
     finally:
         session.set_conf("spark.rapids.sql.metrics.enabled", True)
+
+
+# ---------------------------------------------------------------------------
+# one timeline for a query: the spans that tile an execution, the query
+# identifier, and the device programs named by kernel family
+# ---------------------------------------------------------------------------
+
+# every span an execution over a Parquet table opens besides the operator
+# and sync.* spans (docs/observability.md); thread "query" or "pool"
+QUERY_SPANS = ("query.begin", "plan.logical", "plan.rewrite",
+               "plan.partitions", "Query", "scan.chunk", "scan.upload",
+               "upload.build", "upload.put", "collect.concat",
+               "query.finish")
+POOL_SPANS = ("scan.decode", "scan.decode.read", "scan.decode.convert")
+
+
+@pytest.fixture
+def parquet_table(tmp_path, rng):
+    n = 3000
+    pdf = pd.DataFrame({"k": rng.integers(0, 7, n).astype(np.int64),
+                        "tag": np.array(["t%d" % (i % 5) for i in range(n)]),
+                        "v": rng.random(n)})
+    path = str(tmp_path / "t.parquet")
+    pdf.to_parquet(path, index=False)
+    return path
+
+
+def _agg_over(session, path):
+    return (session.read.parquet(path).filter(F.col("v") > 0.05)
+            .group_by("tag").agg(F.sum("v").alias("sv")))
+
+
+@pytest.fixture
+def traced_events(session, parquet_table):
+    """The tracer's events after one traced collect() over a Parquet file
+    whose one row group is cut into several batches."""
+    session.set_conf("spark.rapids.sql.enabled", True)
+    session.set_conf("spark.rapids.sql.batchSizeRows", 1024)
+    session.set_conf("spark.rapids.tpu.trace.enabled", True)
+    out = _agg_over(session, parquet_table).collect()
+    assert len(out) == 5
+    return TRACER.events()
+
+
+def _spans(events, name):
+    return [e for e in events if e["name"] == name and e["ph"] == "X"]
+
+
+def _inside(inner, outer):
+    return (outer["tid"] == inner["tid"] and outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1)
+
+
+@pytest.mark.parametrize("name", QUERY_SPANS + POOL_SPANS)
+def test_span_present_with_the_query_id(traced_events, name):
+    spans = _spans(traced_events, name)
+    assert spans, sorted({e["name"] for e in traced_events})
+    queries = {e["args"].get("query") for e in traced_events}
+    assert len(queries) == 1 and None not in queries, queries
+    main = _spans(traced_events, "Query")[0]["tid"]
+    on_main = {e["tid"] == main for e in spans}
+    assert on_main == {name in QUERY_SPANS}, (name, on_main)
+
+
+@pytest.mark.parametrize("inner,outer", [
+    ("upload.build", "scan.upload"), ("upload.put", "scan.upload"),
+    ("upload.build", "sync.scan.upload"),
+    ("scan.decode.read", "scan.decode"),
+    ("scan.decode.convert", "scan.decode"),
+    ("plan.partitions", "Query"), ("scan.upload", "Query")])
+def test_span_nests_by_time_on_its_thread(traced_events, inner, outer):
+    outers = _spans(traced_events, outer)
+    inners = _spans(traced_events, inner)
+    assert inners and outers
+    for e in inners:
+        assert any(_inside(e, o) for o in outers), (inner, outer, e)
+    assert all(e["args"]["depth"] >= 1 for e in inners)
+
+
+def test_chunk_copy_is_a_sibling_of_the_upload(traced_events):
+    chunks = _spans(traced_events, "scan.chunk")
+    uploads = _spans(traced_events, "scan.upload")
+    assert len(chunks) == len(uploads) == 3  # 3000 rows in 1024-row batches
+    assert sum(e["args"]["rows"] for e in chunks) == 3000
+    for c in chunks:
+        assert not any(_inside(c, u) or _inside(u, c) for u in uploads)
+    # top-level spans of the query's thread follow each other: no root span
+    main = _spans(traced_events, "Query")[0]["tid"]
+    top = sorted((e for e in traced_events if e["tid"] == main
+                  and e["ph"] == "X" and e["args"]["depth"] == 0),
+                 key=lambda e: e["ts"])
+    assert [e["name"] for e in top][:4] == [
+        "query.begin", "plan.logical", "plan.rewrite", "Query"]
+    assert [e["name"] for e in top][-2:] == ["query.finish", "collect.concat"]
+    for a, b in zip(top, top[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1
+
+
+def test_span_attributes(traced_events):
+    read = _spans(traced_events, "scan.decode.read")[0]["args"]
+    assert read["file"].endswith("t.parquet") and read["row_group"] == 0
+    assert read["bytes"] > 0
+    assert _spans(traced_events, "scan.decode.convert")[0]["args"]["rows"] \
+        == 3000
+    build = _spans(traced_events, "upload.build")[0]["args"]
+    assert build["rows"] == 1024 and build["columns"] == 2
+    assert build["bytes"] > 0
+    assert _spans(traced_events, "upload.put")[0]["args"]["bytes"] \
+        == build["bytes"]
+    assert _spans(traced_events, "plan.partitions")[0]["args"][
+        "partitions"] >= 1
+    assert _spans(traced_events, "plan.rewrite")[0]["args"][
+        "plan_cache_hit"] in (True, False)
+    assert _spans(traced_events, "collect.concat")[0]["args"]["rows"] == 5
+    families = {e["name"] for e in traced_events
+                if e["name"].startswith("dispatch.")}
+    assert {"dispatch.aggupd", "dispatch.aggmrg"} <= families, families
+    assert not any(e["name"].startswith("kernelcache.hit")
+                   for e in traced_events)
+
+
+def test_events_after_a_collect_hold_that_query_alone(session, parquet_table):
+    session.set_conf("spark.rapids.sql.enabled", True)
+    session.set_conf("spark.rapids.tpu.trace.enabled", True)
+    df = _agg_over(session, parquet_table)
+    df.collect()
+    first = {e["args"]["query"] for e in TRACER.events()}
+    df.collect()
+    second = {e["args"].get("query") for e in TRACER.events()}
+    assert len(first) == len(second) == 1 and first != second
+    assert len(_spans(TRACER.events(), "Query")) == 1
+
+
+def test_a_query_start_keeps_the_spans_of_queries_still_running():
+    tr = Tracer()
+    tr.configure(True)
+    import threading
+    started, go_on = threading.Event(), threading.Event()
+
+    def first():
+        tr.begin_query()
+        with tr.span("query.begin"):
+            tr.set_query("q-a")
+        started.set()
+        go_on.wait(10)
+        with tr.span("Query"):
+            pass
+        tr.end_query("q-a")
+    t = threading.Thread(target=first)
+    t.start()
+    started.wait(10)
+    tr.begin_query()  # the second query starts beside the first
+    tr.set_query("q-b")
+    with tr.span("Query"):
+        pass
+    tr.instant("marker")
+    go_on.set()
+    t.join(10)
+    by_query = {(e["name"], e["args"]["query"]) for e in tr.events()}
+    assert by_query == {("query.begin", "q-a"), ("Query", "q-a"),
+                        ("Query", "q-b"), ("marker", "q-b")}
+    tr.end_query("q-b")
+    tr.begin_query()  # both have ended: a third start drops them
+    assert tr.events() == [] and tr.current_query() is None
+
+
+def test_two_sessions_threads_share_the_tracer(session, parquet_table,
+                                               monkeypatch):
+    """Two collects on two threads: the second query starts while the
+    first is inside its drain, and drops none of the first's spans."""
+    import threading
+    session.set_conf("spark.rapids.sql.enabled", True)
+    session.set_conf("spark.rapids.tpu.trace.enabled", True)
+    in_drain, go_on = threading.Event(), threading.Event()
+    drain = type(session)._drain
+    main = threading.get_ident()
+
+    def held_drain(self, plan, ctx, conf):
+        if threading.get_ident() != main:
+            in_drain.set()
+            go_on.wait(30)
+        return drain(self, plan, ctx, conf)
+    monkeypatch.setattr(type(session), "_drain", held_drain)
+    t = threading.Thread(
+        target=lambda: _agg_over(session, parquet_table).collect())
+    t.start()
+    assert in_drain.wait(30)
+    _agg_over(session, parquet_table).collect()
+    go_on.set()
+    t.join(60)
+    assert not t.is_alive()
+    events = TRACER.events()
+    queries = {e["args"].get("query") for e in events}
+    assert len(queries) == 2 and None not in queries
+    for q in queries:
+        names = {e["name"] for e in events if e["args"].get("query") == q}
+        assert set(QUERY_SPANS) - {"scan.chunk"} <= names, (q, names)
+        assert set(POOL_SPANS) <= names, (q, names)
+
+
+def test_tracing_off_opens_no_span_at_any_site(session, parquet_table,
+                                               monkeypatch):
+    from spark_rapids_tpu.obs import trace as trace_mod
+
+    def refuse(*a, **kw):
+        raise AssertionError("a Span was built with tracing off")
+    monkeypatch.setattr(trace_mod.Span, "__init__", refuse)
+    session.set_conf("spark.rapids.sql.enabled", True)
+    session.set_conf("spark.rapids.sql.batchSizeRows", 1024)
+    assert len(_agg_over(session, parquet_table).collect()) == 5
+    assert not TRACER.enabled and TRACER.events() == []
+    assert TRACER.span("upload.build", rows=1) is TRACER.span("dispatch.x")
+
+
+# -- device programs named by kernel family ---------------------------------
+
+def test_kernel_family_is_the_text_before_the_first_bar():
+    from spark_rapids_tpu.utils.kernelcache import kernel_family
+    assert kernel_family("aggupd|sum(x)|mask=1") == "aggupd"
+    assert kernel_family("join|inner|(0,)|(1,)|x0|probe") == "join"
+    assert kernel_family("limitstep") == "limitstep"
+    assert kernel_family("concat|dm1") == kernel_family("concat|dm0")
+    assert kernel_family("exch-rr x|3") == "exch_rr_x"
+
+
+def test_program_name_depends_on_the_family_alone():
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.utils import kernelcache
+    x = jnp.arange(8)
+    texts, built = [], []
+
+    def build():
+        built.append(jax.jit(lambda v: v + 1))
+        return built[-1]
+    for sig in ("famtest|a=1", "famtest|a=2|b"):
+        assert int(kernelcache.cached_jit(sig, build)(x)[0]) == 1
+        texts.append(built[-1].lower(x).as_text().splitlines()[0])
+    assert len(built) == 2
+    assert texts[0] == texts[1]
+    assert "@jit_srt_famtest " in texts[0], texts[0]
+    static = kernelcache.name_program(
+        jax.jit(lambda v, n: v + n, static_argnums=(1,)), "famstatic")
+    assert "@jit_srt_famstatic " in static.lower(x, 2).as_text()
+    # a builder's plain closure has nothing to name, and is handed back
+    plain = lambda v: v  # noqa: E731
+    assert kernelcache.name_program(plain, "fam") is plain
+    assert plain.__name__ == "<lambda>"
+
+
+@pytest.mark.parametrize("qname", ["q5", "q6"])
+def test_tpch_kernels_lower_to_modules_named_by_family(session, qname,
+                                                       monkeypatch):
+    from spark_rapids_tpu.models import tpch_data
+    from spark_rapids_tpu.models.tpch import QUERIES
+    from spark_rapids_tpu.utils import kernelcache
+    kernelcache.clear()
+    calls = {}
+    wrap = kernelcache._wrap_ledgered
+
+    def recording(signature, fn):
+        wrapped = wrap(signature, fn)
+
+        def rec(*a, **kw):
+            calls.setdefault(signature, (fn, a, kw))
+            return wrapped(*a, **kw)
+        return rec
+    monkeypatch.setattr(kernelcache, "_wrap_ledgered", recording)
+    sf = 0.002
+    tables = {name: gen(sf) for name, gen in tpch_data.ALL_TABLES.items()}
+    tables["nation"] = tpch_data.gen_nation()
+    tables["region"] = tpch_data.gen_region()
+    session.set_conf("spark.rapids.sql.enabled", True)
+    session.set_conf("spark.rapids.sql.shuffle.partitions", 2)
+    try:
+        out = QUERIES[qname](session, {
+            name: session.create_dataframe(df, 3 if len(df) > 50 else 1)
+            for name, df in tables.items()}).collect()
+    finally:
+        kernelcache.clear()  # the recording wrappers must not outlive this
+    assert len(out) > 0 and calls
+    for signature, (fn, a, kw) in calls.items():
+        family = kernelcache.kernel_family(signature)
+        head = fn.lower(*a, **kw).as_text().splitlines()[0]
+        assert f"@jit_srt_{family} " in head, (signature[:80], head)
