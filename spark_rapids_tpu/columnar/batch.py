@@ -108,10 +108,12 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
     """The host half of ``DeviceBatch.from_pandas`` (its ``upload.build``
     span): every column's device-layout buffers, dictionary probe and
     char slab. Returns (host_bufs, dict_metas, slab_metas), one entry a
-    column."""
+    column, and the number of string columns built codes-only."""
     from spark_rapids_tpu.columnar.column import (
-        host_dict_encode_stateful, np_build_slab, slab_stride_for,
+        host_dict_encode_hinted, host_dict_encode_stateful, np_build_slab,
+        slab_stride_for,
     )
+    from spark_rapids_tpu.obs.metrics import REGISTRY
     # per-column factorize hints precomputed by the scan pipeline's
     # decode workers (sources._attach_dict_hints), keyed by column
     # name; only trusted when the frame was not re-chunked since
@@ -123,13 +125,34 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
     host_bufs = []
     dict_metas = []
     slab_metas = []
+    codes_only = 0
     # positional iteration: join outputs may carry duplicate column names
     for i, dt in enumerate(schema.dtypes):
-        values, validity = _pandas_to_numpy(df.iloc[:, i], dt)
-        bufs = DeviceColumn.build_host_buffers(values, validity, dt, cap)
         fact = hints.get(str(df.columns[i])) if hints else None
         if fact is not None and len(fact[0]) != n:
             fact = None
+        encode = dict_encode and (dict_numerics or dt.is_string)
+        if fact is not None and encode and dt.is_string:
+            # hinted string column (the decode worker factorized it and
+            # found no NUL byte in its Arrow chars): encode first, and
+            # where the scan's registry accepts, (validity, codes) are
+            # the whole column — no object array, chars, offsets or
+            # prefix8 is built or shipped, as after any concat or
+            # exchange (DeviceColumn's codes-only form)
+            enc = host_dict_encode_hinted(fact, dt, cap, dict_state, i)
+            if enc is not None:
+                vpad, codes, vals = enc
+                host_bufs.append((None, vpad, codes))
+                dict_metas.append(vals)
+                slab_metas.append(0)
+                codes_only += 1
+                continue
+            # the hint does not encode (and has closed the scan's
+            # registry, where there is one): asking again gives the same
+            # answer, so build unencoded
+            encode = False
+        values, validity = _pandas_to_numpy(df.iloc[:, i], dt)
+        bufs = DeviceColumn.build_host_buffers(values, validity, dt, cap)
         # ``dict_numerics=False`` (file-scan uploads): only string
         # columns are dictionary-probed — the numeric probe+encode is
         # an element-wise pass per column per batch on the upload hot
@@ -137,7 +160,7 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
         # (spark.rapids.sql.agg.denseKeys) instead of dictionaries
         enc = host_dict_encode_stateful(values, validity, dt, cap,
                                         dict_state, i, fact=fact) \
-            if dict_encode and (dict_numerics or dt.is_string) else None
+            if encode else None
         if enc is not None and dt.is_string:
             # only pay the slab scan when a dictionary was actually
             # built (high-cardinality columns already bailed at the
@@ -186,7 +209,10 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
                     bufs = (words, bufs[1], lens)
             slab_metas.append(stride)
         host_bufs.append(bufs)
-    return host_bufs, dict_metas, slab_metas
+    REGISTRY.counter("scan.upload.stringColumns").add(
+        sum(dt.is_string for dt in schema.dtypes))
+    REGISTRY.counter("scan.upload.codesOnlyColumns").add(codes_only)
+    return host_bufs, dict_metas, slab_metas, codes_only
 
 
 @jax.tree_util.register_pytree_node_class
@@ -286,14 +312,15 @@ class DeviceBatch:
         cap = capacity if capacity is not None else bucket_capacity(n)
         with TRACER.span("upload.build", rows=n,
                          columns=len(schema.dtypes)) as sp:
-            host_bufs, dict_metas, slab_metas = _build_host_columns(
-                df, schema, n, cap, dict_encode, dict_state, dict_numerics,
-                blocked_chars)
+            host_bufs, dict_metas, slab_metas, codes_only = \
+                _build_host_columns(df, schema, n, cap, dict_encode,
+                                    dict_state, dict_numerics,
+                                    blocked_chars)
             nbytes = 0
             if sp is not None:
                 nbytes = sum(int(getattr(b, "nbytes", 0))
                              for bufs in host_bufs for b in bufs)
-                sp.set(bytes=nbytes)
+                sp.set(bytes=nbytes, codes_only=codes_only)
         # ``device``: explicit placement for sharded scans (mesh execution
         # uploads partition i to mesh device i so data is born distributed)
         with TRACER.span("upload.put", bytes=nbytes):

@@ -736,6 +736,30 @@ def host_dict_encode_stateful(values: np.ndarray,
     return out, st
 
 
+def host_dict_encode_hinted(fact, dtype: DType, capacity: int,
+                            state: Optional[dict], key) -> Optional[tuple]:
+    """Encode a scanned string column from the decode worker's hint alone
+    (``fact`` = ``dict_factorize_hint``'s (codes, uniques)): the column's
+    values are never touched. Returns (validity bool (capacity,), codes
+    int32 (capacity,), values tuple) — the codes-only column's whole
+    payload — or None when the scan's registry does not accept the hint
+    (closed, an unseen value, uniques that are not clean strings). A null
+    row is the factorize NA sentinel, which is every value ``isna`` calls
+    missing, so the validity is read off the codes."""
+    assert dtype.is_string, dtype
+    hint_codes = np.asarray(fact[0])
+    validity = hint_codes >= 0
+    # with ``fact`` a string column's ``values`` is read for its length
+    # alone (both functions below), so the hint's codes stand in for it
+    enc = host_dict_encode_stateful(hint_codes, validity, dtype, capacity,
+                                    state, key, fact=fact)
+    if enc is None:
+        return None
+    vpad = np.zeros(capacity, dtype=np.bool_)
+    vpad[:len(validity)] = validity
+    return vpad, enc[0], enc[1]
+
+
 def _char_bucket(n: int, minimum: int = 16) -> int:
     """Round a char-buffer size up to a power-of-two bucket. With shape
     buckets on (spark.rapids.tpu.compile.shapeBuckets) the bucket pads

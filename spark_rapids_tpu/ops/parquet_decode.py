@@ -503,7 +503,7 @@ def prepare_rowgroup(path: str, rg: int, pvals: dict, columns: List[str],
             table = pq.ParquetFile(path).read_row_group(rg,
                                                         columns=fb_cols)
             df = _arrow_decode(table, direct)
-            df = _attach_dict_hints(df)
+            df = _attach_dict_hints(df, table)
         _HOST_READS.add(1)
         _HOST_BYTES.add(int(df.memory_usage(deep=False).sum()))
         raw.fallback_df = df

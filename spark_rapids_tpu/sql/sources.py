@@ -371,7 +371,7 @@ class ParquetSource(DataSource):
                     df = self._append_partition_values(
                         _arrow_decode(table, direct), pvals)
                     if pipelined:
-                        df = _attach_dict_hints(df)
+                        df = _attach_dict_hints(df, table)
                     if sp is not None:
                         sp.set(rows=len(df))
                 return df
@@ -480,7 +480,7 @@ class CsvSource(DataSource):
                 t = pacsv.read_csv(path)
                 df = _arrow_decode(t, direct)
                 df.columns = list(self.schema.names)
-                return _attach_dict_hints(df) if pipelined else df
+                return _attach_dict_hints(df, t) if pipelined else df
             return decode
         return build_partitions(
             ctx, [(p, decode_task(p)) for p in self.paths])
@@ -599,7 +599,7 @@ class OrcSource(DataSource):
                 if isinstance(table, pa.RecordBatch):
                     table = pa.Table.from_batches([table])
                 df = _arrow_decode(table, direct)
-                return _attach_dict_hints(df) if pipelined else df
+                return _attach_dict_hints(df, table) if pipelined else df
             return decode
         if not splits:
             def empty():
@@ -614,13 +614,52 @@ def _arrow_to_pandas(table) -> pd.DataFrame:
     return df
 
 
-def _attach_dict_hints(df: pd.DataFrame) -> pd.DataFrame:
+def _arrow_string_has_nul(col) -> bool:
+    """True when a NUL byte lies among the chars an Arrow string column's
+    rows span (one pass over each chunk's data buffer, well under a
+    millisecond a row group), or when the layout is not one this scan
+    reads (then nothing is known, and the answer is the cautious one)."""
+    import pyarrow as pa
+    if pa.types.is_string(col.type):
+        off_dt = np.dtype(np.int32)
+    elif pa.types.is_large_string(col.type):
+        off_dt = np.dtype(np.int64)
+    else:
+        return True
+    for chunk in col.chunks:
+        if len(chunk) == 0:
+            continue
+        _validity, offsets, data = chunk.buffers()
+        offs = np.frombuffer(offsets, off_dt, count=len(chunk) + 1,
+                             offset=chunk.offset * off_dt.itemsize)
+        lo, hi = int(offs[0]), int(offs[-1])
+        # ``all`` is false at the first zero byte, and makes no temporary
+        if hi > lo and not np.frombuffer(data, np.uint8, count=hi - lo,
+                                         offset=lo).all():
+            return True
+    return False
+
+
+def _attach_dict_hints(df: pd.DataFrame, table) -> pd.DataFrame:
     """Precompute per-column dictionary factorizations ON THE DECODE
     WORKER (the scan pipeline runs this inside the split's decode task)
     and attach them as ``df.attrs["srt_dict_fact"]`` keyed by column
     name. The host->device upload then pays only an O(cardinality) remap
-    per dictionary column (columnar/column.py dict_factorize_hint) — the
-    probe + factorize were the largest consumer-thread upload cost.
+    per dictionary column (columnar/column.py dict_factorize_hint) and
+    builds the column codes-only (columnar/batch.py) — the probe +
+    factorize and the chars build were the largest consumer-thread
+    upload costs.
+
+    ``table``: the Arrow table ``df`` was converted from, column for
+    column by position; columns of ``df`` past the table's are the
+    caller's constants (hive partition values, one path component a
+    frame). THE NUL GATE runs here: pandas 3.x ``factorize`` merges 'a'
+    with 'a\\x00' (column.string_host_buffers_have_nul), the upload of a
+    hinted column builds no chars to look at, and the merged unique hides
+    the NUL — so a hinted column's Arrow chars are scanned, and one that
+    holds a NUL byte gets NO hint: the upload then takes the unhinted
+    path, finds the NUL in the chars it builds and closes the column's
+    dictionary for the scan.
 
     Only object/string columns are hinted: file-scan uploads skip the
     numeric dictionary probe entirely (exec/transitions.py
@@ -636,7 +675,14 @@ def _attach_dict_hints(df: pd.DataFrame) -> pd.DataFrame:
                 or str(s.dtype) in ("str", "string"):
             h = dict_factorize_hint(s.to_numpy(dtype=object),
                                     is_string=True)
-            if h is not None:
+            if h is None:
+                continue
+            if i < table.num_columns:
+                nul = _arrow_string_has_nul(table.column(i))
+            else:  # a constant: its one value is all there is to check
+                nul = any(isinstance(u, str) and "\x00" in u
+                          for u in h[1])
+            if not nul:
                 hints[str(df.columns[i])] = h
     if hints:
         df.attrs["srt_dict_fact"] = hints
