@@ -22,6 +22,8 @@ from spark_rapids_tpu.columnar.column import (
 )
 from spark_rapids_tpu.exec.aggutil import AggPlan
 from spark_rapids_tpu.exec.base import ExecContext, Partition, PhysicalPlan
+from spark_rapids_tpu.obs.metrics import REGISTRY
+from spark_rapids_tpu.obs.trace import TRACER
 from spark_rapids_tpu.ops import aggregate as agg_ops
 from spark_rapids_tpu.ops import rowops, sortops
 from spark_rapids_tpu.ops.groupby import row_hashes
@@ -108,6 +110,50 @@ def _concat_device(batches: List[DeviceBatch], schema: Schema,
     if dm is not None:
         dm.meter_batch(out)
     return out
+
+
+_COLLAPSE_BYTES = REGISTRY.counter("exchange.collapse.bytes")
+_COLLAPSE_BATCHES = REGISTRY.counter("exchange.collapse.batches")
+_MERGE_ROWS = REGISTRY.counter("agg.merge.inputRows")
+_MERGE_BYTES = REGISTRY.counter("agg.merge.inputBytes")
+_PASSTHROUGH_ROWS = REGISTRY.counter("agg.partial.passthroughRows")
+
+
+def _counting_rows(counter, kernel, byte_counter=None, row_bytes=0):
+    """``kernel`` with the rows of its first argument added to ``counter``
+    at every call, by what the host knows of the batch without a sync
+    (``num_rows_hint``: the row count where it has been fetched, else the
+    capacity); ``byte_counter`` takes those rows at ``row_bytes`` each."""
+    def run(batch, *rest):
+        rows = batch.num_rows_hint()
+        counter.add(rows)
+        if byte_counter is not None:
+            byte_counter.add(rows * row_bytes)
+        return kernel(batch, *rest)
+    return run
+
+
+def _row_bytes(schema: Schema) -> int:
+    """Bytes a row of ``schema`` occupies at the least: each column's value
+    at its width (a string as one 4-byte code) and a validity byte."""
+    return sum((4 if dt.is_string else jnp.dtype(dt.np_dtype).itemsize) + 1
+               for dt in schema.dtypes)
+
+
+def _collapse_concat(batches: List[DeviceBatch], schema: Schema,
+                     growth: float, keep_masks=None) -> DeviceBatch:
+    """The one concat of a local exchange collapse, counted by what the host
+    knows without a sync: ``exchange.collapse.batches`` input batches and
+    ``exchange.collapse.bytes`` of device storage at their capacity
+    (padding included, rows a fused filter will drop included). The span
+    ``exchange.collapse`` covers the concat's dispatch alone; the drain of
+    the children above it belongs to the operator spans."""
+    nbytes = sum(b.device_memory_size() for b in batches)
+    _COLLAPSE_BATCHES.add(len(batches))
+    _COLLAPSE_BYTES.add(nbytes)
+    with TRACER.span("exchange.collapse", batches=len(batches),
+                     bytes=nbytes):
+        return _concat_device(batches, schema, growth, keep_masks)
 
 
 def _fused_filter_source(node: PhysicalPlan, ctx: ExecContext):
@@ -391,11 +437,12 @@ class TpuHashAggregateExec(TpuExec):
                     p.partial_schema, mask_expr=pre_mask, hash_table=mt)))
             # adaptive low-reduction skip: rows projected straight into the
             # partial layout (spark.rapids.sql.agg.skipAggPassReductionRatio)
-            self._passthrough_kernel = cached_jit(
-                "aggpass|" + p.signature + mask_sig,
-                lambda: jax.jit(lambda b: agg_ops.aggregate_passthrough(
-                    b, key_exprs, p.update_inputs, reductions,
-                    p.partial_schema, mask_expr=pre_mask)))
+            self._passthrough_kernel = _counting_rows(
+                _PASSTHROUGH_ROWS, cached_jit(
+                    "aggpass|" + p.signature + mask_sig,
+                    lambda: jax.jit(lambda b: agg_ops.aggregate_passthrough(
+                        b, key_exprs, p.update_inputs, reductions,
+                        p.partial_schema, mask_expr=pre_mask))))
             # merging partials within the partition uses merge kinds
             self._merge_kernel = self._make_merge_kernel()
         else:
@@ -413,20 +460,25 @@ class TpuHashAggregateExec(TpuExec):
         for merged in p.merge_plan:
             for kind, col, idt in merged:
                 reductions.append((kind, col, idt))
-        self._dense_merge = lambda sizes: cached_jit(
+        # every aggmrg program counts its input into agg.merge.inputRows
+        # and, at the partial layout's least width, agg.merge.inputBytes
+        def counted(kernel):
+            return _counting_rows(_MERGE_ROWS, kernel, _MERGE_BYTES,
+                                  _row_bytes(p.partial_schema))
+        self._dense_merge = lambda sizes: counted(cached_jit(
             f"aggmrg|{p.signature}|dense{sizes}",
             lambda: jax.jit(lambda b, los: agg_ops.aggregate_merge(
                 b, p.num_keys, reductions, p.partial_schema,
-                dense=(los, sizes))))
-        self._hash_merge = lambda mt: cached_jit(
+                dense=(los, sizes)))))
+        self._hash_merge = lambda mt: counted(cached_jit(
             f"aggmrg|{p.signature}|hash{mt}",
             lambda: jax.jit(lambda b: agg_ops.aggregate_merge(
                 b, p.num_keys, reductions, p.partial_schema,
-                hash_table=mt)))
-        return cached_jit(
+                hash_table=mt))))
+        return counted(cached_jit(
             "aggmrg|" + p.signature,
             lambda: jax.jit(lambda b: agg_ops.aggregate_merge(
-                b, p.num_keys, reductions, p.partial_schema)))
+                b, p.num_keys, reductions, p.partial_schema))))
 
     def _dense_group_plan(self, ctx: ExecContext):
         """(los list, sizes tuple, spec_key) for the bounded-int composite
@@ -1380,7 +1432,7 @@ class TpuShuffleExchangeExec(TpuExec):
                     if masks is not None and out_sel is not None:
                         batches = [_select_view(b, out_sel)
                                    for b in batches]
-                    yield _concat_device(batches, schema, growth, masks)
+                    yield _collapse_concat(batches, schema, growth, masks)
                 return [nosync_concat]
 
             def single() -> Iterator[DeviceBatch]:
@@ -1395,7 +1447,7 @@ class TpuShuffleExchangeExec(TpuExec):
                     # kernels — at single-resident-batch scale the
                     # count-fetch round trip costs more than the padding
                     # it would remove
-                    yield _concat_device(batches, schema, growth)
+                    yield _collapse_concat(batches, schema, growth)
                     return
                 # capacity shrink: post-aggregate partials carry their
                 # pre-aggregate input capacity as padding; ONE batched
@@ -1530,7 +1582,7 @@ class TpuShuffleExchangeExec(TpuExec):
                         shrunk.append(sb)
                     else:
                         shrunk.append(b)
-                yield _concat_device(shrunk, schema, growth)
+                yield _collapse_concat(shrunk, schema, growth)
             return [single]
 
         assert kind in ("hash", "range", "roundrobin")

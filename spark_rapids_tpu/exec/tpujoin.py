@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from spark_rapids_tpu.columnar.batch import DeviceBatch, Schema, bucket_capacity
 from spark_rapids_tpu.columnar.column import _char_bucket
 from spark_rapids_tpu.exec.base import ExecContext, Partition, PhysicalPlan
+from spark_rapids_tpu.obs.metrics import REGISTRY
 from spark_rapids_tpu.ops import joins as join_ops
 from spark_rapids_tpu.utils.kernelcache import bucket_dim, cached_jit
 
@@ -195,7 +196,12 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
     # joins whose build key has scan-derived advisory bounds small enough
     # to table. The reference's equivalent is cuDF's hash build+probe;
     # here the "hash table" is the identity map over the key range.
-    _DENSE_MAX_RANGE = 1 << 24
+    # The table costs 0.55 ns a slot on a v5e (a 2^26-slot cumsum 0.037 s)
+    # and a stream row one 12 ns gather, where the union sort costs 122 ns
+    # a slot of build and stream alike (PERF.md, PR 28): the table wins
+    # under any batch a scan hands over, so the cap is what the table may
+    # hold of HBM: 2 GB (s32 counts and starts, and their stack).
+    _DENSE_MAX_RANGE = 1 << 27
 
     def _dense_plan(self, ctx, build_schema):
         """(lo, table_size) when the dense path applies, else None."""
@@ -332,6 +338,9 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
             else:
                 build_parts = build_parts * len(stream_parts)
         jt = self.join_type
+        # rows of every stream batch handed to a probe, by what the host
+        # knows without a sync (the row count where fetched, else capacity)
+        stream_rows = REGISTRY.counter("join.stream.rows", type=jt)
 
         dense = None
 
@@ -419,6 +428,8 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                         # all of them (a per-batch device_get would block
                         # on a full round trip each)
                         streams = list(sp_local())
+                        stream_rows.add(sum(s.num_rows_hint()
+                                            for s in streams))
                         raw = [dkern(build, s, lo_arr) for s in streams]
                         oks_d = [r[3] for r in raw]
                         entry = cache.get(key) if cache is not None else None
@@ -445,6 +456,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                     else:
                         for stream in sp_local():
                             emitted = True
+                            stream_rows.add(stream.num_rows_hint())
                             yield self._semi(stream,
                                              probe_fn(build, stream)[0])
                 else:
@@ -456,6 +468,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                     # simplified per-bucket twin — semantic changes to the
                     # probe/totals/expand contract must be mirrored there
                     streams = list(sp_local())
+                    stream_rows.add(sum(s.num_rows_hint() for s in streams))
                     oks_d = []
                     if dense:
                         raw = [dkern(build, s, lo_arr) for s in streams]

@@ -17,6 +17,19 @@ from spark_rapids_tpu.columnar.batch import DeviceBatch, Schema
 from spark_rapids_tpu.columnar.column import DeviceColumn
 
 
+def sort_carrying(key: jnp.ndarray, vectors: Sequence[jnp.ndarray]):
+    """``vectors`` in the stable order of ``key``: (sorted key, [vectors]).
+    The vectors ride the sort as operands: a sort moves an operand at about
+    1 ns a slot on a v5e where a gather by the permutation costs 10-17 ns,
+    and compiles about 10 s longer for every 32 bits of operand (PERF.md,
+    PR 28)."""
+    as_ops = [v.astype(jnp.uint8) if v.dtype == jnp.bool_ else v
+              for v in vectors]
+    out = jax.lax.sort((key, *as_ops), num_keys=1, is_stable=True)
+    return out[0], [o != 0 if v.dtype == jnp.bool_ else o
+                    for o, v in zip(out[1:], vectors)]
+
+
 def rank_of_iota(sorted_vals: jnp.ndarray, out_len: int) -> jnp.ndarray:
     """``searchsorted(sorted_vals, arange(out_len), side='right')`` as a
     histogram + cumsum: two dense-ish passes instead of a per-element
@@ -266,6 +279,20 @@ def filter_batch(batch: DeviceBatch, keep: jnp.ndarray) -> DeviceBatch:
     """Compact rows where ``keep`` (bool capacity-vector) is True to the
     front. keep is pre-masked to live rows by the caller or here."""
     keep = keep & batch.row_mask()
+    plain = all(not c.dtype.is_string and c.dict_values is None
+                for c in batch.columns)
+    if plain and len(batch.columns) <= 4:
+        # a few fixed-width columns: one stable sort by "dropped" carries
+        # data and validity to the front (a wider batch would compile for
+        # minutes: sort_carrying)
+        _, moved = sort_carrying(
+            (~keep).astype(jnp.uint8),
+            [v for c in batch.columns for v in (c.data, c.validity)])
+        new_rows = keep.sum().astype(jnp.int32)
+        live = jnp.arange(batch.capacity, dtype=jnp.int32) < new_rows
+        cols = [DeviceColumn(c.dtype, moved[2 * i], moved[2 * i + 1] & live)
+                for i, c in enumerate(batch.columns)]
+        return DeviceBatch(batch.schema, cols, new_rows)
     # stable partition via the O(n) prefix-count kernel (pallas on TPU)
     from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
     perm, new_rows = compact_permutation(keep)

@@ -57,6 +57,33 @@ def test_tpch_query_differential(session, tpch_all_pandas, qname):
     })
 
 
+def test_q18_on_orders_that_pass_the_having(session, tpch_all_pandas):
+    """``tpch_data`` draws ``l_orderkey`` over four times as many keys as
+    there are lineitem rows, so in the differential above ``sum(l_quantity)
+    > 300`` keeps nothing and every operator above the HAVING runs on empty
+    input. Here the lines are folded onto 400 of the orders (about 30 lines
+    an order), so most of those pass the query's own threshold and the semi
+    join, both joins, the five-key group and the top 100 all see rows."""
+    tables = dict(tpch_all_pandas)
+    keys = tables["orders"]["o_orderkey"].to_numpy()[:400]
+    li = tables["lineitem"]
+    tables["lineitem"] = li.assign(
+        l_orderkey=keys[li["l_orderkey"].to_numpy() % len(keys)])
+    qty = tables["lineitem"].groupby("l_orderkey")["l_quantity"].sum()
+    assert 100 < (qty > 300).sum() <= 400
+
+    def run(s):
+        return QUERIES["q18"](s, {
+            name: s.create_dataframe(df, 3 if len(df) > 50 else 1)
+            for name, df in tables.items()
+            if name in ("lineitem", "orders", "customer")})
+    out = assert_tpu_and_cpu_equal(run, approx=True, conf={
+        "spark.rapids.sql.shuffle.partitions": 2})
+    assert len(out) == 100
+    assert (out["sum_qty"] > 300).all()
+    assert set(out["o_orderkey"]) <= set(qty[qty > 300].index)
+
+
 def test_q1(session, tpch_pandas):
     out = assert_tpu_and_cpu_equal(
         lambda s: QUERIES["q1"](s, {
