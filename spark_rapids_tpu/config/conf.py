@@ -132,7 +132,12 @@ AGG_DENSE_KEYS = register(
     "fixed-width integer with advisory scan-stat bounds fitting 62 bits "
     "of combined slot space, the grouping sort runs on ONE exact "
     "composite key (2 sort operands instead of 4, no hashing, no image "
-    "refinement) and it is the ONLY grouping path compiled. The "
+    "refinement) and it is the ONLY grouping path compiled. Over a "
+    "Parquet scan whose footers carry min/max statistics the bounds are "
+    "declared when the scan is planned, so dense grouping engages on a "
+    "plan's FIRST execution; over any other source (ORC, CSV, in-memory "
+    "frames, Parquet written without statistics) the bounds are "
+    "measured as batches upload and it engages from the SECOND. The "
     "device-computed bounds check joins the deferred speculation "
     "verification: a stale-stats miss transparently re-executes the "
     "query without dense grouping and blocklists the plan. Requires "

@@ -286,6 +286,11 @@ class ExecContext:
             and conf.get_bool("spark.rapids.sql.adaptiveCapacity.enabled",
                               True))
         self.spec_pending: list = []
+        # integer columns whose bounds the scans of THIS execution read
+        # from their files' footers while the plan was laid out
+        # (TpuScanExec._declare_stats): an aggregate whose keys all
+        # resolve to these plans dense on a first execution
+        self.declared_stats: set = set()
         # adaptive-ratio cache entries written during this execution:
         # a speculative run that later fails verification learned its
         # ratios from possibly-garbage group counts — the session clears

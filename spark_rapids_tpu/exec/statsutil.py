@@ -2,7 +2,9 @@
 paths (join direct-index probe, bounded-int composite grouping keys).
 
 The session unions each scanned int column's (min, max) into a
-name-keyed registry (exec/transitions.note_scan_stats) and records
+name-keyed registry — from a Parquet file's footers when the scan is
+planned (TpuScanExec._declare_stats), else batch by batch as the scan
+uploads (exec/transitions.note_scan_stats) — and records
 rename provenance from the logical plan (session.column_aliases). The
 bounds are ADVISORY — every consumer verifies them on device and falls
 back to its exact path — so resolution here only needs to be sound
@@ -14,12 +16,18 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 
-def int_bounds_for_names(session, names) -> Optional[Tuple[int, int]]:
-    """Union advisory (lo, hi) over every stats entry reachable from any
-    of ``names`` through the rename-alias map (walk bounded — alias
-    chains are shallow). None when nothing resolves."""
-    if session is None:
-        return None
+def note_bounds(session, name: str, lo: int, hi: int) -> None:
+    """Union one column's (lo, hi) into the registry."""
+    reg = session.column_stats
+    prev = reg.get(name)
+    if prev is not None:
+        lo, hi = min(lo, prev[0]), max(hi, prev[1])
+    reg[name] = (lo, hi)
+
+
+def stats_names(session, names) -> set:
+    """The registry entries reachable from any of ``names`` through the
+    rename-alias map (walk bounded — alias chains are shallow)."""
     reg = session.column_stats
     amap = session.column_aliases
     names = set(names)
@@ -32,7 +40,16 @@ def int_bounds_for_names(session, names) -> Optional[Tuple[int, int]]:
             break
         names |= nxt
         frontier = nxt
-    bounds = [reg[n] for n in names if n in reg]
+    return {n for n in names if n in reg}
+
+
+def int_bounds_for_names(session, names) -> Optional[Tuple[int, int]]:
+    """Union advisory (lo, hi) over every stats entry reachable from any
+    of ``names``. None when nothing resolves."""
+    if session is None:
+        return None
+    reg = session.column_stats
+    bounds = [reg[n] for n in stats_names(session, names)]
     if not bounds:
         return None
     return (min(b[0] for b in bounds), max(b[1] for b in bounds))

@@ -117,6 +117,7 @@ _COLLAPSE_BATCHES = REGISTRY.counter("exchange.collapse.batches")
 _MERGE_ROWS = REGISTRY.counter("agg.merge.inputRows")
 _MERGE_BYTES = REGISTRY.counter("agg.merge.inputBytes")
 _PASSTHROUGH_ROWS = REGISTRY.counter("agg.partial.passthroughRows")
+_DECLARED_COLUMNS = REGISTRY.counter("scan.stats.declaredColumns")
 
 
 def _counting_rows(counter, kernel, byte_counter=None, row_bytes=0):
@@ -483,7 +484,8 @@ class TpuHashAggregateExec(TpuExec):
     def _dense_group_plan(self, ctx: ExecContext):
         """(los list, sizes tuple, spec_key) for the bounded-int composite
         grouping key, or None (non-int keys, unresolvable stats, >62
-        bits, speculation off, or blocklisted after a verification miss).
+        bits, speculation off, a first execution over bounds no scan
+        declared, or blocklisted after a verification miss).
         The dense program is the ONLY compiled grouping path; the
         device-computed ok flag joins the deferred speculation
         verification and a miss re-executes without dense (and
@@ -515,21 +517,32 @@ class TpuHashAggregateExec(TpuExec):
                 key_names.append({ps.names[j]})
                 key_dts.append(ps.dtypes[j])
         from spark_rapids_tpu.exec.base import plan_fingerprint
+        from spark_rapids_tpu.exec.statsutil import stats_names
         fp = plan_fingerprint(self)
-        # dense only engages for a plan the session has EXECUTED before:
-        # on a first execution the scan stats may not cover this upload
-        # yet (they record as batches stream, after planning), and a
-        # guaranteed-stale speculation would re-execute the query
-        seen = ctx.session.dense_plans_seen
-        if fp not in seen:
-            seen.add(fp)
-            return None
+        # dense engages on a FIRST execution only where every key resolves
+        # to columns whose bounds a scan declared while this plan was laid
+        # out (Parquet footers: they cover exactly the splits the scans
+        # below will read). Otherwise only for a plan the session has
+        # EXECUTED before: on a first execution the measured stats may not
+        # cover this upload yet (they record as batches stream, after
+        # planning), and a guaranteed-stale speculation would re-execute
+        # the query
+        def declared(names) -> bool:
+            found = stats_names(ctx.session, names)
+            return bool(found) and found <= ctx.declared_stats
+        source = "declared" if all(map(declared, key_names)) else "seen"
+        if source == "seen":
+            seen = ctx.session.dense_plans_seen
+            if fp not in seen:
+                seen.add(fp)
+                return None
         got = dense_group_plan(ctx.session, key_names, key_dts)
         if got is None:
             return None
         skey = f"nocache|densegroup|{fp}|{got[1]}"
         if skey in ctx.session.capacity_spec_blocklist:
             return None
+        REGISTRY.counter("agg.dense.plans", source=source).add(1)
         return got[0], got[1], skey
 
     def output_schema(self) -> Schema:
@@ -1150,6 +1163,7 @@ class TpuScanExec(TpuExec):
     def partitions(self, ctx: ExecContext) -> List[Partition]:
         from spark_rapids_tpu.exec.transitions import scan_raw_parts
         cpu_parts = scan_raw_parts(ctx, self.source, self.pushed_filters)
+        declared = frozenset()
         if cpu_parts is None:
             if self.pushed_filters and hasattr(self.source,
                                                "prune_splits"):
@@ -1157,6 +1171,7 @@ class TpuScanExec(TpuExec):
                     ctx, self.pushed_filters)
             else:
                 cpu_parts = self.source.cpu_partitions(ctx)
+            declared = self._declare_stats(ctx)
         max_rows = ctx.conf.batch_size_rows
         schema = self._schema
 
@@ -1204,9 +1219,30 @@ class TpuScanExec(TpuExec):
                 return upload_partition(ctx, part, schema, max_rows,
                                         dict_state, cache, i,
                                         mesh_devs=mesh_devs,
-                                        dict_numerics=dict_numerics)
+                                        dict_numerics=dict_numerics,
+                                        declared_stats=declared)
             return run
         return [make(i, p) for i, p in enumerate(cpu_parts)]
+
+    def _declare_stats(self, ctx: ExecContext) -> frozenset:
+        """Union the integer bounds the source reads from its footers
+        (ParquetSource.declared_int_bounds) into session.column_stats
+        while the plan is laid out, so every aggregate and join above
+        plans with the bounds of exactly the splits this scan will read.
+        Returns the declared names: the upload does not measure them
+        again (transitions.note_scan_stats). Other sources declare
+        nothing and keep the batch-by-batch measurement."""
+        declare = getattr(self.source, "declared_int_bounds", None)
+        if declare is None or ctx.session is None:
+            return frozenset()
+        from spark_rapids_tpu.exec.statsutil import note_bounds
+        bounds = declare(self.pushed_filters)
+        for name, b in bounds.items():
+            if b is not None:
+                note_bounds(ctx.session, name, *b)
+        ctx.declared_stats.update(bounds)
+        _DECLARED_COLUMNS.add(len(bounds))
+        return frozenset(bounds)
 
 
 class TpuShuffleExchangeExec(TpuExec):

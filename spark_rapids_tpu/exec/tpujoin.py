@@ -218,23 +218,11 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
         # scan stats; union bounds over every candidate source (multiple
         # sources only loosen — the device verification catches any
         # residual mismatch)
-        reg = ctx.session.column_stats
-        amap = ctx.session.column_aliases
-        names = {build_schema.names[bk]}
-        frontier = set(names)
-        for _ in range(8):  # alias chains are shallow; bound the walk
-            nxt = set()
-            for n in frontier:
-                nxt |= amap.get(n, set()) - names
-            if not nxt:
-                break
-            names |= nxt
-            frontier = nxt
-        bounds = [reg[n] for n in names if n in reg]
-        if not bounds:
+        from spark_rapids_tpu.exec.statsutil import int_bounds_for_names
+        got = int_bounds_for_names(ctx.session, {build_schema.names[bk]})
+        if got is None:
             return None
-        lo = min(b[0] for b in bounds)
-        hi = max(b[1] for b in bounds)
+        lo, hi = got
         rng = hi - lo + 1
         if rng <= 0 or rng > self._DENSE_MAX_RANGE:
             return None

@@ -40,16 +40,20 @@ def scan_cache_for(ctx: ExecContext, source, schema: Schema,
     return store[key][1]
 
 
-def note_scan_stats(session, df: pd.DataFrame) -> None:
+def note_scan_stats(session, df: pd.DataFrame, declared=()) -> None:
     """Union each scanned int column's (min, max) into the session's
     advisory stats registry (session.column_stats). Called ONLY from scan
     uploads (TpuScanExec / HostToDeviceExec-over-scan), so derived columns
     can never seed it; the dense-key join verifies the bounds on device
-    before relying on them (exec/tpujoin.py)."""
+    before relying on them (exec/tpujoin.py). Columns in ``declared``
+    had their bounds read from the file footers when the scan was
+    planned (TpuScanExec._declare_stats) and are not measured again."""
     if session is None:
         return
-    reg = session.column_stats
+    from spark_rapids_tpu.exec.statsutil import note_bounds
     for name in df.columns:
+        if name in declared:
+            continue
         s = df[name]
         if not (pd.api.types.is_integer_dtype(s.dtype)
                 and not pd.api.types.is_bool_dtype(s.dtype)):
@@ -58,11 +62,7 @@ def note_scan_stats(session, df: pd.DataFrame) -> None:
         # scan-upload hot path would otherwise pay per column
         if not int(s.count()):
             continue
-        lo, hi = int(s.min()), int(s.max())
-        prev = reg.get(str(name))
-        if prev is not None:
-            lo, hi = min(lo, prev[0]), max(hi, prev[1])
-        reg[str(name)] = (lo, hi)
+        note_bounds(session, str(name), int(s.min()), int(s.max()))
 
 
 def upload_blocked_chars(ctx: ExecContext) -> int:
@@ -118,7 +118,8 @@ def scan_raw_parts(ctx: ExecContext, source, pushed_filters):
 def upload_partition(ctx: ExecContext, part: Partition, schema: Schema,
                      max_rows: int, dict_state: dict, cache, i: int,
                      mesh_devs=None, is_scan: bool = True,
-                     dict_numerics: bool = True) -> Iterator[DeviceBatch]:
+                     dict_numerics: bool = True,
+                     declared_stats=()) -> Iterator[DeviceBatch]:
     """Shared host->device upload runner for TpuScanExec and
     HostToDeviceExec: pandas frames from ``part`` -> chunked, capacity-
     bucketed DeviceBatches, with device-scan-cache replay/fill and HBM
@@ -191,7 +192,7 @@ def upload_partition(ctx: ExecContext, part: Partition, schema: Schema,
                     yield fname, batch
                 continue
             if is_scan:
-                note_scan_stats(ctx.session, df)
+                note_scan_stats(ctx.session, df, declared_stats)
             for lo in range(0, max(len(df), 1), max_rows):
                 if double_buffer and lo == 0 and len(df) <= max_rows:
                     # whole-frame chunk: decode already produced a fresh

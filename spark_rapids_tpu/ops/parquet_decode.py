@@ -806,12 +806,9 @@ def decode_rowgroup(ctx, raw: RawRowGroup, schema, max_rows: int,
     n = raw.n
     cap = bucket_capacity(max(n, 1))
     if session is not None:
-        reg = session.column_stats
+        from spark_rapids_tpu.exec.statsutil import note_bounds
         for name, (lo, hi) in raw.stats.items():
-            prev = reg.get(name)
-            if prev is not None:
-                lo, hi = min(lo, prev[0]), max(hi, prev[1])
-            reg[name] = (lo, hi)
+            note_bounds(session, name, lo, hi)
 
     dt_by_name = dict(zip(schema.names, schema.dtypes))
     fb_names = {name for name, _ in raw.fallback}

@@ -148,9 +148,11 @@ class TpuSparkSession:
         # set (dict keys) so the size sweep evicts oldest-first — an
         # arbitrary set.pop() could re-enable a known-bad speculation.
         self.capacity_spec_blocklist: OrderedSet = OrderedSet()
-        # plan fingerprints that have executed once: dense grouping only
-        # engages from the second execution (first-run scan stats cannot
-        # cover the upload yet — they record as batches stream)
+        # plan fingerprints that have executed once: over bounds measured
+        # batch by batch, dense grouping only engages from the second
+        # execution (first-run scan stats cannot cover the upload yet —
+        # they record as batches stream). Over Parquet footers' bounds it
+        # engages on the first (ExecContext.declared_stats)
         self.dense_plans_seen: OrderedSet = OrderedSet()
         # scan-derived integer column bounds: column name -> (min, max),
         # unioned across every scanned batch carrying that name. ADVISORY

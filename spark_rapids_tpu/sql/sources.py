@@ -316,6 +316,33 @@ class ParquetSource(DataSource):
                 keep.append((p, rg, pvals))
         return keep, len(self.splits) - len(keep)
 
+    def declared_int_bounds(self, filters=None) -> dict:
+        """{column: (min, max)} of the requested integer data columns
+        over the splits that survive ``filters``, read from the cached
+        footers before any batch is. A column is declared only when every
+        surviving row group that holds a non-null value of it carries
+        min/max statistics; its entry is None where no surviving row
+        group holds a value (all nulls, or nothing survived). Files
+        written without statistics declare nothing."""
+        splits = self.prune_splits(filters)[0] if filters else self.splits
+        # the schema lists the data columns first, then partition keys
+        out = {c: None for c, dt in zip(self.columns, self.schema.dtypes)
+               if dt.is_integral}
+        for p, rg, _ in splits:
+            if not out:
+                break
+            stats = self._rg_stats(p, rg)
+            for name in list(out):
+                mn, mx, _, nvals = stats.get(name, (None,) * 4)
+                if mn is None or mx is None:
+                    if nvals != 0:  # values, or nothing known, unbounded
+                        del out[name]
+                    continue
+                prev = out[name]
+                out[name] = ((int(mn), int(mx)) if prev is None else
+                             (min(int(mn), prev[0]), max(int(mx), prev[1])))
+        return out
+
     def _read_row_group(self, path: str, rg: int):
         """One row group as an Arrow table: read, decompress and page
         decode are one call into Arrow's C++, so one span."""
