@@ -13,23 +13,22 @@ BASELINE.json's first staged configuration), on one TPU:
                    through ``collect()``, then q6 and q3 together through
                    ``session.serving_scheduler(workers=2)``. Every answer is
                    compared with the CPU oracle (``spark.rapids.sql.enabled=
-                   false``, outside the timings) under bench.py's tolerance.
+                   false``, outside the timings) under benchmarks/match.py's
+                   tolerance.
   pass 2 (warm)    a fresh process on the same compile cache: q6 and q1
                    again; real XLA compiles vs persistent-cache hits.
-  pass 3 (kernels) ``SPARK_RAPIDS_TPU_PALLAS=1``: each Pallas kernel
-                   family's compile verdict; the compaction kernel must
-                   compile and match its jnp twin at 6,000,000 rows; two
-                   platform facts (does block_until_ready block, what one
-                   small blocking fetch costs).
+  pass 3 (facts)   platform facts the engine's design leans on: does
+                   block_until_ready block, what one small blocking fetch
+                   costs, whether the compiler takes an f64 -> u64 bitcast.
   pass 4 (fleet)   one worker process for each chip: one worker more than
                    there are chips is refused at once, and a one-chip worker
                    serves q6.
 
 The parent imports the standard library only and runs one child at a time,
 so each child is the only process holding the chip. Any exception,
-mismatch, CPU fallback, wrong platform, missing native library or refused
-compaction kernel fails the run: the exit code is non-zero and no result
-line is printed. On success the last line of stdout is
+mismatch, CPU fallback, wrong platform or missing native library fails the
+run: the exit code is non-zero and no result line is printed. On success
+the last line of stdout is
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 
@@ -50,7 +49,6 @@ import time
 QUERIES = ("q6", "q1", "q3", "q5")
 SERVED = ("q6", "q3")
 WARM_QUERIES = ("q6", "q1")
-COMPACTION_ROWS = 6_000_000
 # the whole run, compilation included, has 1200 s: the parent stops at this
 # many and each child gets what is left of them (793 s measured cold on one
 # v5e chip, PR 21; 640 s of it pass 1)
@@ -179,7 +177,7 @@ def _timed_collect(session, build, counters):
 
 
 def _run_queries(args, names, served) -> None:
-    from bench import _results_match
+    from benchmarks.match import results_match
 
     from spark_rapids_tpu.models.tpch import QUERIES as TPCH
     session, tables = _open_session(args)
@@ -191,8 +189,8 @@ def _run_queries(args, names, served) -> None:
         first, first_s, c1 = _timed_collect(session, builds[q], counters)
         second, second_s, c2 = _timed_collect(session, builds[q], counters)
         oracles[q] = _oracle(session, builds[q])
-        ok = _results_match(first, oracles[q]) \
-            and _results_match(second, oracles[q])
+        ok = results_match(first, oracles[q]) \
+            and results_match(second, oracles[q])
         print(f"query {q}: first_s={first_s:.3f} second_s={second_s:.3f} "
               f"compiles={c1['compiles']}+{c2['compiles']} "
               f"compile_s={c1['compile_s']:.1f}+{c2['compile_s']:.1f} "
@@ -210,7 +208,7 @@ def _run_queries(args, names, served) -> None:
             jobs = [(q, sched.submit(builds[q], tenant=q, description=q))
                     for q in served]
             for q, job in jobs:
-                ok = _results_match(job.get(600), oracles[q])
+                ok = results_match(job.get(600), oracles[q])
                 print(f"served {q}: status={job.status} "
                       f"wall_s={job.wall_s:.3f} verified={ok}", flush=True)
                 if not ok:
@@ -277,20 +275,13 @@ def _phase_warm(args) -> None:
               "the compile cache")
 
 
-def _phase_kernels(args) -> None:
-    """SPARK_RAPIDS_TPU_PALLAS=1 (set by the parent): three platform
-    facts, which kernel families Mosaic compiles, and the compaction
-    kernel against its twin at a lineitem's worth of rows."""
-    import traceback
-
+def _phase_facts(args) -> None:
+    """Three platform facts; reported, they decide nothing here."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     import spark_rapids_tpu  # noqa: F401 — enables x64 like the engine
-    from spark_rapids_tpu.ops import pallas_kernels as pk
-    if pk._mode() != "pallas":
-        _fail(f"kernel mode is {pk._mode()!r}, not 'pallas'")
 
     # fact 1: does block_until_ready block? A program long enough to time:
     # if it blocks, a fetch after it costs a small fetch, not the program.
@@ -343,47 +334,11 @@ def _phase_kernels(args) -> None:
         verdict = f"{type(e).__name__}: {str(e).strip().splitlines()[0][:200]}"
     print(f"fact f64->u64 bitcast: {verdict}", flush=True)
 
-    verdicts = {}
-    for family in pk.KERNEL_PROBES:
-        try:
-            pk.require_kernels(family)
-            verdicts[family] = "compiled"
-        except pk.PallasKernelRefused as e:
-            # the verdict table is this phase's output: a refusal of any
-            # family but compaction is a finding, not a failure
-            cause = e.__cause__
-            where = traceback.extract_tb(cause.__traceback__)[-1]
-            verdicts[family] = (
-                f"{type(cause).__name__}: "
-                f"{str(cause).strip().splitlines()[0][:300]} "
-                f"[{os.path.basename(where.filename)}:{where.lineno} "
-                f"{where.name}]")
-        print(f"kernel {family}: {verdicts[family]}", flush=True)
-    if verdicts["compaction"] != "compiled":
-        _fail("the compaction kernel does not compile")
-
-    rng = np.random.default_rng(args.seed)
-    for ratio in (0.02, 0.5, 0.98):  # q6-like, even, q1-like filters
-        keep = jnp.asarray(rng.random(COMPACTION_ROWS) < ratio)
-        got = pk._dual_prefix_pallas(keep.astype(jnp.int32), False)
-        want = pk._dual_prefix_jnp(keep.astype(jnp.int32))
-        perm, total = pk.compact_permutation(keep)
-        host_keep = np.asarray(keep)
-        want_perm = np.concatenate([np.flatnonzero(host_keep),
-                                    np.flatnonzero(~host_keep)])
-        same = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want)) \
-            and np.array_equal(np.asarray(perm), want_perm) \
-            and int(total) == int(host_keep.sum())
-        print(f"compaction n={COMPACTION_ROWS} keep={ratio}: "
-              f"kept={int(total)} matches_twin={same}", flush=True)
-        if not same:
-            _fail(f"compaction kernel differs from its twin at keep={ratio}")
-
 
 def _phase_fleet(args) -> None:
     """The router process never initialises a backend: it counts chips
     from PCI and hands each worker one through its environment."""
-    from bench import _results_match
+    from benchmarks.match import results_match
 
     from spark_rapids_tpu.memory import discovery
     from spark_rapids_tpu.serving.fleet.router import launch_process_fleet
@@ -418,8 +373,8 @@ def _phase_fleet(args) -> None:
             if not reply or reply.get("status") != "succeeded" \
                     or not oracle or not oracle.get("result"):
                 _fail(f"fleet worker {rid}: {reply} / oracle {oracle}")
-            ok = _results_match(deserialize_frame(reply["result"]),
-                                deserialize_frame(oracle["result"]))
+            ok = results_match(deserialize_frame(reply["result"]),
+                               deserialize_frame(oracle["result"]))
             platform = worker.status()["status"]["device"]["platform"]
             print(f"fleet: worker {rid} env="
                   f"TPU_VISIBLE_CHIPS={router.worker_env[rid]['TPU_VISIBLE_CHIPS']} "
@@ -432,7 +387,7 @@ def _phase_fleet(args) -> None:
 
 
 PHASES = {"cold": _phase_cold, "warm": _phase_warm,
-          "kernels": _phase_kernels, "fleet": _phase_fleet}
+          "facts": _phase_facts, "fleet": _phase_fleet}
 
 
 def _child(args) -> None:
@@ -446,14 +401,14 @@ def _child(args) -> None:
 # parent: standard library only, one child at a time
 # ---------------------------------------------------------------------------
 
-def _run_phase(phase: str, argv, deadline: float, extra_env=None) -> None:
+def _run_phase(phase: str, argv, deadline: float) -> None:
     """One child in its own process group, swept when it ends however it
     ends — nothing this script started outlives it."""
     print(f"--- phase {phase} ---", flush=True)
     t0 = time.monotonic()
     proc = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "--phase", phase] + argv,
-        env=dict(os.environ, **(extra_env or {})), start_new_session=True)
+        start_new_session=True)
     try:
         rc = proc.wait(timeout=max(deadline - t0, 1.0))
     except subprocess.TimeoutExpired:
@@ -495,7 +450,7 @@ def main() -> None:
     deadline = time.monotonic() + RUN_BUDGET_S
     _run_phase("cold", argv, deadline)
     _run_phase("warm", argv, deadline)
-    _run_phase("kernels", argv, deadline, {"SPARK_RAPIDS_TPU_PALLAS": "1"})
+    _run_phase("facts", argv, deadline)
     _run_phase("fleet", argv, deadline)
     with open(device_file) as f:
         device = json.load(f)

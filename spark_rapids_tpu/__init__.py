@@ -2,11 +2,11 @@
 
 A from-scratch re-design of the capabilities of the RAPIDS Accelerator for
 Apache Spark (reference: tgravescs/spark-rapids) targeting TPUs through
-JAX/XLA/Pallas instead of NVIDIA GPUs through cuDF/RMM/UCX.
+JAX/XLA instead of NVIDIA GPUs through cuDF/RMM/UCX.
 
 Architecture (bottom-up), mirroring the reference's layer map (SURVEY.md section 1):
 
-  L0  jax/XLA/pallas kernels            (reference: external cuDF/RMM/UCX)
+  L0  jax/XLA kernels                   (reference: external cuDF/RMM/UCX)
   L2  memory & device runtime           (reference: GpuDeviceManager/GpuSemaphore/
                                          RapidsBufferCatalog + spill stores)
   L3  I/O + exchange                    (reference: GpuParquetScan, shuffle)

@@ -177,29 +177,25 @@ AGG_SKIP_RATIO = register(
 
 AGG_HASH_ENABLED = register(
     "spark.rapids.sql.agg.hashAggEnabled", _to_bool, False,
-    "One-pass open-addressing hash aggregation "
-    "(ops/pallas_kernels.hash_grouped_aggregate): rows claim slots in a "
-    "load-factor-1/2 table and fold sum/min/max/count accumulators in "
-    "the same probe walk — no sort, no segment scan. Engages for "
+    "Open-addressing hash aggregation "
+    "(ops/tablekernels.hash_grouped_aggregate): rows claim slots in a "
+    "load-factor-1/2 table and each sum/min/max/count is one segment "
+    "reduction at table width — no sort. Engages for "
     "exact-one-word key images (fixed-width values, dictionary codes) "
     "where the dense-key path cannot and the payload-sort path is the "
     "fallback today; batches whose table exceeds "
     "spark.rapids.sql.agg.hash.maxTableSlots recurse through the "
-    "out-of-core hash fan-out into in-budget sub-aggregations. Under "
-    "SPARK_RAPIDS_TPU_PALLAS=1 on a directly attached TPU the Pallas "
-    "slot-table kernel runs; otherwise the vectorized jnp twin "
-    "(identical contract, docs/hashagg.md). Off by default this round.")
+    "out-of-core hash fan-out into in-budget sub-aggregations "
+    "(docs/hashagg.md). Off by default this round.")
 
 AGG_HASH_MAX_SLOTS = register(
     "spark.rapids.sql.agg.hash.maxTableSlots", int, 1 << 17,
     "Slot-count bound of the hash-aggregation table "
-    "(spark.rapids.sql.agg.hashAggEnabled). The compiled Pallas kernel "
-    "keeps the whole (keys x slots) uint64 table VMEM-resident in a "
-    "single-step grid, so the bound is a VMEM budget: at the default "
-    "128Ki slots a 2-image key table is 2MiB plus accumulators. Batches "
-    "sizing past the bound split by key hash (exec/outofcore.py) and "
-    "aggregate per bucket — a handful of in-VMEM passes instead of one "
-    "oversized table.", validator=_positive)
+    "(spark.rapids.sql.agg.hashAggEnabled): at the default 128Ki slots "
+    "a 2-image key table is 2MiB plus accumulators. Batches sizing past "
+    "the bound split by key hash (exec/outofcore.py) and aggregate per "
+    "bucket — a handful of in-budget passes instead of one oversized "
+    "table.", validator=_positive)
 
 AGG_RUNTIME_SKIP = register(
     "spark.rapids.sql.agg.runtimeSkip", _to_bool, True,
@@ -590,7 +586,7 @@ FUSION_STAGE_ENABLED = register(
     "Project/Filter (with interleaved batch coalescing absorbed) run as a "
     "single XLA executable with the intermediate buffers donated inside "
     "the program. false (default) keeps today's per-operator plans "
-    "byte-identical; the bench harness turns it on. Fused stages report "
+    "byte-identical. Fused stages report "
     "their member-operator pipeline to the compile ledger, profile tree, "
     "progress records and flight recorder.")
 
@@ -612,17 +608,6 @@ FUSION_DONATE = register(
     "to every consumer of a shared subtree, which donation must never "
     "touch. Off by default: within one fused program XLA already reuses "
     "intermediate buffers, donation only adds the input itself.")
-
-FUSION_HASH_KERNELS = register(
-    "spark.rapids.sql.fusion.hashKernels", _to_bool, True,
-    "Allow the Pallas open-addressing hash-table kernels "
-    "(ops/pallas_kernels.py) to replace the sort-based fallbacks: the "
-    "union-lexsort join probe (exec/tpujoin.py) for equi joins whose "
-    "key columns are all fixed-width (single or multi-column; string "
-    "keys keep the sort probe), and the sorted count-distinct pass "
-    "(exec/aggfuse.py). Only effective when SPARK_RAPIDS_TPU_PALLAS "
-    "selects the pallas (or interpret) path — the default jnp mode keeps "
-    "the sort spellings byte-identical.")
 
 JOIN_EXACT_LONG_STRINGS = register(
     "spark.rapids.sql.join.exactLongStrings", _to_bool, True,
@@ -948,8 +933,8 @@ SYNC_LEDGER_ENABLED = register(
     "'syncs' section and device-occupancy estimate, hostSync journal "
     "events, the sync track in the Chrome trace export, the live "
     "monitor's srt_host_sync* series and /api/query sync stats, "
-    "flight-recorder failure dumps, bench.py's host_syncs/sync_s record "
-    "and tools/perfdiff.py's --sync-threshold gate. On by default: "
+    "flight-recorder failure dumps and the benchmark's "
+    "syncs_per_query. On by default: "
     "syncs are the expensive operation being measured, so the "
     "bookkeeping is noise next to the blocked wall time it accounts.")
 
@@ -957,7 +942,7 @@ SYNC_LEDGER_MAX_ENTRIES = register(
     "spark.rapids.tpu.sync.ledger.maxEntries", int, 4096,
     "Entries kept in the host-sync ledger's bounded ring (oldest "
     "evicted first). Steady-state queries record a handful of syncs "
-    "each; 4096 covers a long bench sweep between watermark reads.",
+    "each; 4096 covers a long run between watermark reads.",
     validator=_positive)
 
 SYNC_LEDGER_EVENT_MIN_SECONDS = register(
@@ -998,8 +983,7 @@ COMPILE_SHAPE_BUCKETS = register(
     "bucket. Row counts stay exact (num_rows is data; the padding region "
     "is masked exactly like today's capacity padding), so results are "
     "value-identical — only capacities grow. false (default) is "
-    "byte-identical to the unpadded engine; the bench harness turns it "
-    "on (BENCH_SHAPE_BUCKETS=0 reproduces unpadded shapes). Batch ROW "
+    "byte-identical to the unpadded engine. Batch ROW "
     "capacities (spark.rapids.sql.batchSizeRows buckets) are already "
     "the stable primary dimension and are never re-padded.")
 

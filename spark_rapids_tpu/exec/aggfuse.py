@@ -79,64 +79,6 @@ class TpuCountDistinctExec(PhysicalPlan):
                 batch, self.g2_idx, self.rest_idx)
             return finish(batch, rep_rows, counts, n_groups)
         self._kernel = cached_jit(sig, lambda: jax.jit(kernel))
-        self._finish = finish
-
-    def _hash_kernel(self, mode: str):
-        """Hash-table spelling of the fused count-distinct: two
-        open-addressing group assignments (ops/pallas_kernels
-        .hash_group_ids) — distinct G1 tuples, then G2 groups over the
-        tuple representatives — replacing the sorted pass entirely.
-        Falls back to the sorted pass at trace time when any key column
-        is a plain (non-dictionary) string: only fixed-width values and
-        batch-local dictionary codes have exact single-u64 images."""
-        from spark_rapids_tpu.ops import pallas_kernels as pk
-
-        def hash_count_distinct(batch: DeviceBatch):
-            from spark_rapids_tpu.ops.sortops import u64_key_image
-
-            def images(idx_list):
-                imgs = []
-                for ci in idx_list:
-                    col = batch.columns[ci]
-                    per = u64_key_image(col, allow_dict=True)
-                    # null keys are their own distinct value: the
-                    # sentinel image plus the validity bit as an extra
-                    # key column keeps a real value that happens to
-                    # equal the sentinel distinct from NULL
-                    imgs.extend(jnp.where(col.validity, im, jnp.uint64(0))
-                                for im in per)
-                    imgs.append(col.validity.astype(jnp.uint64))
-                return imgs
-
-            cap = batch.capacity
-            valid = batch.row_mask()
-            T = pk.hash_table_size(cap)
-            rows = jnp.arange(cap, dtype=jnp.int32)
-            gid1, _n1, rep1 = pk.hash_group_ids(
-                images(self.g2_idx + self.rest_idx), valid, T, mode=mode)
-            # representative row of each distinct G1 tuple
-            first = (gid1 >= 0) & (
-                rows == rep1[jnp.clip(gid1, 0, cap - 1)])
-            gid2, n2, rep2 = pk.hash_group_ids(
-                images(self.g2_idx), first, T, mode=mode)
-            counts = jnp.zeros((cap,), jnp.int64).at[
-                jnp.where(first, gid2, cap)].add(1, mode="drop")
-            return rep2, counts, n2
-
-        def kernel(batch: DeviceBatch) -> DeviceBatch:
-            from spark_rapids_tpu.ops.aggregate import count_distinct_reduce
-            plain_string = any(
-                batch.columns[ci].dtype.is_string
-                and batch.columns[ci].dict_values is None
-                for ci in self.g2_idx + self.rest_idx)
-            if plain_string:
-                rep_rows, counts, n_groups = count_distinct_reduce(
-                    batch, self.g2_idx, self.rest_idx)
-            else:
-                rep_rows, counts, n_groups = hash_count_distinct(batch)
-            return self._finish(batch, rep_rows, counts, n_groups)
-        return cached_jit(f"{self._sig}|hash|{mode}",
-                          lambda: jax.jit(kernel))
 
     def output_schema(self) -> Schema:
         return self._schema
@@ -151,12 +93,6 @@ class TpuCountDistinctExec(PhysicalPlan):
     def partitions(self, ctx: ExecContext) -> List[Partition]:
         child_parts = self.children[0].executed_partitions(ctx)
         growth = ctx.conf.capacity_growth
-        from spark_rapids_tpu.ops import pallas_kernels as pk
-        mode = pk.hash_kernels_mode()
-        kernel = self._kernel
-        if mode != "off" and ctx.conf.get_bool(
-                "spark.rapids.sql.fusion.hashKernels", True):
-            kernel = self._hash_kernel(mode)
 
         def run():
             from spark_rapids_tpu.exec.tpu import _concat_device
@@ -170,7 +106,7 @@ class TpuCountDistinctExec(PhysicalPlan):
             merged = _concat_device(
                 batches, self.children[0].output_schema(), growth,
                 coarse=True)
-            yield kernel(merged)
+            yield self._kernel(merged)
         return [run]
 
 

@@ -357,7 +357,7 @@ def _join_bucket(ctx, exec_, build: DeviceBatch,
 
     NB: this is deliberately the SIMPLIFIED twin of
     TpuShuffledHashJoinExec's main emission loop (exec/tpujoin.py run():
-    batched one-fetch totals, capacity speculation, dense/Pallas probe
+    batched one-fetch totals, capacity speculation, dense probe
     selection). Changes to join emission semantics there (new join
     types, size/cap layout of _totals, _expand's contract) must be
     mirrored here — the out-of-core tests diff both paths against the

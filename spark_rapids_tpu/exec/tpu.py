@@ -705,14 +705,14 @@ class TpuHashAggregateExec(TpuExec):
 
         if hash_split_idx is not None and update_kernel is not None:
             from spark_rapids_tpu.exec import outofcore as ooc
-            from spark_rapids_tpu.ops import pallas_kernels as pk
+            from spark_rapids_tpu.ops import tablekernels as tk
             base_update = update_kernel
 
             def _bucketed_update(b, level=0):
                 if (level >= 3
-                        or pk.hash_table_size(b.capacity) <= max_slots):
+                        or tk.hash_table_size(b.capacity) <= max_slots):
                     return base_update(b)
-                need = -(-pk.hash_table_size(b.capacity) // max_slots)
+                need = -(-tk.hash_table_size(b.capacity) // max_slots)
                 n = 2
                 while n < 2 * need and n < 64:
                     n <<= 1

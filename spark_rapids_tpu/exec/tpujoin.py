@@ -239,58 +239,12 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                 lambda b, s, lo: join_ops.join_probe_dense(
                     b, s, bk, sk, lo, table_size)))
 
-    def _hash_probe_kernel(self, ctx, build_schema, stream_schema):
-        """Pallas hash-table probe (ops/pallas_kernels.hash_join_probe)
-        replacing the union-lexsort probe when it applies: every key
-        fixed-width (the u64 image IS the exact value — strings fall
-        back to the sort probe), SPARK_RAPIDS_TPU_PALLAS selects the
-        pallas/interpret path, and spark.rapids.sql.fusion.hashKernels
-        is on. Same (counts, bstart, bperm) contract, so expand/totals/
-        match-flags/semi downstream run unchanged. Returns None when
-        inapplicable — the sort probe is always the correct fallback."""
-        if self.join_type == "cross" or not self._bkey:
-            return None
-        from spark_rapids_tpu.ops import pallas_kernels as pk
-        mode = pk.hash_kernels_mode()
-        if mode == "off":
-            return None
-        if not ctx.conf.get_bool("spark.rapids.sql.fusion.hashKernels",
-                                 True):
-            return None
-        for schema, keys in ((build_schema, self._bkey),
-                             (stream_schema, self._skey)):
-            for ki in keys:
-                if schema.dtypes[ki].is_string:
-                    return None
-        bkey, skey = self._bkey, self._skey
-
-        def build():
-            def probe(b, s):
-                from spark_rapids_tpu.ops.sortops import u64_key_image
-                bimgs, simgs = [], []
-                for bk, sk in zip(bkey, skey):
-                    bimgs.extend(u64_key_image(b.columns[bk]))
-                    simgs.extend(u64_key_image(s.columns[sk]))
-                bkv = join_ops._key_valid(b, bkey)
-                skv = join_ops._key_valid(s, skey)
-                return pk.hash_join_probe(
-                    bimgs, bkv, simgs, skv,
-                    pk.hash_table_size(b.capacity), mode=mode)
-            return jax.jit(probe)
-        return cached_jit(f"{self._sig}|hashprobe|{mode}", build)
-
     def partitions(self, ctx: ExecContext) -> List[Partition]:
         si, bi = self._sides()
         stream_parts = self.children[si].executed_partitions(ctx)
         build_parts = self.children[bi].executed_partitions(ctx)
         growth = ctx.conf.capacity_growth
         build_schema = self.children[bi].output_schema()
-        # pallas hash-table probe (opt-in via SPARK_RAPIDS_TPU_PALLAS):
-        # replaces the union-lexsort probe; the dense direct-index path
-        # still wins when scan stats bound the key range
-        hash_probe = self._hash_probe_kernel(
-            ctx, build_schema, self.children[si].output_schema())
-        probe_fn = hash_probe if hash_probe is not None else self._probe
         if len(stream_parts) != len(build_parts):
             # broadcast build side: one build partition shared by every
             # stream partition (full outer never broadcasts — the unmatched-
@@ -446,7 +400,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                             emitted = True
                             stream_rows.add(stream.num_rows_hint())
                             yield self._semi(stream,
-                                             probe_fn(build, stream)[0])
+                                             self._probe(build, stream)[0])
                 else:
                     # probe EVERY stream batch first (dispatch is async and
                     # nearly free), then fetch all expansion totals in ONE
@@ -464,7 +418,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                         oks_d = [r[3] for r in raw]
                         del raw  # or probes[i]=None below frees nothing
                     else:
-                        probes = [probe_fn(build, s) for s in streams]
+                        probes = [self._probe(build, s) for s in streams]
                     totals_d = [self._totals(build, s, *pr)
                                 for s, pr in zip(streams, probes)]
                     entry = cache.get(key) if cache is not None else None

@@ -28,7 +28,7 @@ class TpuDeviceManager:
         self.device = devices[0]
         # what the backend resolved to, on record: a run that meant the
         # chip and got XLA:CPU must be able to see that (obs/monitor.py
-        # status, bench.py, chip_smoke.py)
+        # status, benchmarks/run.py, chip_smoke.py)
         self.platform = self.device.platform
         self.device_kind = self.device.device_kind
         self.num_local_devices = len(devices)

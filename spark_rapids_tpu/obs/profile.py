@@ -7,7 +7,7 @@ The reference answers "where did this query's time go" with the Spark UI's
 per-operator SQL metrics + NVTX timelines; this report is the headless
 equivalent: ``session.profile_report()`` renders it, ``session.
 profile_json()`` returns the machine shape for tooling
-(tools/trace_summary.py consumes it, bench.py archives one per query).
+(tools/trace_summary.py consumes it).
 
 Inclusive/exclusive semantics: operator time is measured around each
 batch-pull in ``PhysicalPlan.executed_partitions``, so a parent's time

@@ -52,8 +52,8 @@ Consumers: the profile report's ``syncs`` section (obs/profile.py), a
 ``srt_host_syncs_total`` / ``srt_host_sync_seconds_total`` Prometheus
 series and live per-query counts on ``/api/query/<id>``
 (obs/monitor.py), the qualification report's sync-share ranking
-(tools/qualification.py), bench.py's per-query ``host_syncs``/``sync_s``
-record and tools/perfdiff.py's ``--sync-threshold`` gate.
+(tools/qualification.py) and the benchmark's ``syncs_per_query``
+(benchmarks/readers/ledger_delta.py).
 """
 
 from __future__ import annotations

@@ -261,9 +261,9 @@ def _sorted_payload_reduce(batch: DeviceBatch, key_idx: List[int],
     (aggregate.scala:338-396) which has no TPU analogue; this is the
     sort-based recipe re-tuned for XLA's scatter and sort lowering."""
     from spark_rapids_tpu.ops import hashing
-    from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
     from spark_rapids_tpu.ops.rowops import gather_columns
     from spark_rapids_tpu.ops.sortops import string_prefix8, u64_key_image
+    from spark_rapids_tpu.ops.tablekernels import compact_permutation
 
     capacity = batch.capacity
     if live is None:
@@ -379,12 +379,11 @@ def _sorted_dead_mask(info: "gb.GroupInfo", live) -> jnp.ndarray:
 def _hash_payload_reduce(batch: DeviceBatch, key_idx: List[int],
                          reductions: List[Tuple[str, int, DType]],
                          out_schema: Schema, live, max_slots: int):
-    """One-pass hash aggregation over the open-addressing slot table
-    (ops/pallas_kernels.hash_grouped_aggregate): every row probes to its
-    key's slot and folds its value into per-slot accumulators in the same
-    walk — no sort, no segment scan, no per-reduction re-sweep. This is
-    the cuDF open-addressing groupby shape (aggregate.scala:338-396) the
-    sorted path only approximates.
+    """Hash aggregation over the open-addressing slot table
+    (ops/tablekernels.hash_grouped_aggregate): every row probes to its
+    key's slot and each reduction is one segment op over the slots — no
+    sort. This is the cuDF open-addressing groupby shape
+    (aggregate.scala:338-396) the sorted path only approximates.
 
     Trace-time applicability (returns None -> caller falls through to the
     sorted/row-space branches):
@@ -401,7 +400,7 @@ def _hash_payload_reduce(batch: DeviceBatch, key_idx: List[int],
     and the per-key validity bits join the key image vector, so a real
     value sharing the sentinel stays a distinct group (the sorted path's
     nullsig spelling)."""
-    from spark_rapids_tpu.ops import pallas_kernels as pk
+    from spark_rapids_tpu.ops import tablekernels as tk
     from spark_rapids_tpu.ops.rowops import gather_columns
     from spark_rapids_tpu.ops.sortops import u64_key_image
 
@@ -410,7 +409,7 @@ def _hash_payload_reduce(batch: DeviceBatch, key_idx: List[int],
         col = batch.columns[ki]
         if col.dtype.is_string and col.dict_values is None:
             return None
-    T = pk.hash_table_size(capacity)
+    T = tk.hash_table_size(capacity)
     if T > max_slots:
         return None
     if live is None:
@@ -457,14 +456,14 @@ def _hash_payload_reduce(batch: DeviceBatch, key_idx: List[int],
         else:
             raise ValueError(f"unknown reduction kind: {kind}")
 
-    counts, rep, accs, nels = pk.hash_grouped_aggregate(imgs, live, jobs, T)
+    counts, rep, accs, nels = tk.hash_grouped_aggregate(imgs, live, jobs, T)
 
     # compact used slots to the front; n_used <= live rows <= capacity and
     # T >= 2*capacity, so the first ``capacity`` compacted entries hold
     # every used slot — output width stays the input bucket (as the
     # sorted path) and downstream shape bucketing is undisturbed
     used = counts > 0
-    slot_perm, n_used = pk.compact_permutation(used)
+    slot_perm, n_used = tk.compact_permutation(used)
     sel = slot_perm[:capacity]
     group_live = pos < n_used
     rep_row = jnp.clip(rep, 0, capacity - 1)[sel]
@@ -514,8 +513,8 @@ def _dict_matmul_reduce(batch: DeviceBatch, key_idx: List[int],
     import numpy as np
     from spark_rapids_tpu.columnar.batch import bucket_capacity
     from spark_rapids_tpu.ops import densered
-    from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
     from spark_rapids_tpu.ops.rowops import gather_column
+    from spark_rapids_tpu.ops.tablekernels import compact_permutation
 
     cards, strides, T = dict_info
     capacity = batch.capacity
@@ -970,7 +969,7 @@ def _rowspace_reduce(batch: DeviceBatch, key_idx: List[int],
     def slot_branch():
         _fast_ok, slot, used, n_used = _slot_state
         width = min(SLOT_TABLE, capacity)
-        from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
+        from spark_rapids_tpu.ops.tablekernels import compact_permutation
         slot_perm, _cnt = compact_permutation(used)
         leaves = reduce_core(width, slot, pos, lambda x: x, n_used,
                              slot_perm=slot_perm)
@@ -1064,11 +1063,11 @@ def count_distinct_reduce(batch: DeviceBatch, g2_idx: List[int],
     of group g (prefix-compact), counts[g] = distinct live G1 tuples.
     """
     from spark_rapids_tpu.ops import hashing
-    from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
     from spark_rapids_tpu.ops.rowops import packed_gather_vectors
     from spark_rapids_tpu.ops.sortops import (
         lexsort_permutation, string_prefix8, u64_key_image,
     )
+    from spark_rapids_tpu.ops.tablekernels import compact_permutation
     capacity = batch.capacity
     if live is None:
         live = batch.row_mask()

@@ -13,9 +13,8 @@ row-group's decode across the two sides of the scan pipeline:
     ``scanDeviceFallback`` with a reason, ranked by tools/qualification).
   * ``decode_rowgroup`` runs ON THE CONSUMER THREAD: ships every plan's
     buffers in ONE ``jax.device_put`` (plus the fallback columns' classic
-    host buffers) and expands them with the ops/pallas_kernels decode
-    family (jnp twins by default, =interpret for kernel-body CI, =1 for
-    attached TPUs) straight into PR 11's native column forms — dictionary
+    host buffers) and expands them with the ops/tablekernels decode
+    family straight into PR 11's native column forms — dictionary
     codes-only, (cap, stride/8) u64 char slabs, dense fixed-width arrays.
 
 Pages are cached encoded (memory/spill.py EncodedPageCache): a warm
@@ -521,11 +520,11 @@ def prepare_rowgroup(path: str, rg: int, pvals: dict, columns: List[str],
 def _decode_levels(up, meta, cap: int, n: int):
     import jax.numpy as jnp
 
-    from spark_rapids_tpu.ops import pallas_kernels as pk
+    from spark_rapids_tpu.ops import tablekernels as tk
     row_mask = jnp.arange(cap, dtype=jnp.int32) < n
     if meta["max_def"] == 0 or "lv_words" not in up:
         return row_mask
-    levels = pk.hybrid_expand(up["lv_words"], up["lv_out_start"],
+    levels = tk.hybrid_expand(up["lv_words"], up["lv_out_start"],
                               up["lv_kind"], up["lv_value"],
                               up["lv_bit_start"], up["lv_bw"], cap)
     return (levels == meta["max_def"]) & row_mask
@@ -558,21 +557,21 @@ def _apply_ts(vals, unit):
 
 
 def _decode_codes(up, cap_or_n: int):
-    from spark_rapids_tpu.ops import pallas_kernels as pk
-    return pk.hybrid_expand(up["cd_words"], up["cd_out_start"],
+    from spark_rapids_tpu.ops import tablekernels as tk
+    return tk.hybrid_expand(up["cd_words"], up["cd_out_start"],
                             up["cd_kind"], up["cd_value"],
                             up["cd_bit_start"], up["cd_bw"], cap_or_n)
 
 
 def _decode_column(name: str, plan: dict, up: dict, dt, cap: int,
                    dict_state: Optional[dict], i: int):
-    """One uploaded plan -> DeviceColumn (eager jnp/pallas dispatch)."""
+    """One uploaded plan -> DeviceColumn (eager jnp dispatch)."""
     import jax.numpy as jnp
 
     from spark_rapids_tpu.columnar import dtype as dtypes
     from spark_rapids_tpu.columnar.batch import bucket_capacity
     from spark_rapids_tpu.columnar.column import DeviceColumn
-    from spark_rapids_tpu.ops import pallas_kernels as pk
+    from spark_rapids_tpu.ops import tablekernels as tk
     meta = plan["meta"]
     kind = plan["kind"]
     n = meta["n"]
@@ -587,13 +586,13 @@ def _decode_column(name: str, plan: dict, up: dict, dt, cap: int,
 
     if kind == "fixed_plain":
         nv = bucket_capacity(max(meta["nn"], 1))
-        vals_v = pk.plain_fixed(up["vals"], meta["pkind"], nv)
+        vals_v = tk.plain_fixed(up["vals"], meta["pkind"], nv)
         return _finish_fixed(dt, vals_v, validity, meta, fill)
 
     if kind == "fixed_delta":
         parts = []
         for j, total in meta["delta_pages"]:
-            parts.append(pk.delta_unpack(
+            parts.append(tk.delta_unpack(
                 up["dl_words"], up[f"d{j}_out_start"],
                 up[f"d{j}_bit_width"], up[f"d{j}_min_delta"],
                 up[f"d{j}_bit_start"], up[f"d{j}_first"], total))
@@ -605,14 +604,14 @@ def _decode_column(name: str, plan: dict, up: dict, dt, cap: int,
     if kind == "fixed_dict":
         nv = bucket_capacity(max(meta["nn"], 1))
         codes_v = _decode_codes(up, nv)
-        dvals = pk.plain_fixed(up["dv_words"], meta["pkind"],
+        dvals = tk.plain_fixed(up["dv_words"], meta["pkind"],
                                max(meta["card"], 1))
         vals_v = dvals[jnp.clip(codes_v, 0, max(meta["card"] - 1, 0))]
         return _finish_fixed(dt, vals_v, validity, meta, fill)
 
     if kind == "str_plain":
         nv = up["st"].shape[0]
-        slab_v = pk.slab_pack(up["chars"], up["st"], up["ln"],
+        slab_v = tk.slab_pack(up["chars"], up["st"], up["ln"],
                               nv, meta["stride"])
         idx = jnp.clip(_value_positions(validity), 0, nv - 1)
         slab = jnp.where(validity[:, None], slab_v[idx], jnp.uint64(0))

@@ -293,8 +293,8 @@ def filter_batch(batch: DeviceBatch, keep: jnp.ndarray) -> DeviceBatch:
         cols = [DeviceColumn(c.dtype, moved[2 * i], moved[2 * i + 1] & live)
                 for i, c in enumerate(batch.columns)]
         return DeviceBatch(batch.schema, cols, new_rows)
-    # stable partition via the O(n) prefix-count kernel (pallas on TPU)
-    from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
+    # stable partition via the O(n) prefix-count kernel
+    from spark_rapids_tpu.ops.tablekernels import compact_permutation
     perm, new_rows = compact_permutation(keep)
     return gather_batch(batch, perm, new_rows)
 
@@ -325,7 +325,7 @@ def concat_batches(batches: Sequence[DeviceBatch],
     schema = batches[0].schema
     idx = jnp.arange(out_capacity, dtype=jnp.int32)
     if keep_masks is not None:
-        from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
+        from spark_rapids_tpu.ops.tablekernels import compact_permutation
         flat_keep = jnp.concatenate(
             [k & b.row_mask() for k, b in zip(keep_masks, batches)])
         perm, total = compact_permutation(flat_keep)

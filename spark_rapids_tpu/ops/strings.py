@@ -1025,7 +1025,7 @@ def translate_string(ctx: EvalContext, col: DevCol, matching: str,
     total = col.offsets[capacity]
     live = (k < total) & (mapped >= 0)
     # stable compaction of surviving chars keeps row-major order
-    from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
+    from spark_rapids_tpu.ops.tablekernels import compact_permutation
     perm, _cnt = compact_permutation(live)
     new_chars = jnp.where(jnp.arange(nchars) <
                           jnp.cumsum(live.astype(jnp.int32))[-1],

@@ -136,7 +136,7 @@ def _compact_received(dtypes_, received, rcounts, n):
     """Flatten per-source (n, cap) received buffers into one compacted
     local batch. Stable liveness sorts keep source-major order, so row
     buffers and char slabs stay aligned after their separate compactions."""
-    from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
+    from spark_rapids_tpu.ops.tablekernels import compact_permutation
     shard_cap = received[0][1].shape[1]
     rcap = n * shard_cap
     live = (jnp.arange(shard_cap, dtype=jnp.int32)[None, :]

@@ -47,8 +47,8 @@ and drains its AOT pre-warm pass while still out of rotation
 (``_prime``).
 
 Stdout carries ONLY protocol lines: the real fd 1 is duped away and
-fd 1 rebound to stderr before the session boots (the bench.py worker's
-trick), so stray engine prints can never corrupt the channel.
+fd 1 rebound to stderr before the session boots, so stray engine
+prints can never corrupt the channel.
 """
 
 from __future__ import annotations

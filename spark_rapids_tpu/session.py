@@ -171,8 +171,7 @@ class TpuSparkSession:
         self.last_plan = None
         self.last_profile = None
         # adaptive-execution record of the last AQE query: stage count,
-        # rule decisions, final plan tree (sql/adaptive/executor.py);
-        # bench.py --aqe-sweep archives it per query
+        # rule decisions, final plan tree (sql/adaptive/executor.py)
         self.last_aqe: Optional[dict] = None
         # tenant/job-group tag (set_job_group): flows into every event,
         # the tenant.* metric labels, and live progress records — the
@@ -1004,8 +1003,7 @@ class TpuSparkSession:
 
     def profile_json(self) -> Optional[dict]:
         """Machine shape of the last query's profile (None when no
-        profiled query has run). Consumed by tools/trace_summary.py and
-        archived per query by bench.py."""
+        profiled query has run). Consumed by tools/trace_summary.py."""
         return None if self.last_profile is None else \
             self.last_profile.to_json()
 

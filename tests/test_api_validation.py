@@ -19,3 +19,13 @@ def test_every_known_exec_covered():
                "ShuffleExchangeExec", "ExpandExec", "GenerateExec",
                "WriteExec"):
         assert op in text, op
+
+
+def test_configs_doc_is_what_the_registry_generates():
+    """docs/configs.md is ``conf.help_text()``: regenerate it with a conf."""
+    import os
+    from spark_rapids_tpu.config import conf
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "configs.md")
+    with open(path) as f:
+        assert f.read() == conf.help_text() + "\n"

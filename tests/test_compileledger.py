@@ -174,7 +174,7 @@ class TestLedgerAttribution:
         """ROADMAP item 2's steady-state invariant, pinned: warm-up may
         compile, the second run of the same query MUST NOT — this is
         the regression test the whole-stage-fusion work must keep
-        green (and what bench.py's timed_compiles measures)."""
+        green (what the benchmark counts as `window_compiles`)."""
         from spark_rapids_tpu.models import tpch_data
         from spark_rapids_tpu.models.tpch import QUERIES
         lineitem = tpch_data.gen_lineitem(0.002)

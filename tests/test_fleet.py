@@ -406,10 +406,10 @@ class TestProcessFleet:
             jobs = [(t, q, fleet.submit(q, tenant=t, want_result=True))
                     for t in tenants for q in (spec_q1, spec_q6)]
             assert fleet.drain(timeout=600.0)
-            from bench import _results_match
+            from benchmarks.match import results_match
             for t, q, j in jobs:
                 assert j.status == "succeeded", (t, j.status, j.error)
-                assert _results_match(j.result(), oracle[q["query"]]), \
+                assert results_match(j.result(), oracle[q["query"]]), \
                     f"{t}/{q['query']}: result drifted from oracle"
             snap = fleet.snapshot(include_workers=False)
             assert snap["shedTotal"] == 0 and snap["workersLost"] == 0

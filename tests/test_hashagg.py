@@ -106,19 +106,6 @@ def test_hash_agg_forced_fanout_matches(session, rng):
     assert splits, "forced fan-out never engaged"
 
 
-def test_hash_agg_interpret_mode_exec(session, rng, monkeypatch):
-    """SPARK_RAPIDS_TPU_PALLAS=interpret drives the REAL Pallas
-    aggregation kernel body (interpreted) through the whole exec glue —
-    key-image assembly, null sentinels, slot compaction — against the
-    CPU oracle. This is the tier-1 CI of the kernel the chip runs."""
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_PALLAS", "interpret")
-    pdf = _click_frame(rng, n=600)
-    _hash_vs_sort_vs_cpu(
-        session, lambda s: _all_kinds(
-            s.create_dataframe(pdf, 2).group_by("k")),
-        sort_leg=False)
-
-
 def test_hash_agg_respects_conf_default_off(session, rng):
     # default-safe: without the conf the dispatch never takes the hash
     # branch (aggregate kernels carry no |hash marker)

@@ -4,10 +4,7 @@ Tier-1 oracle pins at a TINY artificial working-set budget
 (``spark.rapids.tpu.outOfCore.partitionBytes``): a join/agg/sort whose
 measured working set exceeds the budget must complete via grace
 partitioning + spill (spill events > 0, out-of-core operator counters
-advancing) with results identical to the CPU oracle. The full-scale
-sweep is ``bench.py --stress`` (BENCH_STRESS.json, gated by
-tools/perfdiff.py); a reduced-scale run of it lives in the slow tier
-(test_bench_stress marker below)."""
+advancing) with results identical to the CPU oracle."""
 
 import numpy as np
 import pandas as pd
@@ -167,26 +164,3 @@ def test_level_hash_changes_between_levels(session, rng):
         counts.append(tuple(int(x) for x in jax.device_get(c)))
         assert sum(counts[-1]) == len(df)
     assert len(set(counts)) > 1, "levels produced identical partitions"
-
-
-@pytest.mark.slow  # reduced-scale end-to-end bench tier (~1-2 min)
-def test_bench_stress_tier_writes_artifact(tmp_path):
-    import json
-    import os
-    import subprocess
-    import sys
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_STRESS_ROWS="60000",
-               BENCH_STRESS_BUDGET=str(1 << 20),
-               BENCH_STRESS_FILE=str(tmp_path / "BENCH_STRESS.json"),
-               BENCH_LOAD_WAIT_S="5")
-    r = subprocess.run([sys.executable, "bench.py", "--stress"],
-                       capture_output=True, text=True, env=env,
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))), timeout=900)
-    assert r.returncode == 0, r.stderr[-2000:]
-    doc = json.loads((tmp_path / "BENCH_STRESS.json").read_text())
-    assert doc["mode"] == "stress"
-    assert doc["verified"] is True
-    assert doc["spill_events_total"] > 0
-    assert doc["throughput_rows_per_s"] > 0

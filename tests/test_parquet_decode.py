@@ -72,24 +72,6 @@ def test_plain_and_dict_types_match_oracle(session, tmp_path, rng):
     assert _metric("scan.device.splits") > 0
 
 
-def test_interpret_mode_matches_oracle(session, tmp_path, rng,
-                                       monkeypatch):
-    """SPARK_RAPIDS_TPU_PALLAS=interpret runs the REAL kernel bodies on
-    CPU (the PR 12 kernel-twin pattern) — same oracle equality."""
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_PALLAS", "interpret")
-    rows = 200
-    df = pd.DataFrame({
-        "i": np.arange(rows, dtype=np.int64),
-        "f": rng.random(rows),
-        "s": [f"str{k % 11}" for k in range(rows)],
-        "ni": pd.array([None if k % 4 == 0 else k for k in range(rows)],
-                       dtype="Int64"),
-    })
-    p = tmp_path / "t.parquet"
-    df.to_parquet(str(p), row_group_size=60, index=False)
-    _assert_equal(_read(session, p, False), _read(session, p, True))
-
-
 def test_delta_binary_packed(session, tmp_path, rng):
     rows = 500
     tbl = pa.table({

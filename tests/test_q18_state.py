@@ -273,7 +273,7 @@ def _gather_form(batch, keep):
     """filter_batch as it was before the carrying sort: the compaction
     permutation and a gather of every column by it."""
     from spark_rapids_tpu.ops import rowops
-    from spark_rapids_tpu.ops.pallas_kernels import compact_permutation
+    from spark_rapids_tpu.ops.tablekernels import compact_permutation
     perm, rows = compact_permutation(keep & batch.row_mask())
     return rowops.gather_batch(batch, perm, rows)
 
