@@ -101,14 +101,48 @@ class Schema:
         return Schema(names, dts)
 
 
+class SplitAttrs(dict):
+    """What a scan's decode worker computed for its split, riding the
+    frame's ``attrs``: the dictionary hints (``srt_dict_fact``) and the
+    prepared columns (``srt_prepared``). pandas deep-copies ``attrs``
+    whenever a frame or a column is derived from another and compares
+    them when frames are concatenated; megabytes of arrays would be
+    copied at every column access, and compared with an error. So this
+    copies as itself and equals only itself: it rides by reference, and
+    nothing writes into it."""
+
+    def __deepcopy__(self, memo):
+        return self
+
+    def __eq__(self, other):
+        return self is other
+
+    def __ne__(self, other):
+        return self is not other
+
+
+class PreparedColumns(SplitAttrs):
+    """{column name: (dtype, data, validity)}: the fixed-width columns of
+    one decoded split of ``rows`` rows, already in the device layout
+    (column.prepared_fixed_buffers). ``nbytes``: what the buffers hold
+    beyond the frame's own memory."""
+
+    def __init__(self, rows: int):
+        super().__init__()
+        self.rows = rows
+        self.nbytes = 0
+
+
 def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
                         cap: int, dict_encode: bool,
                         dict_state: Optional[dict], dict_numerics: bool,
-                        blocked_chars: int):
+                        blocked_chars: int,
+                        prepared: Optional[PreparedColumns] = None):
     """The host half of ``DeviceBatch.from_pandas`` (its ``upload.build``
     span): every column's device-layout buffers, dictionary probe and
     char slab. Returns (host_bufs, dict_metas, slab_metas), one entry a
-    column, and the number of string columns built codes-only."""
+    column, the number of string columns built codes-only and the number
+    of fixed-width columns shipped as the decode worker prepared them."""
     from spark_rapids_tpu.columnar.column import (
         host_dict_encode_hinted, host_dict_encode_stateful, np_build_slab,
         slab_stride_for,
@@ -125,10 +159,13 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
     host_bufs = []
     dict_metas = []
     slab_metas = []
-    codes_only = 0
+    codes_only = shipped = 0
+    if prepared is not None and prepared.rows != n:
+        prepared = None  # the frame was cut since, like a stale hint
     # positional iteration: join outputs may carry duplicate column names
     for i, dt in enumerate(schema.dtypes):
-        fact = hints.get(str(df.columns[i])) if hints else None
+        name = str(df.columns[i])
+        fact = hints.get(name) if hints else None
         if fact is not None and len(fact[0]) != n:
             fact = None
         encode = dict_encode and (dict_numerics or dt.is_string)
@@ -151,8 +188,18 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
             # registry, where there is one): asking again gives the same
             # answer, so build unencoded
             encode = False
-        values, validity = _pandas_to_numpy(df.iloc[:, i], dt)
-        bufs = DeviceColumn.build_host_buffers(values, validity, dt, cap)
+        prep = prepared.get(name) if prepared else None
+        if prep is not None and prep[0] == dt and len(prep[1]) == cap:
+            # the decode worker left this column in the device layout:
+            # shipped as it is, nothing allocated, copied or scanned (its
+            # buffers may be shared, so nothing here writes into them)
+            bufs = prep[1:]
+            values, validity = bufs[0][:n], bufs[1][:n]
+            shipped += 1
+        else:
+            values, validity = _pandas_to_numpy(df.iloc[:, i], dt)
+            bufs = DeviceColumn.build_host_buffers(values, validity, dt,
+                                                   cap)
         # ``dict_numerics=False`` (file-scan uploads): only string
         # columns are dictionary-probed — the numeric probe+encode is
         # an element-wise pass per column per batch on the upload hot
@@ -212,7 +259,10 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
     REGISTRY.counter("scan.upload.stringColumns").add(
         sum(dt.is_string for dt in schema.dtypes))
     REGISTRY.counter("scan.upload.codesOnlyColumns").add(codes_only)
-    return host_bufs, dict_metas, slab_metas, codes_only
+    REGISTRY.counter("scan.upload.fixedColumns").add(
+        sum(not dt.is_string for dt in schema.dtypes))
+    REGISTRY.counter("scan.upload.shippedColumns").add(shipped)
+    return host_bufs, dict_metas, slab_metas, codes_only, shipped
 
 
 @jax.tree_util.register_pytree_node_class
@@ -290,7 +340,9 @@ class DeviceBatch:
                     dict_state: Optional[dict] = None,
                     dict_numerics: bool = True,
                     blocked_chars: int = 0,
-                    device=None) -> "DeviceBatch":
+                    device=None,
+                    prepared: Optional[PreparedColumns] = None
+                    ) -> "DeviceBatch":
         """Host -> device transition (reference: GpuRowToColumnarExec /
         HostColumnarToGpu, GpuRowToColumnarExec.scala:45-502).
 
@@ -305,22 +357,26 @@ class DeviceBatch:
         instead of packed chars+offsets — row movement then rides 2-D
         lane-contiguous row gathers and packed chars only materialize if
         an operator genuinely reads them (spark.rapids.sql.dict.
-        blockedChars)."""
+        blockedChars). ``prepared``: the columns of ``df`` that the scan's
+        decode worker left in the device layout (PreparedColumns), which
+        the build ships as they are; the caller vouches that ``df`` is
+        the worker's frame, row for row."""
         if schema is None:
             schema = Schema.from_pandas(df)
         n = len(df)
         cap = capacity if capacity is not None else bucket_capacity(n)
         with TRACER.span("upload.build", rows=n,
                          columns=len(schema.dtypes)) as sp:
-            host_bufs, dict_metas, slab_metas, codes_only = \
+            host_bufs, dict_metas, slab_metas, codes_only, shipped = \
                 _build_host_columns(df, schema, n, cap, dict_encode,
                                     dict_state, dict_numerics,
-                                    blocked_chars)
+                                    blocked_chars, prepared)
             nbytes = 0
             if sp is not None:
                 nbytes = sum(int(getattr(b, "nbytes", 0))
                              for bufs in host_bufs for b in bufs)
-                sp.set(bytes=nbytes, codes_only=codes_only)
+                sp.set(bytes=nbytes, codes_only=codes_only,
+                       shipped=shipped)
         # ``device``: explicit placement for sharded scans (mesh execution
         # uploads partition i to mesh device i so data is born distributed)
         with TRACER.span("upload.put", bytes=nbytes):

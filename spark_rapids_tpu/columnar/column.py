@@ -16,6 +16,7 @@ This keeps every XLA program shape-static while allowing dynamic result sizes
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -568,6 +569,45 @@ def string_host_buffers_have_nul(bufs, n: int) -> bool:
     chars, _validity, offsets = bufs[0], bufs[1], bufs[2]
     used = int(offsets[n])
     return bool(used and (chars[:used] == 0).any())
+
+
+@functools.lru_cache(maxsize=16)
+def shared_validity(n: int, capacity: int) -> np.ndarray:
+    """The validity ``build_host_buffers`` gives a column of ``n`` rows
+    and no null: one read-only array for every such column of every
+    batch of that size (a scan's full row groups all share one, its
+    partial ones one a length), so it is written once and never again."""
+    v = np.zeros(capacity, dtype=np.bool_)
+    v[:n] = True
+    v.flags.writeable = False
+    return v
+
+
+def prepared_fixed_buffers(values: np.ndarray, dtype: DType, capacity: int,
+                           scale: int = 1):
+    """``build_host_buffers``'s (data, validity) for a fixed-width column
+    with no null, made by the thread that decoded ``values`` (the scan's
+    decode workers, sources._attach_prepared) so that the upload ships
+    them as they are. A full batch whose values are already the device's
+    is ``values`` itself, read-only and not copied; anything else is
+    written once into a ``capacity``-long buffer with the null fill
+    behind row ``n``. ``scale``: timestamps to micros in that same pass,
+    a multiplier, or a negative floor divisor (numpy's own rounding when
+    it casts datetime64[ns] down)."""
+    n = len(values)
+    assert n <= capacity and values.dtype == dtype.np_dtype
+    if n == capacity and scale == 1:
+        values.flags.writeable = False
+        return values, shared_validity(n, capacity)
+    dpad = np.empty(capacity, dtype=dtype.np_dtype)
+    if scale == 1:
+        dpad[:n] = values
+    elif scale > 1:
+        np.multiply(values, scale, out=dpad[:n])
+    else:
+        np.floor_divide(values, -scale, out=dpad[:n])
+    dpad[n:] = dtypes.null_fill_value(dtype)
+    return dpad, shared_validity(n, capacity)
 
 
 def dict_factorize_hint(values, is_string: bool):

@@ -12,7 +12,9 @@ from typing import Iterator, List
 
 import pandas as pd
 
-from spark_rapids_tpu.columnar.batch import DeviceBatch, Schema
+from spark_rapids_tpu.columnar.batch import (
+    DeviceBatch, Schema, SplitAttrs,
+)
 from spark_rapids_tpu.exec.base import ExecContext, Partition, PhysicalPlan
 
 
@@ -194,12 +196,18 @@ def upload_partition(ctx: ExecContext, part: Partition, schema: Schema,
             if is_scan:
                 note_scan_stats(ctx.session, df, declared_stats)
             for lo in range(0, max(len(df), 1), max_rows):
+                prepared = None
                 if double_buffer and lo == 0 and len(df) <= max_rows:
                     # whole-frame chunk: decode already produced a fresh
                     # RangeIndex frame; the reset_index copy is pure cost
                     # on the upload hot path (legacy reader keeps it —
                     # rollback reproduces the old path exactly)
                     chunk = df
+                    # a scan's own frame, whole: what its decode worker
+                    # left in the device layout goes with it (any other
+                    # frame may have changed under the same names)
+                    if is_scan:
+                        prepared = df.attrs.get("srt_prepared")
                 else:
                     # a sibling of scan.upload, not a child: the copy of
                     # every byte of a re-chunked split happens out here
@@ -212,9 +220,9 @@ def upload_partition(ctx: ExecContext, part: Partition, schema: Schema,
                             # re-chunked split: slice the worker's
                             # factorize hints positionally so they survive
                             # (from_pandas drops length-mismatched hints)
-                            chunk.attrs["srt_dict_fact"] = {
+                            chunk.attrs["srt_dict_fact"] = SplitAttrs({
                                 nm: (codes[lo:lo + max_rows], u)
-                                for nm, (codes, u) in hints.items()}
+                                for nm, (codes, u) in hints.items()})
                         if _sp is not None:
                             _sp.set(rows=len(chunk))
                 with TRACER.span("scan.upload", partition=i,
@@ -232,7 +240,8 @@ def upload_partition(ctx: ExecContext, part: Partition, schema: Schema,
                             dict_numerics=dict_numerics,
                             blocked_chars=blocked,
                             device=(mesh_devs[i % len(mesh_devs)]
-                                    if mesh_devs else None))
+                                    if mesh_devs else None),
+                            prepared=prepared)
                         _sc.add_bytes(batch.device_memory_size())
                     # host->device transfer attribution (host buffer
                     # build + device_put dispatch) against the upload

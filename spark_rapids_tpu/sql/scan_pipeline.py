@@ -73,13 +73,18 @@ _BUDGET_STALLS = REGISTRY.counter("scan.prefetch.budgetStalls")
 
 def _nbytes(obj) -> int:
     """Host bytes a decoded split retains in the prefetch queue: pandas
-    frames by column memory_usage, deviceDecode RawRowGroups (and
+    frames by column memory_usage (and the prepared buffers beside
+    them, sources._attach_prepared), deviceDecode RawRowGroups (and
     anything else plan-shaped) by their ``nbytes``."""
     if obj is None:
         return 0
     mu = getattr(obj, "memory_usage", None)
     if mu is not None:
-        return int(mu(deep=False).sum())
+        # plus what the worker's device-layout buffers hold of their own
+        # (padded and converted columns; a full batch's share the frame's)
+        prepared = obj.attrs.get("srt_prepared")
+        return int(mu(deep=False).sum()) \
+            + (prepared.nbytes if prepared is not None else 0)
     return int(getattr(obj, "nbytes", 0) or 0)
 
 

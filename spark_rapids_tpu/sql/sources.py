@@ -14,7 +14,9 @@ from typing import List, Optional, Tuple
 import numpy as np
 import pandas as pd
 
-from spark_rapids_tpu.columnar.batch import Schema
+from spark_rapids_tpu.columnar.batch import (
+    PreparedColumns, Schema, SplitAttrs, bucket_capacity,
+)
 from spark_rapids_tpu.exec.base import ExecContext, Partition
 from spark_rapids_tpu.obs.trace import TRACER
 
@@ -398,7 +400,8 @@ class ParquetSource(DataSource):
                     df = self._append_partition_values(
                         _arrow_decode(table, direct), pvals)
                     if pipelined:
-                        df = _attach_dict_hints(df, table)
+                        df = _attach_prepared(
+                            _attach_dict_hints(df, table), table)
                     if sp is not None:
                         sp.set(rows=len(df))
                 return df
@@ -626,7 +629,10 @@ class OrcSource(DataSource):
                 if isinstance(table, pa.RecordBatch):
                     table = pa.Table.from_batches([table])
                 df = _arrow_decode(table, direct)
-                return _attach_dict_hints(df, table) if pipelined else df
+                if pipelined:
+                    df = _attach_prepared(_attach_dict_hints(df, table),
+                                          table)
+                return df
             return decode
         if not splits:
             def empty():
@@ -712,7 +718,64 @@ def _attach_dict_hints(df: pd.DataFrame, table) -> pd.DataFrame:
             if not nul:
                 hints[str(df.columns[i])] = h
     if hints:
-        df.attrs["srt_dict_fact"] = hints
+        df.attrs["srt_dict_fact"] = SplitAttrs(hints)
+    return df
+
+
+# prepared_fixed_buffers' ``scale`` for an Arrow timestamp unit
+_TO_MICROS = {"s": 1_000_000, "ms": 1_000, "us": 1, "ns": -1_000}
+
+
+def _attach_prepared(df: pd.DataFrame, table) -> pd.DataFrame:
+    """Leave every fixed-width column the upload would only copy in the
+    layout the device takes, ON THE DECODE WORKER, as
+    ``df.attrs["srt_prepared"]`` (columnar/batch.py PreparedColumns) keyed
+    by column name: ``upload.build`` on the query's thread then ships the
+    buffers and allocates nothing (exec/transitions.py hands them over
+    for a frame that comes straight from a scan and was not cut).
+
+    ``table``: the Arrow table ``df`` was converted from, column for
+    column by position. A column is prepared when it is an integer, a
+    float, a date or a timestamp of the engine's types, holds no null
+    and arrived as one chunk: a full batch's data is Arrow's own buffer
+    seen as numpy, a partial batch's is written once behind the null
+    fill, and a timestamp of any unit becomes int64 micros in that one
+    pass (the value ``_pandas_to_numpy`` yields, never through pandas).
+    Everything else (nulls, booleans, strings, zoned timestamps, a
+    timestamp that holds pandas' NaT sentinel) is left to the upload's
+    own build, which needs the frame's column as it always did."""
+    from spark_rapids_tpu.columnar import dtype as dtmod
+    from spark_rapids_tpu.columnar.column import prepared_fixed_buffers
+    n = table.num_rows
+    if n == 0 or len(df) != n:
+        return df
+    cap = bucket_capacity(n)
+    prepared = PreparedColumns(n)
+    for i in range(table.num_columns):
+        col = table.column(i)
+        if col.null_count or col.num_chunks != 1:
+            continue
+        try:
+            dt = dtmod.from_arrow(col.type)
+        except TypeError:
+            continue
+        if dt.is_string or dt == dtmod.BOOL:  # bit-packed in Arrow
+            continue
+        chunk = col.chunk(0)
+        values = np.frombuffer(chunk.buffers()[1], dt.np_dtype, count=n,
+                               offset=chunk.offset * dt.itemsize)
+        scale = 1
+        if dt == dtmod.TIMESTAMP_US:
+            if col.type.tz is not None \
+                    or values.min() == np.iinfo(np.int64).min:
+                continue
+            scale = _TO_MICROS[col.type.unit]
+        data, validity = prepared_fixed_buffers(values, dt, cap, scale)
+        prepared[str(df.columns[i])] = (dt, data, validity)
+        if data is not values:  # padded or converted: memory of its own
+            prepared.nbytes += data.nbytes
+    if prepared:
+        df.attrs["srt_prepared"] = prepared
     return df
 
 
