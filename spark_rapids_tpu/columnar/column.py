@@ -212,6 +212,13 @@ class DeviceColumn:
             np.int32)
         return dchars, dstarts, dlens
 
+    def rebuilt_char_capacity(self) -> int:
+        """Chars ``_materialize_chars`` rebuilds for this dictionary column:
+        the static worst case capacity * longest value, bucketed."""
+        max_len = max((len(v.encode("utf-8")) for v in self.dict_values),
+                      default=1)
+        return _char_bucket(int(self.validity.shape[0]) * max_len)
+
     def _materialize_chars(self) -> None:
         """Rebuild chars+offsets from dictionary codes (jnp ops: works
         eagerly or inside a consumer's trace). Char capacity is the
@@ -225,9 +232,7 @@ class DeviceColumn:
         offsets = jnp.concatenate([
             jnp.zeros((1,), jnp.int32),
             jnp.cumsum(lens).astype(jnp.int32)])
-        max_len = max((len(v.encode("utf-8")) for v in self.dict_values),
-                      default=1)
-        char_cap = _char_bucket(cap * max_len)
+        char_cap = self.rebuilt_char_capacity()
         from spark_rapids_tpu.ops.rowops import rank_of_iota
         k = jnp.arange(char_cap, dtype=jnp.int32)
         out_row = jnp.clip(rank_of_iota(offsets, char_cap) - 1, 0, cap - 1)

@@ -32,6 +32,7 @@ from spark_rapids_tpu.sql.exprs.core import Expression
 from spark_rapids_tpu.sql.exprs.evalbridge import (
     eval_projection, make_context, to_device_column,
 )
+from spark_rapids_tpu.sql.exprs.stringexprs import counting_pattern_predicates
 from spark_rapids_tpu.sql.functions import SortOrder
 
 
@@ -191,7 +192,9 @@ def _fused_filter_source(node: PhysicalPlan, ctx: ExecContext):
                 pred = to_device_column(ectx, cond.eval_device(ectx))
                 return pred.data & pred.validity & batch.row_mask()
             return jax.jit(mask)
-        return node.children[0], cached_jit(sig, build), node.out_sel
+        return (node.children[0],
+                counting_pattern_predicates([cond])(cached_jit(sig, build)),
+                node.out_sel)
     return node, None, None
 
 
@@ -251,7 +254,8 @@ class TpuProjectExec(TpuExec):
             # the projection is traced eagerly per batch instead of through
             # the process-wide kernel cache (the reference similarly special
             # cases these, GpuTransitionOverrides.scala:110-123).
-            self._kernel = lambda batch: eval_projection(batch, bound, names)
+            self._kernel = counting_pattern_predicates(bound)(
+                lambda batch: eval_projection(batch, bound, names))
         elif any(as_ref(e) is not None for e in bound):
             # mixed projection: jit computes ONLY the derived outputs;
             # bare-reference outputs pass their column objects through
@@ -259,10 +263,11 @@ class TpuProjectExec(TpuExec):
             comp = [(n, e) for n, e in self.exprs if as_ref(e) is None]
             sig = "projectmix|" + "|".join(
                 f"{n}={expr_signature(e)}" for n, e in comp)
-            ckern = cached_jit(sig, lambda: jax.jit(
-                lambda batch: eval_projection(
-                    batch, [e for _n, e in comp],
-                    [n for n, _e in comp])))
+            ckern = counting_pattern_predicates(bound)(
+                cached_jit(sig, lambda: jax.jit(
+                    lambda batch: eval_projection(
+                        batch, [e for _n, e in comp],
+                        [n for n, _e in comp]))))
 
             def mixed_kernel(batch: DeviceBatch) -> DeviceBatch:
                 computed = ckern(batch)
@@ -282,8 +287,9 @@ class TpuProjectExec(TpuExec):
         else:
             sig = "project|" + "|".join(
                 f"{n}={expr_signature(e)}" for n, e in self.exprs)
-            self._kernel = cached_jit(sig, lambda: jax.jit(
-                lambda batch: eval_projection(batch, bound, names)))
+            self._kernel = counting_pattern_predicates(bound)(
+                cached_jit(sig, lambda: jax.jit(
+                    lambda batch: eval_projection(batch, bound, names))))
 
     def output_schema(self) -> Schema:
         cs = self.children[0].output_schema()
@@ -342,7 +348,7 @@ class TpuFilterExec(TpuExec):
         self._impure = has_nondeterministic(condition)
         if self._impure:
             # see TpuProjectExec: task-local state must be read at call time
-            self._kernel = kernel
+            self._kernel = counting_pattern_predicates([condition])(kernel)
         else:
             # names participate in the cache key: the closure bakes the
             # output Schema, so an aliased selection must not hit a
@@ -351,7 +357,8 @@ class TpuFilterExec(TpuExec):
                        else f"|sel={tuple(out_sel[1])}"
                             f":{','.join(out_sel[0])}")
             sig = "filter|" + expr_signature(condition) + sel_sig
-            self._kernel = cached_jit(sig, lambda: jax.jit(kernel))
+            self._kernel = counting_pattern_predicates([condition])(
+                cached_jit(sig, lambda: jax.jit(kernel)))
 
     def output_schema(self) -> Schema:
         cs = self.children[0].output_schema()
@@ -411,39 +418,44 @@ class TpuHashAggregateExec(TpuExec):
                     reductions.append((kind, input_idx, idt))
             mask_sig = ("|mask=" + expr_signature(pre_mask)
                         if pre_mask is not None else "")
-            self._kernel = cached_jit(
+            # every program over the child's batches counts the pattern
+            # predicates of the fused mask, the keys and the inputs
+            counted = counting_pattern_predicates(
+                ([pre_mask] if pre_mask is not None else [])
+                + key_exprs + list(p.update_inputs))
+            self._kernel = counted(cached_jit(
                 "aggupd|" + p.signature + mask_sig,
                 lambda: jax.jit(lambda b: agg_ops.aggregate_update(
                     b, key_exprs, p.update_inputs, reductions,
-                    p.partial_schema, mask_expr=pre_mask)))
+                    p.partial_schema, mask_expr=pre_mask))))
             # bounded-int composite grouping key variant (advisory scan
             # stats resolved at partitions() time; the ONLY compiled
             # grouping path — a miss re-executes via the deferred
             # speculation verification, ops/aggregate.dense_composite)
-            self._dense_update = lambda sizes: cached_jit(
+            self._dense_update = lambda sizes: counted(cached_jit(
                 f"aggupd|{p.signature}{mask_sig}|dense{sizes}",
                 lambda: jax.jit(lambda b, los: agg_ops.aggregate_update(
                     b, key_exprs, p.update_inputs, reductions,
                     p.partial_schema, mask_expr=pre_mask,
-                    dense=(los, sizes))))
+                    dense=(los, sizes)))))
             # one-pass hash-aggregation variant (spark.rapids.sql.agg.
             # hashAggEnabled): same program, the slot-table branch armed
             # with its slot budget — _hash_payload_reduce declines at
             # TRACE time where inapplicable, so this kernel is safe for
             # any batch
-            self._hash_update = lambda mt: cached_jit(
+            self._hash_update = lambda mt: counted(cached_jit(
                 f"aggupd|{p.signature}{mask_sig}|hash{mt}",
                 lambda: jax.jit(lambda b: agg_ops.aggregate_update(
                     b, key_exprs, p.update_inputs, reductions,
-                    p.partial_schema, mask_expr=pre_mask, hash_table=mt)))
+                    p.partial_schema, mask_expr=pre_mask, hash_table=mt))))
             # adaptive low-reduction skip: rows projected straight into the
             # partial layout (spark.rapids.sql.agg.skipAggPassReductionRatio)
             self._passthrough_kernel = _counting_rows(
-                _PASSTHROUGH_ROWS, cached_jit(
+                _PASSTHROUGH_ROWS, counted(cached_jit(
                     "aggpass|" + p.signature + mask_sig,
                     lambda: jax.jit(lambda b: agg_ops.aggregate_passthrough(
                         b, key_exprs, p.update_inputs, reductions,
-                        p.partial_schema, mask_expr=pre_mask))))
+                        p.partial_schema, mask_expr=pre_mask)))))
             # merging partials within the partition uses merge kinds
             self._merge_kernel = self._make_merge_kernel()
         else:

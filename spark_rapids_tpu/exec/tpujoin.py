@@ -25,6 +25,7 @@ from spark_rapids_tpu.utils.kernelcache import bucket_dim, cached_jit
 
 SUPPORTED_JOIN_TYPES = ("inner", "left", "right", "full", "leftsemi",
                         "leftanti", "cross")
+_INPUT_BYTES = REGISTRY.counter("join.inputBytes")
 
 
 def _start_host_copies(arrays) -> None:
@@ -139,10 +140,15 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
         sig = f"join|{jt}|{skey}|{bkey}|x{int(exact_long_strings)}"
         self._sig = sig
         self._skey, self._bkey = skey, bkey
+        # what every ``dispatch.join`` span of this join says of itself
+
+        def span_attrs(*_a):
+            return {"type": jt}
+        self._span_attrs = span_attrs
         self._probe = cached_jit(sig + "|probe", lambda: jax.jit(
             lambda b, s: join_ops.join_probe(
                 b, s, bkey, skey, cross=cross,
-                exact_long_strings=exact_long_strings)))
+                exact_long_strings=exact_long_strings)), span_attrs)
         outer = jt in ("left", "right", "full")
         swap = not self._stream_is_left
 
@@ -152,28 +158,31 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                    if outer else counts)
             return join_ops.join_expand(build, stream, counts, adj, bstart,
                                         bperm, out_cap, swap, s_caps, b_caps)
-        self._expand = cached_jit(sig + "|expand", lambda: jax.jit(
-            expand, static_argnums=(5, 6, 7)))
+        self._expand = cached_jit(
+            sig + "|expand",
+            lambda: jax.jit(expand, static_argnums=(5, 6, 7)),
+            lambda *a: {"type": jt, "out_cap": a[5]})
 
         def totals(build, stream, counts, bstart, bperm):
             adj = (join_ops.outer_adjusted_counts(stream, counts)
                    if outer else counts)
             return join_ops.expand_totals(build, stream, counts, adj, bperm,
                                           bstart)
-        self._totals = cached_jit(sig + "|totals", lambda: jax.jit(totals))
+        self._totals = cached_jit(sig + "|totals", lambda: jax.jit(totals),
+                                  span_attrs)
         if jt == "full":
             # a lambda of its own: cached_jit names the jitted function
             # after the family, and the module's function keeps its name
             self._match_flags = cached_jit(sig + "|mf", lambda: jax.jit(
-                lambda *a: join_ops.build_match_flags(*a)))
+                lambda *a: join_ops.build_match_flags(*a)), span_attrs)
             self._unmatched = cached_jit(sig + "|unm", lambda: jax.jit(
                 lambda b, m, ss: join_ops.unmatched_build_batch(
                     b, m, ss, swap_sides=False),
-                static_argnums=(2,)))
+                static_argnums=(2,)), span_attrs)
         if jt in ("leftsemi", "leftanti"):
             self._semi = cached_jit(sig + "|semi", lambda: jax.jit(
                 lambda s, c: join_ops.semi_anti_filter(
-                    s, c, anti=jt == "leftanti")))
+                    s, c, anti=jt == "leftanti")), span_attrs)
 
     def output_schema(self) -> Schema:
         ls = self.children[0].output_schema()
@@ -237,7 +246,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
             f"{self._sig}|dense{table_size}",
             lambda: jax.jit(
                 lambda b, s, lo: join_ops.join_probe_dense(
-                    b, s, bk, sk, lo, table_size)))
+                    b, s, bk, sk, lo, table_size)), self._span_attrs)
 
     def partitions(self, ctx: ExecContext) -> List[Partition]:
         si, bi = self._sides()
@@ -283,6 +292,18 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
         # rows of every stream batch handed to a probe, by what the host
         # knows without a sync (the row count where fetched, else capacity)
         stream_rows = REGISTRY.counter("join.stream.rows", type=jt)
+        # the out_cap every expand was dispatched with
+        expand_rows = REGISTRY.counter("join.expand.outRows", type=jt)
+        # rows of the build and of every stream batch, counted the same
+        # way, at the least width of a row of each schema, each input once
+        from spark_rapids_tpu.exec.tpu import _row_bytes
+        stream_row_bytes = _row_bytes(self.children[si].output_schema())
+        build_row_bytes = _row_bytes(build_schema)
+
+        def count_streams(streams):
+            rows = sum(s.num_rows_hint() for s in streams)
+            stream_rows.add(rows)
+            _INPUT_BYTES.add(rows * stream_row_bytes)
 
         dense = None
 
@@ -353,6 +374,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                     sp_local = lambda sl=spre: iter(sl)  # noqa: E731
                 build = _concat_device(list(bp_local()), build_schema,
                                        growth, coarse=True)
+                _INPUT_BYTES.add(build.num_rows_hint() * build_row_bytes)
                 matched_acc = None
                 emitted = False
                 nonlocal dense
@@ -370,8 +392,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                         # all of them (a per-batch device_get would block
                         # on a full round trip each)
                         streams = list(sp_local())
-                        stream_rows.add(sum(s.num_rows_hint()
-                                            for s in streams))
+                        count_streams(streams)
                         raw = [dkern(build, s, lo_arr) for s in streams]
                         oks_d = [r[3] for r in raw]
                         entry = cache.get(key) if cache is not None else None
@@ -398,7 +419,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                     else:
                         for stream in sp_local():
                             emitted = True
-                            stream_rows.add(stream.num_rows_hint())
+                            count_streams([stream])
                             yield self._semi(stream,
                                              self._probe(build, stream)[0])
                 else:
@@ -410,7 +431,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                     # simplified per-bucket twin — semantic changes to the
                     # probe/totals/expand contract must be mirrored there
                     streams = list(sp_local())
-                    stream_rows.add(sum(s.num_rows_hint() for s in streams))
+                    count_streams(streams)
                     oks_d = []
                     if dense:
                         raw = [dkern(build, s, lo_arr) for s in streams]
@@ -496,6 +517,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                         if spec_hit:
                             caps_used.append((out_cap, s_caps, b_caps))
                         emitted = True
+                        expand_rows.add(out_cap)
                         expanded = self._expand(build, stream, counts,
                                                 bstart, bperm, out_cap,
                                                 s_caps, b_caps)

@@ -121,6 +121,19 @@ def ends_with(ctx: EvalContext, col: DevCol, lit: str):
     return eq, col.validity
 
 
+def _pos_match(chars: jnp.ndarray, pat: bytes) -> jnp.ndarray:
+    """bool[nchars]: position i matches iff chars[i:i+m] == pat (a match may
+    run over a row's end: the caller bounds it by the row's extent)."""
+    nchars = chars.shape[0]
+    pos_match = jnp.ones((nchars,), dtype=jnp.bool_)
+    for j, c in enumerate(pat):
+        shifted = jnp.roll(chars, -j) if j else chars
+        # mask rolled-around tail
+        ok = (jnp.arange(nchars) + j) < nchars
+        pos_match = pos_match & (shifted == c) & ok
+    return pos_match
+
+
 def contains(ctx: EvalContext, col: DevCol, lit: str):
     pat = lit.encode("utf-8")
     m = len(pat)
@@ -130,13 +143,7 @@ def contains(ctx: EvalContext, col: DevCol, lit: str):
     chars = col.data
     nchars = chars.shape[0]
     capacity = ctx.capacity
-    # position i matches if chars[i:i+m] == pat
-    pos_match = jnp.ones((nchars,), dtype=jnp.bool_)
-    for j, c in enumerate(pat):
-        shifted = jnp.roll(chars, -j) if j else chars
-        # mask rolled-around tail
-        ok = (jnp.arange(nchars) + j) < nchars
-        pos_match = pos_match & (shifted == c) & ok
+    pos_match = _pos_match(chars, pat)
     # a match at position p counts for row r iff p >= off[r] and
     # p + m <= off[r+1]; per-row ANY is a prefix-sum range query (two
     # tiny gathers per ROW) instead of per-char row ids + segment_max
@@ -150,6 +157,43 @@ def contains(ctx: EvalContext, col: DevCol, lit: str):
     hi = jnp.clip(ends_r - (m - 1), starts_r, nchars)
     cnt = ps[hi] - ps[starts_r]
     return (cnt > 0) & (lens >= m), col.validity
+
+
+def like_segments(ctx: EvalContext, col: DevCol, head: str,
+                  middle: Tuple[str, ...], tail: str):
+    """LIKE of literal segments separated by ``%``: ``head%m1%m2...%tail``,
+    ``head`` and ``tail`` possibly empty (the pattern starts or ends with
+    ``%``). ``head`` is anchored to the row's start and ``tail`` to its end;
+    each middle segment is matched at its earliest position at or after
+    the end of the one before, which leaves the most room for the rest, so
+    a row matches iff this greedy walk ends at or before the tail's start.
+    A middle segment costs one pass a byte over the chars, a reversed
+    running minimum over them (the earliest match at or after every
+    position) and one gather a row."""
+    starts = col.offsets[:-1].astype(jnp.int32)
+    ends = col.offsets[1:].astype(jnp.int32)
+    chars = col.data
+    nchars = chars.shape[0]
+    hb, tb = head.encode("utf-8"), tail.encode("utf-8")
+    ok = (ends - starts) >= (len(hb) + len(tb))
+    if hb:
+        ok = ok & _match_at(col, starts, hb)
+    limit = ends - len(tb)
+    if tb:
+        ok = ok & _match_at(col, jnp.maximum(limit, 0), tb)
+    pos = starts + len(hb)
+    i = jnp.arange(nchars, dtype=jnp.int32)
+    for seg in middle:
+        pat = seg.encode("utf-8")
+        nxt = jax.lax.cummin(
+            jnp.where(_pos_match(chars, pat), i, jnp.int32(nchars)),
+            reverse=True)
+        at = nxt[jnp.clip(pos, 0, nchars - 1)]
+        # ``pos`` of an empty last row is nchars itself: the clip then
+        # reads a match before it, which ``at >= pos`` refuses
+        ok = ok & (at >= pos) & (at + len(pat) <= limit)
+        pos = at + len(pat)
+    return ok & (pos <= limit), col.validity
 
 
 def string_equal(ctx: EvalContext, lv: DevValue, rv: DevValue):
@@ -469,11 +513,7 @@ def locate(ctx: EvalContext, col: DevCol, lit: str,
         return jnp.where(lens >= 0, jnp.int32(max(start_pos, 1)), 0)
     chars = col.data
     nchars = chars.shape[0]
-    pos_match = jnp.ones((nchars,), dtype=jnp.bool_)
-    for j, c in enumerate(pat):
-        shifted = jnp.roll(chars, -j) if j else chars
-        ok = (jnp.arange(nchars) + j) < nchars
-        pos_match = pos_match & (shifted == c) & ok
+    pos_match = _pos_match(chars, pat)
     i = jnp.arange(nchars, dtype=jnp.int32)
     row_ids = _char_row_ids(col, capacity)
     fits = (i + m) <= col.offsets[row_ids + 1]
@@ -499,11 +539,7 @@ def replace_literal(ctx: EvalContext, col: DevCol, search: str,
         return col
     chars = col.data
     nchars = chars.shape[0]
-    pos_match = jnp.ones((nchars,), dtype=jnp.bool_)
-    for j, c in enumerate(pat):
-        shifted = jnp.roll(chars, -j) if j else chars
-        ok = (jnp.arange(nchars) + j) < nchars
-        pos_match = pos_match & (shifted == c) & ok
+    pos_match = _pos_match(chars, pat)
     i = jnp.arange(nchars, dtype=jnp.int32)
     row_ids = _char_row_ids(col, capacity)
     fits = (i + m) <= col.offsets[row_ids + 1]

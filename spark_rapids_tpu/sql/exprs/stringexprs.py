@@ -1,12 +1,18 @@
 """String expressions (reference: sql/rapids/stringFunctions.scala, 698 LoC).
 
 Device kernels live in ops/strings.py. Like the reference, complex regex is
-restricted: LIKE patterns that reduce to prefix/suffix/contains run on
-device, anything else tags the plan off (GpuOverrides.scala:334-379 applies
-the same restriction)."""
+restricted: LIKE patterns of literal segments separated by ``%`` run on
+device, ``_`` and escapes tag the plan off (GpuOverrides.scala:334-379
+applies the same kind of restriction).
+
+A pattern predicate (startswith/endswith/contains/LIKE) over a dictionary
+column is decided once a dictionary value on the host while the program is
+traced (``decide_by_value``): the rows are one gather by code, and a
+codes-only column stays lazy."""
 
 from __future__ import annotations
 
+import re
 from typing import List, Optional
 
 import jax.numpy as jnp
@@ -16,9 +22,10 @@ import pandas as pd
 from spark_rapids_tpu.columnar import dtypes
 from spark_rapids_tpu.columnar.batch import Schema
 from spark_rapids_tpu.columnar.dtype import DType
+from spark_rapids_tpu.obs.metrics import REGISTRY
 from spark_rapids_tpu.ops import strings as string_ops
 from spark_rapids_tpu.sql.exprs.core import (
-    DevCol, DevScalar, DevValue, EvalContext, Expression, Literal,
+    BoundRef, DevCol, DevScalar, DevValue, EvalContext, Expression, Literal,
 )
 from spark_rapids_tpu.sql.exprs.hostutil import host_unary_values, rebuild_series
 
@@ -135,8 +142,23 @@ class Substring(Expression):
         return rebuild_series(out, validity, dtypes.STRING, index)
 
 
+def decide_by_value(col: DevCol, match) -> Optional[jnp.ndarray]:
+    """``match(value)`` over a dictionary column, or None where ``col`` has
+    no dictionary. ``dict_values`` is static in the pytree, so the predicate
+    is decided here, on the host, once a value while the program is traced,
+    into a ``(card + 1,)`` table whose last entry (the NULL sentinel and the
+    padding) is false; the rows are one gather by code. Neither chars nor
+    offsets are read: a codes-only column stays lazy."""
+    if col.dict_values is None or col.dict_codes is None:
+        return None
+    card = len(col.dict_values)
+    table = np.zeros(card + 1, np.bool_)
+    table[:card] = [bool(match(v)) for v in col.dict_values]
+    return jnp.asarray(table)[jnp.clip(col.dict_codes, 0, card)]
+
+
 class _LiteralPatternPredicate(Expression):
-    """Base for startswith/endswith/contains with a literal pattern."""
+    """Base for startswith/endswith/contains/LIKE with a literal pattern."""
     fn_name = "?"
 
     def __init__(self, child: Expression, pattern: str):
@@ -158,7 +180,9 @@ class _LiteralPatternPredicate(Expression):
     def eval_device(self, ctx: EvalContext) -> DevValue:
         v = self.children[0].eval_device(ctx)
         assert isinstance(v, DevCol)
-        data, validity = self.device_kernel(ctx, v)
+        data, validity = decide_by_value(v, self.host_match), v.validity
+        if data is None:
+            data, validity = self.device_kernel(ctx, v)
         return DevCol(dtypes.BOOL, data, validity)
 
     def eval_host(self, df: pd.DataFrame) -> pd.Series:
@@ -192,72 +216,144 @@ class Contains(_LiteralPatternPredicate):
         return self.pattern in s
 
 
-class Like(Expression):
-    """SQL LIKE with literal pattern. Patterns reducible to
-    prefix/suffix/contains/exact run on device; others tag off (the
-    reference restricts regex the same way, GpuOverrides.scala:334-379)."""
+class Like(_LiteralPatternPredicate):
+    """SQL LIKE with a literal pattern of literal segments separated by
+    ``%`` (``a%``, ``%a``, ``%a%``, ``a%b``, ``%a%b%``...): over a
+    dictionary column decided once a value, over chars by the kernels of
+    ops/strings.py. ``_`` and escapes tag off (the reference restricts
+    regex the same way, GpuOverrides.scala:334-379)."""
+    fn_name = "like"
 
     def __init__(self, child: Expression, pattern: str):
-        super().__init__([child])
-        self.pattern = pattern
+        super().__init__(child, pattern)
         self._kind, self._needle = _classify_like(pattern)
-
-    def dtype(self, schema: Schema) -> DType:
-        return dtypes.BOOL
+        self._regex = re.compile(_like_to_regex(pattern), re.DOTALL)
 
     def sql_name(self, schema=None) -> str:
         return f"({self.children[0].sql_name(schema)} LIKE {self.pattern!r})"
 
     def device_supported(self, schema: Schema) -> Optional[str]:
         if self._kind is None:
-            return (f"LIKE pattern {self.pattern!r} needs general regex, "
-                    "which is not supported on TPU")
+            return (f"LIKE pattern {self.pattern!r} has {self._needle}, "
+                    "which is not supported on TPU (literal segments "
+                    "separated by % are)")
         return None
 
-    def eval_device(self, ctx: EvalContext) -> DevValue:
-        v = self.children[0].eval_device(ctx)
-        assert isinstance(v, DevCol)
-        if self._kind == "exact":
-            data, validity = string_ops.string_equal_literal(ctx, v, self._needle)
-        elif self._kind == "prefix":
-            data, validity = string_ops.starts_with(ctx, v, self._needle)
-        elif self._kind == "suffix":
-            data, validity = string_ops.ends_with(ctx, v, self._needle)
-        elif self._kind == "contains":
-            data, validity = string_ops.contains(ctx, v, self._needle)
-        else:
-            raise RuntimeError(self._kind)
-        return DevCol(dtypes.BOOL, data, validity)
+    def host_match(self, s: str) -> bool:
+        return self._regex.fullmatch(s) is not None
 
-    def eval_host(self, df: pd.DataFrame) -> pd.Series:
-        import re
-        regex = re.compile(_like_to_regex(self.pattern), re.DOTALL)
-        values, validity, index = host_unary_values(self.children[0].eval_host(df))
-        data = np.array([bool(regex.fullmatch(x)) if x is not None else False
-                         for x in values], dtype=np.bool_)
-        return rebuild_series(data, validity, dtypes.BOOL, index)
+    def device_kernel(self, ctx, col):
+        if self._kind == "exact":
+            return string_ops.string_equal_literal(ctx, col, self._needle)
+        if self._kind == "prefix":
+            return string_ops.starts_with(ctx, col, self._needle)
+        if self._kind == "suffix":
+            return string_ops.ends_with(ctx, col, self._needle)
+        if self._kind == "contains":
+            return string_ops.contains(ctx, col, self._needle)
+        if self._kind == "segments":
+            return string_ops.like_segments(ctx, col, *self._needle)
+        raise RuntimeError(self._kind)
 
 
 def _classify_like(p: str):
-    """Map a LIKE pattern to (kind, needle) if it avoids general regex."""
+    """(kind, needle) of a LIKE pattern the device runs: literal segments
+    separated by ``%``. The one-segment kinds keep their own kernels;
+    ``segments`` carries (head, middle segments, tail) for
+    ``string_ops.like_segments``. (None, what stands in the way) for a
+    pattern with ``_`` or an escape."""
     if "_" in p:
-        return None, None
-    body = p.strip("%")
-    if "%" in body:
-        return None, None  # interior wildcard
-    starts = p.startswith("%")
-    ends = p.endswith("%")
-    if starts and ends:
-        return "contains", body
-    if ends:
-        return "prefix", body
-    if starts:
-        return "suffix", body
-    return "exact", body
+        return None, "the single-character wildcard _"
+    if "\\" in p:
+        return None, "an escape (\\)"
+    parts = p.split("%")
+    if len(parts) == 1:
+        return "exact", p
+    head, tail = parts[0], parts[-1]
+    middle = tuple(seg for seg in parts[1:-1] if seg)
+    if not middle and not (head and tail):
+        if head:
+            return "prefix", head
+        if tail:
+            return "suffix", tail
+        return "contains", ""
+    if len(middle) == 1 and not head and not tail:
+        return "contains", middle[0]
+    return "segments", (head, middle, tail)
+
+
+_DICT_ROWS = REGISTRY.counter("expr.dictPredicate.rows")
+_REBUILT_BYTES = REGISTRY.counter("strings.charsRebuilt.bytes")
+
+
+def _pattern_sites(exprs):
+    """Every pattern predicate in the bound trees ``exprs``, as (its
+    ``expr.dictPredicate.batches{fn}`` counter, the ordinal of the column it
+    reads where its child is a bare reference, else None, the ordinals of
+    the string columns under a child that derives a string)."""
+    sites = []
+
+    def refs_under(e, out):
+        if isinstance(e, BoundRef) and e._dtype.is_string:
+            out.append(e.index)
+        for c in e.children:
+            refs_under(c, out)
+        return out
+
+    def visit(e):
+        if isinstance(e, _LiteralPatternPredicate):
+            child = e.children[0]
+            batches = REGISTRY.counter("expr.dictPredicate.batches",
+                                       fn=e.fn_name)
+            if isinstance(child, BoundRef):
+                sites.append((batches, child.index, ()))
+            else:
+                sites.append((batches, None, tuple(refs_under(child, []))))
+        for c in e.children:
+            visit(c)
+    for e in exprs:
+        visit(e)
+    return sites
+
+
+def counting_pattern_predicates(exprs):
+    """A wrapper for the kernels whose first argument is the batch ``exprs``
+    are bound to: ``wrap(kernel)`` is ``kernel`` with the string pattern
+    predicates of ``exprs`` counted at every call (a jitted program's trace
+    runs once; this runs every dispatch), from what the host knows without
+    a sync. A predicate over a bare reference to a dictionary column is
+    answered from the dictionary: ``expr.dictPredicate.rows`` takes the
+    batch's rows (``num_rows_hint``), ``expr.dictPredicate.batches{fn}``
+    one. A predicate over a derived string (``substring``, ``concat``...)
+    reads bytes, so each codes-only column under it is rebuilt from its
+    codes in the program: ``strings.charsRebuilt.bytes`` takes the char
+    capacity of each, and is touched with 0 where nothing was rebuilt.
+    ``wrap(kernel)`` is ``kernel`` itself where ``exprs`` hold no such
+    predicate."""
+    sites = _pattern_sites(exprs)
+    if not sites:
+        return lambda kernel: kernel
+
+    def wrap(kernel):
+        def run(batch, *rest):
+            rebuilt = 0
+            for batches, ref, derived_from in sites:
+                if ref is not None:
+                    if batch.columns[ref].dict_values is not None:
+                        _DICT_ROWS.add(batch.num_rows_hint())
+                        batches.add(1)
+                    continue
+                for i in derived_from:
+                    col = batch.columns[i]
+                    if col.is_lazy and not col.has_slab:
+                        rebuilt += col.rebuilt_char_capacity()
+            _REBUILT_BYTES.add(rebuilt)
+            return kernel(batch, *rest)
+        return run
+    return wrap
 
 
 def _like_to_regex(p: str) -> str:
-    import re
     out = []
     for ch in p:
         if ch == "%":

@@ -152,11 +152,13 @@ def name_program(fn, family: str):
     return fn
 
 
-def _wrap_ledgered(signature: str, fn):
+def _wrap_ledgered(signature: str, fn, span_attrs=None):
     """Dispatch context of a cached kernel. The ``dispatch.<family>``
     span times the host side of every call (an asynchronous dispatch:
     the device's own time by family is read from the profiler trace,
-    where ``name_program`` put the family). Compile ledger
+    where ``name_program`` put the family); ``span_attrs(*args)``, where
+    given, names the call's attributes on it, asked only while tracing is
+    on. Compile ledger
     (obs/compileledger.py): every call publishes its signature +
     argument references to a thread-local for the call's duration, so a
     backend compile fired inside it knows its kernel identity and input
@@ -166,13 +168,18 @@ def _wrap_ledgered(signature: str, fn):
     from spark_rapids_tpu.obs import compileledger as _cl
     span = "dispatch." + kernel_family(signature)
 
+    def spanned(a):
+        if span_attrs is not None and _TRACER.enabled:
+            return _TRACER.span(span, **span_attrs(*a))
+        return _TRACER.span(span)
+
     def wrapped(*a, **kw):
         if not _cl.LEDGER.enabled:
-            with _TRACER.span(span):
+            with spanned(a):
                 return fn(*a, **kw)
         d = _cl.dispatch_begin(signature, a, kw)
         try:
-            with _TRACER.span(span):
+            with spanned(a):
                 out = fn(*a, **kw)
         finally:
             entries = _cl.dispatch_end(d)
@@ -185,8 +192,10 @@ def _wrap_ledgered(signature: str, fn):
     return wrapped
 
 
-def cached_jit(signature: str, builder: Callable[[], Any]):
-    """Return the cached kernel for ``signature``, building it once.
+def cached_jit(signature: str, builder: Callable[[], Any],
+               span_attrs=None):
+    """Return the cached kernel for ``signature``, building it once;
+    ``span_attrs``: see ``_wrap_ledgered``.
 
     Hit/miss/build-time counters feed the process-wide observability
     registry (obs/metrics.py REGISTRY, names kernelCache.*); when the
@@ -213,7 +222,7 @@ def cached_jit(signature: str, builder: Callable[[], Any]):
     with _TRACER.span("kernelcache.build", signature=signature[:160]):
         fn = name_program(builder(), kernel_family(signature))
     _BUILD_TIME.record(time.perf_counter() - t0)
-    fn = _wrap_ledgered(signature, fn)
+    fn = _wrap_ledgered(signature, fn, span_attrs)
     with _LOCK:
         fn = _CACHE.setdefault(signature, fn)
     hook = _BUILD_HOOK

@@ -513,8 +513,8 @@ def test_tpch_kernels_lower_to_modules_named_by_family(session, qname,
     calls = {}
     wrap = kernelcache._wrap_ledgered
 
-    def recording(signature, fn):
-        wrapped = wrap(signature, fn)
+    def recording(signature, fn, span_attrs=None):
+        wrapped = wrap(signature, fn, span_attrs)
 
         def rec(*a, **kw):
             calls.setdefault(signature, (fn, a, kw))
