@@ -109,7 +109,10 @@ class SplitAttrs(dict):
     them when frames are concatenated; megabytes of arrays would be
     copied at every column access, and compared with an error. So this
     copies as itself and equals only itself: it rides by reference, and
-    nothing writes into it."""
+    nothing writes into it. ``nbytes``: what its buffers hold beyond the
+    frame's own memory, for the prefetch queue's budget."""
+
+    nbytes = 0
 
     def __deepcopy__(self, memo):
         return self
@@ -124,13 +127,11 @@ class SplitAttrs(dict):
 class PreparedColumns(SplitAttrs):
     """{column name: (dtype, data, validity)}: the fixed-width columns of
     one decoded split of ``rows`` rows, already in the device layout
-    (column.prepared_fixed_buffers). ``nbytes``: what the buffers hold
-    beyond the frame's own memory."""
+    (column.prepared_fixed_buffers)."""
 
     def __init__(self, rows: int):
         super().__init__()
         self.rows = rows
-        self.nbytes = 0
 
 
 def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
@@ -141,8 +142,10 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
     """The host half of ``DeviceBatch.from_pandas`` (its ``upload.build``
     span): every column's device-layout buffers, dictionary probe and
     char slab. Returns (host_bufs, dict_metas, slab_metas), one entry a
-    column, the number of string columns built codes-only and the number
-    of fixed-width columns shipped as the decode worker prepared them."""
+    column, and the span's counts: ``codes_only`` string columns built
+    as (validity, codes) alone, ``codes_shipped`` of them that went out
+    as the decode worker's own buffers, ``shipped`` fixed-width columns
+    that went out as the decode worker prepared them."""
     from spark_rapids_tpu.columnar.column import (
         host_dict_encode_hinted, host_dict_encode_stateful, np_build_slab,
         slab_stride_for,
@@ -150,7 +153,8 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
     from spark_rapids_tpu.obs.metrics import REGISTRY
     # per-column factorize hints precomputed by the scan pipeline's
     # decode workers (sources._attach_dict_hints), keyed by column
-    # name; only trusted when the frame was not re-chunked since
+    # name: (codes, uniques, ready buffers or None); only trusted when
+    # the frame was not re-chunked since
     hints = getattr(df, "attrs", None)
     hints = hints.get("srt_dict_fact") if hints else None
     # build every column's device-layout buffers host-side, then ship
@@ -159,7 +163,7 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
     host_bufs = []
     dict_metas = []
     slab_metas = []
-    codes_only = shipped = 0
+    codes_only = codes_shipped = shipped = 0
     if prepared is not None and prepared.rows != n:
         prepared = None  # the frame was cut since, like a stale hint
     # positional iteration: join outputs may carry duplicate column names
@@ -170,19 +174,21 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
             fact = None
         encode = dict_encode and (dict_numerics or dt.is_string)
         if fact is not None and encode and dt.is_string:
-            # hinted string column (the decode worker factorized it and
-            # found no NUL byte in its Arrow chars): encode first, and
-            # where the scan's registry accepts, (validity, codes) are
-            # the whole column — no object array, chars, offsets or
-            # prefix8 is built or shipped, as after any concat or
-            # exchange (DeviceColumn's codes-only form)
+            # hinted string column (the decode worker encoded it and
+            # found no NUL byte in its values): encode first, and where
+            # the scan's registry accepts, (validity, codes) are the
+            # whole column — no object array, chars, offsets or prefix8
+            # is built or shipped, as after any concat or exchange
+            # (DeviceColumn's codes-only form); where the worker's values
+            # are the registry's, its buffers go out untouched
             enc = host_dict_encode_hinted(fact, dt, cap, dict_state, i)
             if enc is not None:
-                vpad, codes, vals = enc
+                vpad, codes, vals, as_made = enc
                 host_bufs.append((None, vpad, codes))
                 dict_metas.append(vals)
                 slab_metas.append(0)
                 codes_only += 1
+                codes_shipped += as_made
                 continue
             # the hint does not encode (and has closed the scan's
             # registry, where there is one): asking again gives the same
@@ -205,8 +211,9 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
         # an element-wise pass per column per batch on the upload hot
         # path, and integer grouping keys ride the dense-key path
         # (spark.rapids.sql.agg.denseKeys) instead of dictionaries
-        enc = host_dict_encode_stateful(values, validity, dt, cap,
-                                        dict_state, i, fact=fact) \
+        enc = host_dict_encode_stateful(
+            values, validity, dt, cap, dict_state, i,
+            fact=fact[:2] if fact is not None else None) \
             if encode else None
         if enc is not None and dt.is_string:
             # only pay the slab scan when a dictionary was actually
@@ -259,10 +266,13 @@ def _build_host_columns(df: pd.DataFrame, schema: "Schema", n: int,
     REGISTRY.counter("scan.upload.stringColumns").add(
         sum(dt.is_string for dt in schema.dtypes))
     REGISTRY.counter("scan.upload.codesOnlyColumns").add(codes_only)
+    REGISTRY.counter("scan.upload.codesShippedColumns").add(codes_shipped)
     REGISTRY.counter("scan.upload.fixedColumns").add(
         sum(not dt.is_string for dt in schema.dtypes))
     REGISTRY.counter("scan.upload.shippedColumns").add(shipped)
-    return host_bufs, dict_metas, slab_metas, codes_only, shipped
+    return host_bufs, dict_metas, slab_metas, {
+        "codes_only": codes_only, "codes_shipped": codes_shipped,
+        "shipped": shipped}
 
 
 @jax.tree_util.register_pytree_node_class
@@ -367,7 +377,7 @@ class DeviceBatch:
         cap = capacity if capacity is not None else bucket_capacity(n)
         with TRACER.span("upload.build", rows=n,
                          columns=len(schema.dtypes)) as sp:
-            host_bufs, dict_metas, slab_metas, codes_only, shipped = \
+            host_bufs, dict_metas, slab_metas, counts = \
                 _build_host_columns(df, schema, n, cap, dict_encode,
                                     dict_state, dict_numerics,
                                     blocked_chars, prepared)
@@ -375,8 +385,7 @@ class DeviceBatch:
             if sp is not None:
                 nbytes = sum(int(getattr(b, "nbytes", 0))
                              for bufs in host_bufs for b in bufs)
-                sp.set(bytes=nbytes, codes_only=codes_only,
-                       shipped=shipped)
+                sp.set(bytes=nbytes, **counts)
         # ``device``: explicit placement for sharded scans (mesh execution
         # uploads partition i to mesh device i so data is born distributed)
         with TRACER.span("upload.put", bytes=nbytes):

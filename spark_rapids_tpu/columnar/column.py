@@ -17,7 +17,7 @@ This keeps every XLA program shape-static while allowing dynamic result sizes
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -646,6 +646,48 @@ def dict_factorize_hint(values, is_string: bool):
     return codes, uniques
 
 
+def _canonical_remap(sort_key: np.ndarray):
+    """(order, remap) of a dictionary's uniques: ``order`` sorts them into
+    the canonical order (identical value SETS across batches -> identical
+    dictionaries -> one compiled program), ``remap[old_code]`` is a
+    value's code in that order and the null sentinel ``card`` maps to
+    itself — also for an old code of -1, which indexes the same last
+    slot."""
+    card = len(sort_key)
+    order = np.argsort(sort_key, kind="stable")
+    remap = np.empty(card + 1, dtype=np.int32)
+    remap[order] = np.arange(card, dtype=np.int32)
+    remap[card] = card
+    return order, remap
+
+
+def dict_ready_buffers(codes: np.ndarray, has_null: bool,
+                       uniques: Sequence[str], capacity: int):
+    """A codes-only string column's whole payload — (validity bool
+    (capacity,), codes int32 (capacity,), values tuple) — made by the
+    thread that encoded the column (the scan's decode workers,
+    sources._attach_dict_hints) from its hint: ``codes`` int32 in
+    first-appearance order with -1 at a null (``has_null``: there is
+    one), ``uniques`` clean ``str`` values, 1 to DICT_MAX_CARD of them.
+    What ``host_dict_encode`` yields for the same hint, in one pass over
+    the codes, so that ``host_dict_encode_hinted`` ships it as it is
+    wherever its values are the scan's dictionary."""
+    n, card = len(codes), len(uniques)
+    order, remap = _canonical_remap(np.asarray(uniques, dtype=object))
+    out = np.empty(capacity, dtype=np.int32)
+    # -1 wraps to the last slot, the null sentinel's
+    np.take(remap, codes, out=out[:n], mode="wrap")
+    out[n:] = card
+    if not has_null:
+        vpad = shared_validity(n, capacity)
+    else:
+        vpad = np.zeros(capacity, dtype=np.bool_)
+        np.greater_equal(codes, 0, out=vpad[:n])
+        vpad.flags.writeable = False
+    out.flags.writeable = False  # shipped by reference, maybe more than once
+    return vpad, out, tuple(uniques[i] for i in order)
+
+
 def host_dict_encode(values: np.ndarray, validity: Optional[np.ndarray],
                      dtype: DType, capacity: int, fact=None):
     """Host-side dictionary probe+encode of a column being uploaded.
@@ -689,12 +731,7 @@ def host_dict_encode(values: np.ndarray, validity: Optional[np.ndarray],
         # python scalars: hashable, stable across numpy versions
         vals = arr.tolist()
         sort_key = arr
-    # canonical order: identical value SETS across batches -> identical
-    # dictionaries -> one compiled program
-    order = np.argsort(sort_key, kind="stable")
-    remap = np.empty(card + 1, dtype=np.int32)
-    remap[order] = np.arange(card, dtype=np.int32)
-    remap[card] = card  # null sentinel maps to itself
+    order, remap = _canonical_remap(sort_key)
     new_codes = remap[np.where(codes < 0, card, codes)]
     if validity is not None:
         # factorize saw canonicalized fill values at null rows as real
@@ -784,25 +821,42 @@ def host_dict_encode_stateful(values: np.ndarray,
 def host_dict_encode_hinted(fact, dtype: DType, capacity: int,
                             state: Optional[dict], key) -> Optional[tuple]:
     """Encode a scanned string column from the decode worker's hint alone
-    (``fact`` = ``dict_factorize_hint``'s (codes, uniques)): the column's
-    values are never touched. Returns (validity bool (capacity,), codes
-    int32 (capacity,), values tuple) — the codes-only column's whole
+    (``fact`` = (codes, uniques, ready): ``dict_factorize_hint``'s pair,
+    and ``dict_ready_buffers``' payload or None): the column's values are
+    never touched. Returns (validity bool (capacity,), codes int32
+    (capacity,), values tuple, shipped) — the codes-only column's whole
     payload — or None when the scan's registry does not accept the hint
-    (closed, an unseen value, uniques that are not clean strings). A null
-    row is the factorize NA sentinel, which is every value ``isna`` calls
-    missing, so the validity is read off the codes."""
+    (closed, an unseen value, uniques that are not clean strings).
+
+    ``shipped``: the worker's buffers went out as they are, because their
+    values ARE the scan's dictionary (or establish it, as the first
+    batch's do on any path); nothing is written into them. Any other
+    batch — a value missing, a value unseen, another capacity, a hint
+    sliced by a re-chunk — is remapped against the registry from the
+    hint's codes, where a null row is the factorize NA sentinel (every
+    value ``isna`` calls missing), so the validity is read off the
+    codes."""
     assert dtype.is_string, dtype
+    st = state.get(key) if state is not None else None
+    if st is False:
+        return None
+    ready = fact[2]
+    if ready is not None and len(ready[1]) == capacity \
+            and (st is None or st == ready[2]):
+        if st is None and state is not None:
+            state[key] = ready[2]
+        return ready + (True,)
     hint_codes = np.asarray(fact[0])
     validity = hint_codes >= 0
     # with ``fact`` a string column's ``values`` is read for its length
     # alone (both functions below), so the hint's codes stand in for it
     enc = host_dict_encode_stateful(hint_codes, validity, dtype, capacity,
-                                    state, key, fact=fact)
+                                    state, key, fact=fact[:2])
     if enc is None:
         return None
     vpad = np.zeros(capacity, dtype=np.bool_)
     vpad[:len(validity)] = validity
-    return vpad, enc[0], enc[1]
+    return vpad, enc[0], enc[1], False
 
 
 def _char_bucket(n: int, minimum: int = 16) -> int:

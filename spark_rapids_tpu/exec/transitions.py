@@ -218,11 +218,12 @@ def upload_partition(ctx: ExecContext, part: Partition, schema: Schema,
                             "srt_dict_fact")
                         if hints:
                             # re-chunked split: slice the worker's
-                            # factorize hints positionally so they survive
-                            # (from_pandas drops length-mismatched hints)
+                            # hints positionally so they survive
+                            # (from_pandas drops length-mismatched hints);
+                            # its whole-split buffers do not
                             chunk.attrs["srt_dict_fact"] = SplitAttrs({
-                                nm: (codes[lo:lo + max_rows], u)
-                                for nm, (codes, u) in hints.items()})
+                                nm: (codes[lo:lo + max_rows], u, None)
+                                for nm, (codes, u, _) in hints.items()})
                         if _sp is not None:
                             _sp.set(rows=len(chunk))
                 with TRACER.span("scan.upload", partition=i,

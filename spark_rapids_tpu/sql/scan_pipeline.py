@@ -73,18 +73,19 @@ _BUDGET_STALLS = REGISTRY.counter("scan.prefetch.budgetStalls")
 
 def _nbytes(obj) -> int:
     """Host bytes a decoded split retains in the prefetch queue: pandas
-    frames by column memory_usage (and the prepared buffers beside
-    them, sources._attach_prepared), deviceDecode RawRowGroups (and
-    anything else plan-shaped) by their ``nbytes``."""
+    frames by column memory_usage (and the worker's buffers beside them,
+    sources._attach_prepared and _attach_dict_hints), deviceDecode
+    RawRowGroups (and anything else plan-shaped) by their ``nbytes``."""
     if obj is None:
         return 0
     mu = getattr(obj, "memory_usage", None)
     if mu is not None:
         # plus what the worker's device-layout buffers hold of their own
-        # (padded and converted columns; a full batch's share the frame's)
-        prepared = obj.attrs.get("srt_prepared")
-        return int(mu(deep=False).sum()) \
-            + (prepared.nbytes if prepared is not None else 0)
+        # (padded and converted columns, dictionary codes; a full batch's
+        # fixed-width columns share the frame's)
+        return int(mu(deep=False).sum()) + sum(
+            obj.attrs[k].nbytes for k in ("srt_prepared", "srt_dict_fact")
+            if k in obj.attrs)
     return int(getattr(obj, "nbytes", 0) or 0)
 
 

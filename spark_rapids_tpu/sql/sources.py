@@ -18,6 +18,7 @@ from spark_rapids_tpu.columnar.batch import (
     PreparedColumns, Schema, SplitAttrs, bucket_capacity,
 )
 from spark_rapids_tpu.exec.base import ExecContext, Partition
+from spark_rapids_tpu.obs.metrics import REGISTRY
 from spark_rapids_tpu.obs.trace import TRACER
 
 
@@ -647,78 +648,114 @@ def _arrow_to_pandas(table) -> pd.DataFrame:
     return df
 
 
-def _arrow_string_has_nul(col) -> bool:
-    """True when a NUL byte lies among the chars an Arrow string column's
-    rows span (one pass over each chunk's data buffer, well under a
-    millisecond a row group), or when the layout is not one this scan
-    reads (then nothing is known, and the answer is the cautious one)."""
-    import pyarrow as pa
-    if pa.types.is_string(col.type):
-        off_dt = np.dtype(np.int32)
-    elif pa.types.is_large_string(col.type):
-        off_dt = np.dtype(np.int64)
-    else:
-        return True
-    for chunk in col.chunks:
-        if len(chunk) == 0:
-            continue
-        _validity, offsets, data = chunk.buffers()
-        offs = np.frombuffer(offsets, off_dt, count=len(chunk) + 1,
-                             offset=chunk.offset * off_dt.itemsize)
-        lo, hi = int(offs[0]), int(offs[-1])
-        # ``all`` is false at the first zero byte, and makes no temporary
-        if hi > lo and not np.frombuffer(data, np.uint8, count=hi - lo,
-                                         offset=lo).all():
-            return True
-    return False
+_ARROW_HINTS = {o: REGISTRY.counter("scan.hint.arrowColumns", outcome=o)
+                for o in ("hinted", "card", "nul")}
+
+
+def _arrow_dictionary(col):
+    """(dictionary, [indices of every chunk]) of an Arrow string column,
+    every chunk under ONE dictionary: Arrow's own hash, bytes compared
+    exactly, the interpreter lock released, no Python object a row."""
+    import pyarrow.compute as pc
+    enc = pc.dictionary_encode(col)
+    if enc.num_chunks > 1:
+        enc = enc.unify_dictionaries()
+    return enc.chunk(0).dictionary, [c.indices for c in enc.chunks]
+
+
+def _arrow_dict_hint(col):
+    """``column.dict_factorize_hint`` for a string column of the scan's
+    Arrow table, made from the column itself: the same probe (the first
+    ``_DICT_PROBE`` rows, the same gate), the same codes (first
+    appearance, -1 at a null) as int32, the same uniques as ``str`` — and
+    beside them ``column.dict_ready_buffers``' (validity, codes, values),
+    the column as the device takes it. None when the column is no
+    dictionary candidate or one of its values holds a NUL byte: a NUL in
+    any row is a NUL in that row's value, and Arrow keeps 'a' and 'a\\x00'
+    apart where pandas' ``factorize`` merges them, so the at most
+    DICT_MAX_CARD values are all there is to look at."""
+    from spark_rapids_tpu.columnar.column import (
+        _DICT_PROBE, DICT_MAX_CARD, dict_ready_buffers,
+    )
+    n = len(col)
+    if n == 0:
+        return None
+    dictionary, indices = _arrow_dictionary(col.slice(0, _DICT_PROBE))
+    if len(dictionary) > DICT_MAX_CARD \
+            or len(dictionary) > max(64, min(n, _DICT_PROBE) // 4):
+        _ARROW_HINTS["card"].add(1)
+        return None
+    if n > _DICT_PROBE:  # else the probe was the column
+        dictionary, indices = _arrow_dictionary(col)
+    if not 0 < len(dictionary) <= DICT_MAX_CARD:  # 0: every row is null
+        _ARROW_HINTS["card"].add(1)
+        return None
+    uniques = dictionary.to_pylist()
+    if any("\x00" in u for u in uniques):
+        _ARROW_HINTS["nul"].add(1)
+        return None
+    has_null = col.null_count > 0
+    codes = [(c.fill_null(-1) if c.null_count else c).to_numpy()
+             for c in indices]
+    codes = codes[0] if len(codes) == 1 else np.concatenate(codes)
+    _ARROW_HINTS["hinted"].add(1)
+    return codes, uniques, dict_ready_buffers(
+        codes, has_null, uniques, bucket_capacity(n))
 
 
 def _attach_dict_hints(df: pd.DataFrame, table) -> pd.DataFrame:
-    """Precompute per-column dictionary factorizations ON THE DECODE
-    WORKER (the scan pipeline runs this inside the split's decode task)
-    and attach them as ``df.attrs["srt_dict_fact"]`` keyed by column
-    name. The host->device upload then pays only an O(cardinality) remap
-    per dictionary column (columnar/column.py dict_factorize_hint) and
-    builds the column codes-only (columnar/batch.py) — the probe +
-    factorize and the chars build were the largest consumer-thread
-    upload costs.
+    """Precompute per-column dictionary encodings ON THE DECODE WORKER
+    (the scan pipeline runs this inside the split's decode task) and
+    attach them as ``df.attrs["srt_dict_fact"]`` keyed by column name:
+    (codes, uniques, ready). The host->device upload then builds the
+    column codes-only (columnar/batch.py) and, where the batch's values
+    are the scan's dictionary, ships ``ready`` as it is
+    (column.host_dict_encode_hinted); otherwise it pays an O(cardinality)
+    remap of the codes.
 
     ``table``: the Arrow table ``df`` was converted from, column for
-    column by position; columns of ``df`` past the table's are the
-    caller's constants (hive partition values, one path component a
-    frame). THE NUL GATE runs here: pandas 3.x ``factorize`` merges 'a'
-    with 'a\\x00' (column.string_host_buffers_have_nul), the upload of a
-    hinted column builds no chars to look at, and the merged unique hides
-    the NUL — so a hinted column's Arrow chars are scanned, and one that
-    holds a NUL byte gets NO hint: the upload then takes the unhinted
-    path, finds the NUL in the chars it builds and closes the column's
-    dictionary for the scan.
+    column by position. Its string columns are encoded by Arrow
+    (_arrow_dict_hint): the frame's own column is never read, so it stays
+    what every fallback path expects; a column of any other layout gets
+    no hint. Columns of ``df`` past the table's are the caller's
+    constants (hive partition values, one path component a frame) and go
+    through pandas (column.dict_factorize_hint); their one value is all
+    there is to check for a NUL. A column with a NUL byte gets NO hint
+    either way: the upload then takes the unhinted path, finds the NUL in
+    the chars it builds and closes the column's dictionary for the scan.
 
-    Only object/string columns are hinted: file-scan uploads skip the
-    numeric dictionary probe entirely (exec/transitions.py
-    scan_dict_numerics), and string ``to_numpy(object)`` is exactly the
-    value space ``_pandas_to_numpy`` hands the encoder; datetime and
-    nullable-extension columns convert through fills and unit casts, so
-    they would need a value-space translation the hint cannot do."""
+    Only string columns are hinted: file-scan uploads skip the numeric
+    dictionary probe entirely (exec/transitions.py scan_dict_numerics);
+    datetime and nullable-extension columns convert through fills and
+    unit casts, so they would need a value-space translation the hint
+    cannot do."""
+    import pyarrow as pa
+
     from spark_rapids_tpu.columnar.column import dict_factorize_hint
-    hints = {}
+    hints = SplitAttrs()
     for i in range(df.shape[1]):
+        name = str(df.columns[i])
+        if i < table.num_columns:
+            col = table.column(i)
+            if pa.types.is_string(col.type) \
+                    or pa.types.is_large_string(col.type):
+                h = _arrow_dict_hint(col)
+                if h is not None:
+                    hints[name] = h
+                    validity, codes, _values = h[2]
+                    hints.nbytes += h[0].nbytes + codes.nbytes \
+                        + (validity.nbytes if col.null_count else 0)
+            continue  # any other layout: nothing known, so no hint
         s = df.iloc[:, i]
         if (isinstance(s.dtype, np.dtype) and s.dtype.kind == "O") \
                 or str(s.dtype) in ("str", "string"):
             h = dict_factorize_hint(s.to_numpy(dtype=object),
                                     is_string=True)
-            if h is None:
-                continue
-            if i < table.num_columns:
-                nul = _arrow_string_has_nul(table.column(i))
-            else:  # a constant: its one value is all there is to check
-                nul = any(isinstance(u, str) and "\x00" in u
-                          for u in h[1])
-            if not nul:
-                hints[str(df.columns[i])] = h
+            if h is not None and not any(
+                    isinstance(u, str) and "\x00" in u for u in h[1]):
+                hints[name] = h + (None,)
     if hints:
-        df.attrs["srt_dict_fact"] = SplitAttrs(hints)
+        df.attrs["srt_dict_fact"] = hints
     return df
 
 

@@ -52,8 +52,9 @@ def _schema(table: pa.Table) -> Schema:
 
 def _build(df, schema, prepared):
     n = len(df)
-    return _build_host_columns(df, schema, n, bucket_capacity(n), True,
-                               {}, False, 0, prepared)
+    *bufs, counts = _build_host_columns(
+        df, schema, n, bucket_capacity(n), True, {}, False, 0, prepared)
+    return (*bufs, counts["shipped"])
 
 
 def _todays_build(df, schema):
@@ -94,8 +95,7 @@ def test_full_batch_ships_the_decoders_own_memory(np_dtype):
     df = _decoded(table)
     schema = _schema(table)
     before = _counts()
-    bufs, _d, _s, _codes_only, shipped = _build(
-        df, schema, df.attrs["srt_prepared"])
+    bufs, *_rest, shipped = _build(df, schema, df.attrs["srt_prepared"])
     assert shipped == 1
     assert _counts() == (before[0] + 1, before[1] + 1)
     data, validity = bufs[0]
