@@ -9,16 +9,30 @@ distinct().group_by().count() spelling — into a two-level aggregation:
 
 The reference executes that chain as two full cuDF hash aggregations
 (aggregate.scala:40-225 keeps the expansion; each level is a real pass).
-On this backend every aggregation pass pays a sort + segment sweep, so
-the chain dominates distinct-heavy queries (q16: 1.7s of 2.4s). This
+On this backend every aggregation pass pays a sort + segment sweep. This
 pass recognizes the chain on the FINAL physical plan and replaces it
 with one operator running a single sorted pass over the G1 key tuple
 (ops/aggregate.count_distinct_reduce): distinct-tuple boundaries and
-G2-group boundaries come from the same sorted images.
+G2-group boundaries come from the same sorted images. On one v5e chip
+(my chip runs, PR 35; PERF.md section 6), TPC-H q16 at SF30, 2.7M rows
+in one 2^22-slot batch: the chain's four aggregates 0.83 s of device
+time an execution and 513 s of a cold run's compiles, the one operator
+0.023 s and 76 s; at SF100 over 2^24 slots the operator as it stood
+before PR 35 (seven chained sort passes and eighteen gathers) took
+4.54 s where the chain took 5.71 s, and the operator as it is 0.136 s
+(101 s of compile).
+
+Both spellings reach the one operator and kernel. The level-2 function
+says which count it is: count(*) (the hand-written chain) counts every
+distinct tuple, NULL a value like another; count(K) over the distinct
+key K (what ``F.count_distinct`` expands to) is SQL's count(DISTINCT K),
+where a tuple whose K is NULL is not counted and a group of such tuples
+alone reads 0 and stays. Which it is is static in the traced program.
 
 Gated to: single-chip (no mesh — the chain's exchanges carry real
 distribution on a mesh), bare-column keys, a lone count(*) (count(lit 1))
-result, and results that are plain key references or the count.
+or count(K) of the one key that G1 adds to G2, and results that are plain
+key references or the count.
 """
 
 from __future__ import annotations
@@ -32,60 +46,65 @@ from spark_rapids_tpu.columnar import dtypes
 from spark_rapids_tpu.columnar.batch import DeviceBatch, Schema
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.exec.base import ExecContext, Partition, PhysicalPlan
+from spark_rapids_tpu.obs.metrics import REGISTRY
+from spark_rapids_tpu.obs.trace import TRACER
 from spark_rapids_tpu.utils.kernelcache import cached_jit
+
+# counted as the one program is dispatched, by what the host knows without
+# a sync: the row count where it has been fetched, else the capacity, so
+# under a join or a projection they read slots dispatched, not live rows
+_INPUT_ROWS = REGISTRY.counter("agg.distinct.inputRows")
+_INPUT_BYTES = REGISTRY.counter("agg.distinct.inputBytes")
+_BATCHES = REGISTRY.counter("agg.distinct.batches")
+
+# the spelling a fused chain came from, by its level-2 function
+FORMS = {False: "distinct_count", True: "count_distinct"}
 
 
 class TpuCountDistinctExec(PhysicalPlan):
     """One-pass grouped distinct count (see module docstring).
 
     ``out_plan``: for each output column, ("key", child_col_idx) or
-    ("count", None), in output-schema order."""
+    ("count", None), in output-schema order. ``skip_null``: the count is
+    count(DISTINCT rest key), not count(*) over the distinct tuples."""
 
     columnar_output = True
+    # the groups come out in the input's capacity
+    padded_output = True
 
     def __init__(self, child: PhysicalPlan, out_schema: Schema,
                  out_plan: List[Tuple[str, Optional[int]]],
-                 g2_idx: List[int], rest_idx: List[int]):
+                 g2_idx: List[int], rest_idx: List[int],
+                 skip_null: bool = False):
         super().__init__([child])
         self._schema = out_schema
         self.out_plan = list(out_plan)
         self.g2_idx = list(g2_idx)
         self.rest_idx = list(rest_idx)
+        self.skip_null = skip_null
         sig = (f"cdist|{tuple(g2_idx)}|{tuple(rest_idx)}"
-               f"|{tuple(out_plan)}|{out_schema!r}")
+               f"|{tuple(out_plan)}|{out_schema!r}|n{int(skip_null)}")
         self._sig = sig
-
-        def finish(batch: DeviceBatch, rep_rows, counts, n_groups):
-            from spark_rapids_tpu.ops.rowops import gather_columns
-            cap = batch.capacity
-            live = jnp.arange(cap, dtype=jnp.int32) < n_groups
-            key_cols = gather_columns(
-                [batch.columns[ci] for kind, ci in self.out_plan
-                 if kind == "key"], rep_rows, live)
-            cols: List[DeviceColumn] = []
-            ki = 0
-            for kind, _ci in self.out_plan:
-                if kind == "key":
-                    cols.append(key_cols[ki])
-                    ki += 1
-                else:
-                    cols.append(DeviceColumn(dtypes.INT64, counts, live))
-            return DeviceBatch(self._schema, cols,
-                               n_groups.astype(jnp.int32))
 
         def kernel(batch: DeviceBatch) -> DeviceBatch:
             from spark_rapids_tpu.ops.aggregate import count_distinct_reduce
-            rep_rows, counts, n_groups = count_distinct_reduce(
-                batch, self.g2_idx, self.rest_idx)
-            return finish(batch, rep_rows, counts, n_groups)
-        self._kernel = cached_jit(sig, lambda: jax.jit(kernel))
+            keys, counts, n_groups = count_distinct_reduce(
+                batch, self.g2_idx, self.rest_idx, skip_null=skip_null)
+            live = jnp.arange(batch.capacity, dtype=jnp.int32) < n_groups
+            cols = [keys[ci] if kind == "key"
+                    else DeviceColumn(dtypes.INT64, counts, live)
+                    for kind, ci in self.out_plan]
+            return DeviceBatch(self._schema, cols, n_groups)
+        self._kernel = cached_jit(
+            sig, lambda: jax.jit(kernel),
+            lambda b: {"capacity": b.capacity, "form": FORMS[skip_null]})
 
     def output_schema(self) -> Schema:
         return self._schema
 
     def describe(self) -> str:
         return (f"TpuCountDistinctExec(g2={self.g2_idx}, "
-                f"distinct={self.rest_idx})")
+                f"distinct={self.rest_idx}, {FORMS[self.skip_null]})")
 
     def fingerprint_extra(self) -> str:
         return self._sig
@@ -95,17 +114,26 @@ class TpuCountDistinctExec(PhysicalPlan):
         growth = ctx.conf.capacity_growth
 
         def run():
-            from spark_rapids_tpu.exec.tpu import _concat_device
+            from spark_rapids_tpu.exec.tpu import _concat_device, _row_bytes
             batches = [b for p in child_parts for b in p()]
             if not batches:
                 yield DeviceBatch.empty(self._schema)
                 return
+            child_schema = self.children[0].output_schema()
+            nbytes = sum(b.device_memory_size() for b in batches)
             # coarse materialization: the fused pass's kernel signature
             # rides the merged capacity — the shape-bucket ladder keeps
             # it stable across input sizes (compile.shapeBuckets)
-            merged = _concat_device(
-                batches, self.children[0].output_schema(), growth,
-                coarse=True)
+            with TRACER.span("agg.distinct.collapse", batches=len(batches),
+                             bytes=nbytes) as sp:
+                merged = _concat_device(batches, child_schema, growth,
+                                        coarse=True)
+                if sp is not None:
+                    sp.set(capacity=merged.capacity)
+            rows = sum(b.num_rows_hint() for b in batches)
+            _BATCHES.add(len(batches))
+            _INPUT_ROWS.add(rows)
+            _INPUT_BYTES.add(rows * _row_bytes(child_schema))
             yield self._kernel(merged)
         return [run]
 
@@ -117,12 +145,22 @@ def _strip_alias(e):
     return e
 
 
-def _is_count_star(e) -> bool:
+def _counted(e, g1_names: List[str]) -> Optional[str]:
+    """What a level-2 count counts: ``"*"`` for count(*) (count(lit 1)),
+    the G1 key's name for count(K) over a reference to the inner
+    aggregate's output (the physical plan's are bound), None for anything
+    else."""
     from spark_rapids_tpu.sql.exprs.aggregates import Count
-    from spark_rapids_tpu.sql.exprs.core import Literal
+    from spark_rapids_tpu.sql.exprs.core import BoundRef, Literal
     e = _strip_alias(e)
-    return (isinstance(e, Count)
-            and isinstance(_strip_alias(e.children[0]), Literal))
+    if not isinstance(e, Count):
+        return None
+    arg = _strip_alias(e.children[0])
+    if isinstance(arg, Literal):
+        return "*"
+    if isinstance(arg, BoundRef) and 0 <= arg.index < len(g1_names):
+        return g1_names[arg.index]
+    return None
 
 
 def _skip_coalesce(node: PhysicalPlan) -> PhysicalPlan:
@@ -179,10 +217,17 @@ def _match_chain(node: PhysicalPlan):
     g1_names = [n for n, _ in plan_i.grouping]
     if [n for n, _ in plan_i.results] != g1_names:
         return None
-    # outer: one count(*) and all other results bare G2 key references
-    if len(plan_o.agg_fns) != 1 or not _is_count_star(plan_o.agg_fns[0]):
+    # outer: one count and all other results bare G2 key references. The
+    # count is count(*), or count(K) of the one key G1 adds to G2: a count
+    # of any other column is another question than how many distinct
+    # tuples a group holds
+    if len(plan_o.agg_fns) != 1:
         return None
+    counted = _counted(plan_o.agg_fns[0], g1_names)
     g2_names = [n for n, _ in plan_o.grouping]
+    rest_names = [n for n in g1_names if n not in set(g2_names)]
+    if counted is None or (counted != "*" and rest_names != [counted]):
+        return None
     # an empty outer grouping (global count-distinct) must NOT fuse: the
     # unfused final aggregate runs force_single_group and returns one
     # row (count 0) on empty input, while the fused kernel would return
@@ -190,11 +235,6 @@ def _match_chain(node: PhysicalPlan):
     if not g2_names:
         return None
     if not set(g2_names) <= set(g1_names):
-        return None
-    # the count_distinct_reduce nullsig packs one validity bit per G1 key
-    # into a uint32 (ops/aggregate.py count_distinct_reduce); wider
-    # tuples would overflow the shift (ADVICE r4 #3)
-    if len(g1_names) > 32:
         return None
     # outer grouping exprs must be bare references to the SAME-named
     # inner G1 output — a computed expr aliased to an inner output name
@@ -226,7 +266,7 @@ def _match_chain(node: PhysicalPlan):
     out_plan: List[Tuple[str, Optional[int]]] = []
     for name, e in plan_o.results:
         e = _strip_alias(e)
-        if _is_count_star(e):
+        if e is plan_o.agg_fns[0]:
             out_plan.append(("count", None))
             continue
         if isinstance(e, Col) and e.name in g2_names:
@@ -239,13 +279,17 @@ def _match_chain(node: PhysicalPlan):
     if sum(1 for k, _ in out_plan if k == "count") != 1:
         return None
     g2_idx = [g1_child_idx[n] for n in g2_names]
-    rest_idx = [g1_child_idx[n] for n in g1_names if n not in set(g2_names)]
+    rest_idx = [g1_child_idx[n] for n in rest_names]
     return TpuCountDistinctExec(child, plan_o.output_schema, out_plan,
-                                g2_idx, rest_idx)
+                                g2_idx, rest_idx, skip_null=counted != "*")
 
 
 def fuse_count_distinct(plan: PhysicalPlan) -> PhysicalPlan:
     """Bottom-up rewrite replacing every matched chain."""
     plan.children = [fuse_count_distinct(c) for c in plan.children]
     replaced = _match_chain(plan)
-    return replaced if replaced is not None else plan
+    if replaced is None:
+        return plan
+    REGISTRY.counter("agg.distinct.plans",
+                     form=FORMS[replaced.skip_null]).add(1)
+    return replaced

@@ -39,6 +39,10 @@ class PhysicalPlan:
 
     # True if this operator's output is device columnar (TPU path)
     columnar_output = False
+    # True if this operator's batches systematically carry far more
+    # capacity than rows, so an exchange above it counts and shrinks them
+    # (TpuShuffleExchangeExec._padded_producer)
+    padded_output = False
 
     def __init__(self, children: Sequence["PhysicalPlan"] = ()):  # noqa: D401
         self.children: List[PhysicalPlan] = list(children)

@@ -401,6 +401,8 @@ class TpuHashAggregateExec(TpuExec):
     per-batch update, then concat + merge of the (small) partial results —
     the reference's exact loop shape, each step one fused XLA program."""
 
+    padded_output = True
+
     def __init__(self, child: PhysicalPlan, plan: AggPlan, mode: str,
                  pre_mask: Optional[Expression] = None):
         super().__init__([child])
@@ -957,6 +959,8 @@ class TpuLocalLimitExec(TpuExec):
     Later batches past the limit yield empty slices instead of breaking
     the loop: the extra enqueues are cheaper than one sync."""
 
+    padded_output = True
+
     def __init__(self, child: PhysicalPlan, limit: int):
         super().__init__([child])
         self.limit = limit
@@ -1320,18 +1324,13 @@ class TpuShuffleExchangeExec(TpuExec):
     def _padded_producer(node: PhysicalPlan) -> bool:
         """Does the subtree below (up to the next exchange) contain an
         operator whose batches systematically carry far more capacity than
-        rows? Aggregates always do; limits and semi/anti joins compact
-        hard within unchanged capacity. Plain filters are deliberately NOT
-        counted: at moderate selectivity the shrink's count-fetch sync +
-        gathers measured slower than just concatenating (a very selective
-        filter below a join is the accepted trade-off)."""
-        from spark_rapids_tpu.exec.tpujoin import TpuShuffledHashJoinExec
-        if isinstance(node, TpuHashAggregateExec):
-            return True
-        if isinstance(node, TpuLocalLimitExec):
-            return True
-        if (isinstance(node, TpuShuffledHashJoinExec)
-                and node.join_type in ("leftsemi", "leftanti")):
+        rows (``padded_output``)? Aggregates always do; limits and
+        semi/anti joins compact hard within unchanged capacity. Plain
+        filters are deliberately NOT counted: at moderate selectivity the
+        shrink's count-fetch sync + gathers measured slower than just
+        concatenating (a very selective filter below a join is the
+        accepted trade-off)."""
+        if node.padded_output:
             return True
         if isinstance(node, TpuShuffleExchangeExec):
             return False  # already shrunk at that boundary

@@ -114,6 +114,11 @@ class TpuBroadcastExchangeExec(PhysicalPlan):
 class TpuShuffledHashJoinExec(PhysicalPlan):
     columnar_output = True
 
+    @property
+    def padded_output(self) -> bool:
+        # a semi or anti join compacts hard within its stream's capacity
+        return self.join_type in ("leftsemi", "leftanti")
+
     def __init__(self, left: PhysicalPlan, right: PhysicalPlan,
                  join_type: str, left_keys: List[int], right_keys: List[int],
                  exact_long_strings: bool = True):

@@ -294,8 +294,9 @@ def q15(s, t):
 
 
 def q16(s, t):
-    """Parts/supplier relationship (Q16Like); count(distinct) rendered as
-    distinct + count."""
+    """Parts/supplier relationship (Q16Like), count(distinct ps_suppkey) as
+    the specification writes it; NOT IN over the supplier's key (no null)
+    is the anti join."""
     bad_supp = t["supplier"].filter(
         F.col("s_comment").like("%Customer%Complaints%"))
     part = t["part"].filter(
@@ -306,10 +307,8 @@ def q16(s, t):
             .join(bad_supp, left_on=["ps_suppkey"], right_on=["s_suppkey"],
                   how="leftanti")
             .join(part, left_on=["ps_partkey"], right_on=["p_partkey"])
-            .select("p_brand", "p_type", "p_size", "ps_suppkey")
-            .distinct()
             .group_by("p_brand", "p_type", "p_size")
-            .agg(F.count("*").alias("supplier_cnt"))
+            .agg(F.count_distinct("ps_suppkey").alias("supplier_cnt"))
             .order_by(F.col("supplier_cnt").desc(), "p_brand", "p_type",
                       "p_size"))
 

@@ -1041,8 +1041,96 @@ def _rowspace_reduce(batch: DeviceBatch, key_idx: List[int],
     return DeviceBatch(out_schema, out_cols, num_groups)
 
 
+def _equality_image(col: DeviceColumn):
+    """(fields, decode) of one key column for the fused count-distinct.
+
+    ``fields``: [(uint64 image, bits)] whose joint value is equal exactly
+    where two VALID values of the column are: a dictionary code takes the
+    bits its cardinality needs, an integer of at most 32 bits its width,
+    so several keys share one sort operand (_pack_fields). ``decode``
+    rebuilds the column from those fields' values, or is None where the
+    image is not invertible (floats; plain strings, whose image is
+    prefix8 + length + two polynomial hashes, the grouping contract):
+    such a key is gathered from a row of its group instead."""
+    from spark_rapids_tpu.ops import hashing
+    from spark_rapids_tpu.ops.sortops import string_prefix8, u64_key_image
+    dt = col.dtype
+    if dt.is_string and col.dict_values is not None:
+        card = len(col.dict_values)
+
+        def decode_codes(vals, validity):
+            codes = jnp.where(validity, vals[0].astype(jnp.int32), card)
+            return DeviceColumn(dt, None, validity, dict_codes=codes,
+                                dict_values=col.dict_values)
+        return ([(col.dict_codes.astype(jnp.uint64),
+                  max(1, (card - 1).bit_length()))], decode_codes)
+    if dt.is_string:
+        h1, h2 = hashing.string_poly_hashes_col(col)
+        return ([(string_prefix8(col), 64),
+                 (col.lens_().astype(jnp.uint64), 32), (h1, 64), (h2, 64)],
+                None)
+    d = col.data
+    if d.dtype == jnp.bool_:
+        return ([(d.astype(jnp.uint64), 1)],
+                lambda vals, validity: DeviceColumn(dt, vals[0] != 0,
+                                                    validity))
+    if jnp.issubdtype(d.dtype, jnp.floating):
+        return [(im, 64) for im in u64_key_image(col)], None
+    bits = 8 * d.dtype.itemsize
+    img = d.astype(jnp.int64).view(jnp.uint64)
+    if bits < 64:
+        img = img & jnp.uint64((1 << bits) - 1)
+
+    def decode_int(vals, validity):
+        v = vals[0].view(jnp.int64)
+        if bits < 64 and jnp.issubdtype(d.dtype, jnp.signedinteger):
+            sign = jnp.int64(1 << (bits - 1))
+            v = (v ^ sign) - sign
+        return DeviceColumn(dt, v.astype(d.dtype), validity)
+    return [(img, bits)], decode_int
+
+
+def _pack_fields(fields):
+    """Pack [(uint64 image, bits)] into as few words of at most 64 bits
+    as hold them, in order, the first field the most significant of its
+    word; a word is the narrowest unsigned type its fields fill (a sort
+    compiles and runs by the bits it carries). Returns (words, places):
+    places[i] = (word, shift, bits) of field i."""
+    groups: List[List[int]] = []
+    used = 0
+    for i, (_img, bits) in enumerate(fields):
+        if groups and used + bits <= 64:
+            groups[-1].append(i)
+            used += bits
+        else:
+            groups.append([i])
+            used = bits
+    words, places = [], [None] * len(fields)
+    for w, group in enumerate(groups):
+        shift = sum(fields[i][1] for i in group)
+        word = None
+        for i in group:
+            img, bits = fields[i]
+            shift -= bits
+            part = img << jnp.uint64(shift) if shift else img
+            word = part if word is None else word | part
+            places[i] = (w, shift, bits)
+        used = sum(fields[i][1] for i in group)
+        words.append(word.astype(next(
+            t for t in (jnp.uint8, jnp.uint16, jnp.uint32, jnp.uint64)
+            if used <= 8 * jnp.dtype(t).itemsize)))
+    return words, places
+
+
+def _field(words, place) -> jnp.ndarray:
+    w, shift, bits = place
+    v = words[w].astype(jnp.uint64)
+    v = v >> jnp.uint64(shift) if shift else v
+    return v & jnp.uint64((1 << bits) - 1) if bits < 64 else v
+
+
 def count_distinct_reduce(batch: DeviceBatch, g2_idx: List[int],
-                          rest_idx: List[int], live=None):
+                          rest_idx: List[int], skip_null: bool = False):
     """count(distinct <rest keys>) grouped by <g2 keys> in ONE sorted
     pass over the combined G1 = g2+rest tuple — the fused form of the
     distinct -> regroup -> count chain Spark (and this planner) expands
@@ -1051,76 +1139,118 @@ def count_distinct_reduce(batch: DeviceBatch, g2_idx: List[int],
     aggregation pass costs a hash sort + segment sweep, so fusing the
     two levels halves the dominant cost — q16's shape).
 
-    Sorted by (g2 images, rest images): a G1-distinct tuple starts where
-    ANY image differs from the previous row; a G2 group starts where a
-    G2 image differs. Exactness matches the grouping paths: fixed-width
-    keys compare by value images, strings by dict code (exact) or
-    prefix8+length+dual-poly-hash (collision ~2^-128, the documented
-    grouping contract). Null keys group together via per-key validity
-    signatures, like _sorted_payload_reduce.
+    Two carrying sorts and elementwise passes, no gather or scatter where
+    every g2 key's image is invertible (sorts are cheap on this chip,
+    gathers dear, PERF.md). Each key is a validity bit and an equality
+    image (_equality_image) packed with its neighbours into uint64 words
+    (q16: brand and type codes, the int32 size, their validity and the
+    dead flag are one word; the int64 supplier key a second; its validity
+    a third). Neither sort need be stable (equal words are one tuple,
+    group starts have distinct positions), which spares each an operand.
+    Sorted by (dead, g2 words, rest words): a G1-distinct tuple
+    starts where ANY word differs from the previous row; a G2 group
+    starts where a g2 word differs. A second sort, keyed by a group
+    start's own position, brings the group starts to the front in order
+    with their g2 words and the running count of tuples before them, so a
+    group's count is the next group's running count less its own and its
+    keys are decoded from the words it carries. Exactness matches the
+    grouping paths: fixed-width keys and dictionary codes are exact,
+    plain strings prefix8+length+dual-poly-hash (collision ~2^-128, the
+    documented grouping contract). Null keys group together: the image
+    of a NULL is 0 under a validity bit of 0.
 
-    Returns (rep_rows, counts, num_groups): rep_rows[g] = a source row
-    of group g (prefix-compact), counts[g] = distinct live G1 tuples.
+    ``skip_null`` (static) is SQL's count(DISTINCT k): a tuple with a
+    NULL among its rest keys is not counted, and a group whose every
+    tuple is such reads 0 and stays. Off, it is count(*) over the
+    distinct tuples, where NULL is a value like another.
+
+    Returns (keys, counts, num_groups): keys = {g2 column index: that key
+    column of every group}, counts = distinct live G1 tuples a group, both
+    prefix-compact at the batch's capacity.
     """
-    from spark_rapids_tpu.ops import hashing
-    from spark_rapids_tpu.ops.rowops import packed_gather_vectors
-    from spark_rapids_tpu.ops.sortops import (
-        lexsort_permutation, string_prefix8, u64_key_image,
+    from spark_rapids_tpu.ops.rowops import (
+        gather_columns, packed_gather_vectors,
     )
-    from spark_rapids_tpu.ops.tablekernels import compact_permutation
+    from spark_rapids_tpu.ops.sortops import (
+        MAX_DIRECT_SORT_OPERANDS, lexsort_permutation,
+    )
     capacity = batch.capacity
-    if live is None:
-        live = batch.row_mask()
+    live = batch.row_mask()
+    pos = jnp.arange(capacity, dtype=jnp.int32)
 
-    def key_ops(idx_list):
-        imgs: List[jnp.ndarray] = []
-        nullsig = jnp.zeros((capacity,), jnp.uint32)
-        for j, ki in enumerate(idx_list):
+    def key_fields(idx_list, lead):
+        """[validity bit, image fields] a key; (fields, what each key's
+        decode needs: (validity field, its image fields, decode))."""
+        fields, keys = list(lead), []
+        for ki in idx_list:
             col = batch.columns[ki]
-            if col.dtype.is_string and col.dict_values is not None:
-                per = [col.dict_codes.astype(jnp.uint64)]
-            elif col.dtype.is_string:
-                lens = col.lens_()
-                h1, h2 = hashing.string_poly_hashes_col(col)
-                per = [string_prefix8(col), lens.astype(jnp.uint64), h1, h2]
-            else:
-                per = u64_key_image(col)
-            imgs.extend(jnp.where(col.validity, im, jnp.uint64(0))
-                        for im in per)
-            nullsig = nullsig | (col.validity.astype(jnp.uint32)
-                                 << jnp.uint32(j))
-        return imgs, nullsig
+            imgs, decode = _equality_image(col)
+            at = len(fields)
+            fields.append((col.validity.astype(jnp.uint64), 1))
+            fields.extend((jnp.where(col.validity, im, jnp.uint64(0)), b)
+                          for im, b in imgs)
+            keys.append((at, range(at + 1, len(fields)), decode))
+        return fields, keys
 
-    g2_imgs, g2_null = key_ops(g2_idx)
-    r_imgs, r_null = key_ops(rest_idx)
-    dead = (~live).astype(jnp.uint8)
-    ops = [dead] + g2_imgs + [g2_null] + r_imgs + [r_null]
-    perm = lexsort_permutation(ops)
-    s = packed_gather_vectors(ops, perm)
-    dead_s = s[0] != 0
-    n2 = len(g2_imgs) + 1
-    g2_s, rest_s = s[1:1 + n2], s[1 + n2:]
-    first = jnp.zeros((capacity,), jnp.bool_).at[0].set(True)
+    # dead rows last: the flag is the first word's most significant field
+    g2_fields, g2_keys = key_fields(
+        g2_idx, [((~live).astype(jnp.uint64), 1)])
+    r_fields, r_keys = key_fields(rest_idx, [])
+    g2_words, g2_places = _pack_fields(g2_fields)
+    r_words, r_places = _pack_fields(r_fields)
+    n2 = len(g2_words)
+    words = g2_words + r_words
+    # a key that cannot be decoded is read from a row of its group
+    carried = [ki for ki, (_v, _f, decode) in zip(g2_idx, g2_keys)
+               if decode is None]
+    if len(words) + bool(carried) <= MAX_DIRECT_SORT_OPERANDS:
+        s = jax.lax.sort(tuple(words) + ((pos,) if carried else ()),
+                         num_keys=len(words), is_stable=False)
+        words_s, perm = list(s[:len(words)]), s[-1]
+    else:
+        perm = lexsort_permutation(words)
+        words_s = packed_gather_vectors(words, perm)
 
-    def diff_any(vecs, acc):
+    def differs(vecs, acc):
         for v in vecs:
             acc = acc | jnp.concatenate(
                 [jnp.zeros((1,), jnp.bool_), v[1:] != v[:-1]])
         return acc
 
-    d_g2 = diff_any(g2_s, first)
-    d_any = diff_any(rest_s, d_g2)
-    live_s = ~dead_s
-    g2_b = d_g2 & live_s
-    g1_b = d_any & live_s
-    gid = jnp.clip(jnp.cumsum(g2_b.astype(jnp.int32)) - 1, 0, capacity - 1)
-    counts = jax.ops.segment_sum(
-        jnp.where(g1_b, 1, 0).astype(jnp.int32),
-        jnp.where(live_s, gid, capacity),
-        num_segments=capacity + 1)[:capacity]
-    cperm, n_groups = compact_permutation(g2_b)
-    rep_rows = perm[cperm]
-    return rep_rows, counts.astype(jnp.int64), n_groups
+    # dead rows sorted last and live rows were a prefix: ``live`` is the
+    # sorted rows' mask too
+    d_g2 = differs(words_s[:n2], pos == 0)
+    g2_b = d_g2 & live
+    g1_b = differs(words_s[n2:], d_g2) & live
+    if skip_null:
+        for at, _f, _d in r_keys:
+            g1_b = g1_b & (_field(words_s[n2:], r_places[at]) != 0)
+    g1 = g1_b.astype(jnp.int32)
+    before = jnp.cumsum(g1) - g1          # tuples counted before this row
+    total = jnp.sum(g1)
+    n_groups = jnp.sum(g2_b.astype(jnp.int32))
+    # the group starts to the front, in order, with what they carry
+    c = jax.lax.sort(
+        (jnp.where(g2_b, pos, capacity),) + tuple(words_s[:n2]) + (before,)
+        + ((perm,) if carried else ()), num_keys=1, is_stable=False)
+    heads, before_c = list(c[1:1 + n2]), c[1 + n2]
+    group_live = pos < n_groups
+    after = jnp.where(pos == n_groups - 1, total,
+                      jnp.concatenate([before_c[1:], before_c[:1]]))
+    counts = jnp.where(group_live, after - before_c, 0).astype(jnp.int64)
+    keys = {}
+    for ki, (at, img_fields, decode) in zip(g2_idx, g2_keys):
+        if decode is not None:
+            validity = (_field(heads, g2_places[at]) != 0) & group_live
+            keys[ki] = decode([_field(heads, g2_places[f])
+                               for f in img_fields], validity)
+    if carried:
+        rep_rows = jnp.where(group_live, c[-1], 0)
+        for ki, col in zip(carried, gather_columns(
+                [batch.columns[ki] for ki in carried], rep_rows,
+                group_live)):
+            keys[ki] = col
+    return keys, counts, n_groups
 
 
 def dense_composite(batch: DeviceBatch, key_idx: List[int],

@@ -326,19 +326,29 @@ def expand_totals(build: DeviceBatch, stream: DeviceBatch,
     [total_rows, chars per stream string col..., chars per build string
     col...]. String char totals are exact (each emitted pair copies the
     source strings once); build-side totals ride a prefix sum over the
-    sorted build rows."""
+    sorted build rows. A string column the expand moves without its chars
+    (gather_columns: a dictionary column by its codes, a slab column by
+    its slab) sizes no char buffer and reads 0: its total would cost two
+    gathers over the build and four over the stream for nothing (q16 at
+    SF100: 11.1 s of a 36.8 s query for p_brand and p_type, PERF.md)."""
     def str_lens(c):
-        """Per-row byte lengths WITHOUT materializing lazy (codes-only or
-        slab) columns (DeviceColumn.lens_)."""
         return c.lens_().astype(jnp.int64)
 
+    def copies_chars(c):
+        return c.dict_values is None and not c.has_slab
+
+    zero = jnp.zeros((), jnp.int64)
     parts = [counts_adj.sum().astype(jnp.int64)]
     for c in stream.columns:
         if c.dtype.is_string:
-            parts.append((counts_adj.astype(jnp.int64) * str_lens(c)).sum())
+            parts.append(
+                (counts_adj.astype(jnp.int64) * str_lens(c)).sum()
+                if copies_chars(c) else zero)
     nb = build.capacity
     for c in build.columns:
-        if c.dtype.is_string:
+        if c.dtype.is_string and not copies_chars(c):
+            parts.append(zero)
+        elif c.dtype.is_string:
             lens_sorted = str_lens(c)[bperm]
             cl = jnp.concatenate([jnp.zeros((1,), jnp.int64),
                                   jnp.cumsum(lens_sorted)])

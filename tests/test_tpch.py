@@ -84,6 +84,40 @@ def test_q18_on_orders_that_pass_the_having(session, tpch_all_pandas):
     assert set(out["o_orderkey"]) <= set(qty[qty > 300].index)
 
 
+def test_q16_counts_distinct_suppliers_in_one_pass(session, tpch_all_pandas):
+    """q16 is written with ``F.count_distinct`` as the specification has
+    it, plans as the one-pass operator, and equals pandas' ``nunique``."""
+    import inspect
+    from spark_rapids_tpu.models import tpch
+    src = inspect.getsource(tpch.q16)
+    assert "count_distinct(\"ps_suppkey\")" in src and ".distinct()" not in src
+    session.set_conf("spark.rapids.sql.test.enabled", True)
+    t = {name: session.create_dataframe(tpch_all_pandas[name], 3)
+         for name in ("partsupp", "part", "supplier")}
+    got = QUERIES["q16"](session, t).collect()
+    fused = [n for n in session.last_plan.walk()
+             if type(n).__name__ == "TpuCountDistinctExec"]
+    assert len(fused) == 1 and fused[0].skip_null
+    assert not any(type(n).__name__ == "TpuHashAggregateExec"
+                   for n in session.last_plan.walk())
+    supplier, part = tpch_all_pandas["supplier"], tpch_all_pandas["part"]
+    bad = supplier.s_suppkey[supplier.s_comment.str.contains(
+        "Customer.*Complaints")]
+    part = part[(part.p_brand != "Brand#45")
+                & ~part.p_type.str.startswith("MEDIUM POLISHED")
+                & part.p_size.isin([49, 14, 23, 45, 19, 3, 36, 9])]
+    ps = tpch_all_pandas["partsupp"]
+    j = ps[~ps.ps_suppkey.isin(bad)].merge(
+        part, left_on="ps_partkey", right_on="p_partkey")
+    want = (j.groupby(["p_brand", "p_type", "p_size"]).ps_suppkey.nunique()
+            .rename("supplier_cnt").reset_index()
+            .sort_values(["supplier_cnt", "p_brand", "p_type", "p_size"],
+                         ascending=[False, True, True, True])
+            .reset_index(drop=True))
+    assert len(want) > 0
+    assert got.values.tolist() == want.values.tolist()
+
+
 def test_q1(session, tpch_pandas):
     out = assert_tpu_and_cpu_equal(
         lambda s: QUERIES["q1"](s, {
