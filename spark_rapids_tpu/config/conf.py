@@ -107,10 +107,15 @@ AGG_FUSE_FILTER = register(
 
 EXCHANGE_FUSE_FILTER = register(
     "spark.rapids.sql.exchange.fuseFilter", _to_bool, True,
-    "Fuse a deterministic Filter directly below a collapsed exchange (or "
-    "a broadcast materialization) into the concat's single compaction "
-    "gather, eliminating the standalone filter's per-batch per-column "
-    "gathers (~5M rows/s on TPU).")
+    "Let a collapsed exchange (or a broadcast materialization) claim a "
+    "deterministic Filter directly below it and run it a batch at a time "
+    "as the child's batches arrive. A batch of at most four columns, each "
+    "fixed-width or a dictionary string, is compacted by the filter's own "
+    "sorting kernel and the collapse concatenates by block copies; any "
+    "other batch (strings with chars or a slab, five columns or more) "
+    "hands the concat a keep mask, and the concat compacts every part in "
+    "one gather. Off: nothing is claimed and the filter runs as the "
+    "operator it is.")
 
 ADAPTIVE_CAPACITY = register(
     "spark.rapids.sql.adaptiveCapacity.enabled", _to_bool, True,

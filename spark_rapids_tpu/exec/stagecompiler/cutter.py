@@ -14,9 +14,11 @@ fusions:
 
   * a (Coalesce +) Filter directly below a shuffle/broadcast exchange is
     left out of the chain whenever ``spark.rapids.sql.exchange
-    .fuseFilter`` is on — the exchange's collapse concat claims exactly
-    that filter as a single-gather mask (exec/tpu._fused_filter_source),
-    which beats running the compaction inside a fused program;
+    .fuseFilter`` is on — the exchange's collapse claims exactly that
+    filter and runs it a batch at a time under its drain, by the
+    filter's own sorting kernel or as a keep mask for the concat
+    (exec/tpu._fused_filter_source), which beats running the compaction
+    inside a fused program;
   * chains with fewer than ``spark.rapids.sql.fusion.minOperators``
     compute members do not fuse (fusing one operator only renames its
     dispatch).
@@ -68,7 +70,7 @@ def _is_compute(node: PhysicalPlan) -> bool:
 
 def _parent_claims_filter(parent: Optional[PhysicalPlan],
                           top: PhysicalPlan, conf) -> bool:
-    """Does the consumer fold a directly-below Filter into its own concat
+    """Does the consumer claim a directly-below Filter for its own collapse
     (exec/tpu._fused_filter_source)? Broadcast materializations always
     do; shuffle exchanges only on the single/collapse path — hash/range
     kinds with local collapse on, no accelerated shuffle manager, and no

@@ -48,7 +48,8 @@ def _concat_device(batches: List[DeviceBatch], schema: Schema,
                    coarse: bool = False) -> DeviceBatch:
     """Concatenate device batches (GpuCoalesceBatches / ConcatAndConsumeAll,
     GpuCoalesceBatches.scala:38-165). ``keep_masks``: per-batch keep
-    vectors of a fused Filter (see _fused_filter_source). ``coarse``:
+    vectors of a claimed Filter's mask form, ``None`` for a batch the
+    filter already compacted (see _fused_filter_source). ``coarse``:
     pad the output capacity up the shape-bucket ladder
     (utils/kernelcache.bucket_dim) — used for SECONDARY-dimension
     materializations (join build tables, broadcast tables, fused
@@ -115,6 +116,7 @@ def _concat_device(batches: List[DeviceBatch], schema: Schema,
 
 _COLLAPSE_BYTES = REGISTRY.counter("exchange.collapse.bytes")
 _COLLAPSE_BATCHES = REGISTRY.counter("exchange.collapse.batches")
+_COLLAPSE_COMPACTED = REGISTRY.counter("exchange.collapse.compactedBatches")
 _MERGE_ROWS = REGISTRY.counter("agg.merge.inputRows")
 _MERGE_BYTES = REGISTRY.counter("agg.merge.inputBytes")
 _PASSTHROUGH_ROWS = REGISTRY.counter("agg.partial.passthroughRows")
@@ -143,47 +145,61 @@ def _row_bytes(schema: Schema) -> int:
 
 
 def _collapse_concat(batches: List[DeviceBatch], schema: Schema,
-                     growth: float, keep_masks=None) -> DeviceBatch:
+                     growth: float, keep_masks=None,
+                     compacted: int = 0) -> DeviceBatch:
     """The one concat of a local exchange collapse, counted by what the host
     knows without a sync: ``exchange.collapse.batches`` input batches and
     ``exchange.collapse.bytes`` of device storage at their capacity
-    (padding included, rows a fused filter will drop included). The span
-    ``exchange.collapse`` covers the concat's dispatch alone; the drain of
-    the children above it belongs to the operator spans."""
+    (padding included, rows a mask will drop included). The span
+    ``exchange.collapse`` covers the concat's dispatch alone (``compacted``:
+    how many of its batches a claimed filter had already compacted,
+    _drain_claimed); the drain of the children above it belongs to the
+    operator spans."""
     nbytes = sum(b.device_memory_size() for b in batches)
     _COLLAPSE_BATCHES.add(len(batches))
     _COLLAPSE_BYTES.add(nbytes)
     with TRACER.span("exchange.collapse", batches=len(batches),
-                     bytes=nbytes):
+                     bytes=nbytes, compacted=compacted):
         return _concat_device(batches, schema, growth, keep_masks)
 
 
 def _fused_filter_source(node: PhysicalPlan, ctx: ExecContext):
-    """(source node, mask kernel, out_sel) for the exchange/broadcast
-    collapse concat: a deterministic TpuFilterExec directly below folds
-    its predicate into the concat's single compaction gather instead of
-    paying per-batch per-column compaction gathers (~5M rows/s on TPU) —
-    the exchange-side sibling of fuse_filter_into_aggregate
-    (exec/fusion.py). ``out_sel`` is the filter's fused output selection
-    (fuse_selection_into_filter); the caller applies it as a zero-copy
-    column view before the concat. Returns (node, None, None) when
-    nothing fuses. NB the whole-stage cutter mirrors this claim
-    (exec/stagecompiler/cutter._parent_claims_filter) and leaves the
-    claimed filter out of fused pipelines — changes to the conditions
-    here must be reflected there."""
+    """(source node, claimed filter) for the exchange/broadcast collapse: a
+    deterministic TpuFilterExec directly below is claimed by the collapse
+    and run a batch at a time as the child's batches arrive
+    (_drain_claimed) — the exchange-side sibling of
+    fuse_filter_into_aggregate (exec/fusion.py). ``claimed(batch)`` returns
+    ``(batch, mask)`` in the filter's output schema:
+
+      * where the selected columns are ``rowops.sort_compactable`` (at most
+        four, fixed-width or dictionary strings — every filtered collapse
+        of the benchmark), the filter's own ``filter|...`` kernel: the
+        batch comes back prefix-compact with a device-side row count, no
+        sync, and ``mask`` is None, so a collapse of such batches is the
+        unmasked concat's block copies;
+      * else the ``filtermask|...`` kernel's keep vector beside the
+        zero-copy selection, and the concat compacts every part with one
+        permutation and one gather a dtype group (``concatmask``) instead
+        of per-batch per-column compaction gathers.
+
+    The form is chosen from the batch's columns alone, a batch at a time.
+    Returns (node, None) when nothing is claimed. NB the whole-stage cutter
+    mirrors this claim (exec/stagecompiler/cutter._parent_claims_filter)
+    and leaves the claimed filter out of fused pipelines — changes to the
+    conditions here must be reflected there."""
     from spark_rapids_tpu.exec.coalesce import TpuCoalesceBatchesExec
     if isinstance(node, TpuCoalesceBatchesExec):
         # the collapse concat coalesces everything anyway — a TargetSize
         # re-batching between the filter and the exchange is a no-op on
-        # this path, and looking through it is what lets the filter fuse
-        # (the planner inserts Coalesce above every filter; without this
-        # q12's 3M-row filter pays its own per-column compaction gather,
-        # measured 1.16s exclusive vs the fused concat's single gather)
+        # this path, and looking through it is what lets the filter be
+        # claimed (the planner inserts Coalesce above every filter)
         node = node.children[0]
     if (isinstance(node, TpuFilterExec) and not node._impure
             and ctx.conf.get_bool(
                 "spark.rapids.sql.exchange.fuseFilter", True)):
         cond = node.condition
+        out_sel = node.out_sel
+        compact_kernel = node._kernel
         sig = "filtermask|" + expr_signature(cond)
 
         def build():
@@ -192,10 +208,37 @@ def _fused_filter_source(node: PhysicalPlan, ctx: ExecContext):
                 pred = to_device_column(ectx, cond.eval_device(ectx))
                 return pred.data & pred.validity & batch.row_mask()
             return jax.jit(mask)
-        return (node.children[0],
-                counting_pattern_predicates([cond])(cached_jit(sig, build)),
-                node.out_sel)
-    return node, None, None
+        mask_kernel = counting_pattern_predicates([cond])(
+            cached_jit(sig, build))
+
+        def claimed(batch: DeviceBatch):
+            view = _select_view(batch, out_sel)
+            if rowops.sort_compactable(view.columns):
+                return compact_kernel(batch), None
+            return view, mask_kernel(batch)
+        return node.children[0], claimed
+    return node, None
+
+
+def _drain_claimed(parts: Sequence[Partition], claimed):
+    """(batches, keep masks, compacted) of a collapse's child partitions,
+    in order. A claimed filter (_fused_filter_source) runs on every batch
+    as it arrives, so its program is queued on the device while the host
+    decodes the next split. ``compacted`` counts the batches that came back
+    compacted (``exchange.collapse.compactedBatches``); where that is all
+    of them, or nothing is claimed, the masks are None and the concat is
+    the unmasked one."""
+    batches = []
+    masks = []
+    for p in parts:
+        for b in p():
+            if claimed is not None:
+                b, mask = claimed(b)
+                masks.append(mask)
+            batches.append(b)
+    compacted = sum(m is None for m in masks)
+    _COLLAPSE_COMPACTED.add(compacted)
+    return batches, (masks if compacted < len(masks) else None), compacted
 
 
 def _select_view(batch: DeviceBatch, out_sel) -> DeviceBatch:
@@ -1462,24 +1505,21 @@ class TpuShuffleExchangeExec(TpuExec):
             # outputs carry pre-agg padding worth removing before the
             # merge/sort).
             if not self._padded_producer(self.children[0]):
-                # a deterministic Filter directly below folds into the
-                # concat's compaction gather (_fused_filter_source)
-                src_node, mask_kernel, out_sel = _fused_filter_source(
+                # a deterministic Filter directly below is claimed and run
+                # a batch at a time under the drain (_fused_filter_source)
+                src_node, claimed = _fused_filter_source(
                     self.children[0], ctx)
                 fused_parts = (src_node.executed_partitions(ctx)
-                               if mask_kernel is not None else child_parts)
+                               if claimed is not None else child_parts)
 
                 def nosync_concat() -> Iterator[DeviceBatch]:
-                    batches = [b for p in fused_parts for b in p()]
+                    batches, masks, compacted = _drain_claimed(
+                        fused_parts, claimed)
                     if not batches:
                         yield DeviceBatch.empty(schema)
                         return
-                    masks = ([mask_kernel(b) for b in batches]
-                             if mask_kernel is not None else None)
-                    if masks is not None and out_sel is not None:
-                        batches = [_select_view(b, out_sel)
-                                   for b in batches]
-                    yield _collapse_concat(batches, schema, growth, masks)
+                    yield _collapse_concat(batches, schema, growth, masks,
+                                           compacted)
                 return [nosync_concat]
 
             def single() -> Iterator[DeviceBatch]:

@@ -58,19 +58,16 @@ class TpuBroadcastExchangeExec(PhysicalPlan):
 
         def materialize():
             from spark_rapids_tpu.exec.tpu import (
-                _concat_device, _fused_filter_source, _select_view,
+                _concat_device, _drain_claimed, _fused_filter_source,
             )
-            src_node, mask_kernel, out_sel = _fused_filter_source(child, ctx)
+            # a Filter directly below is claimed and run a batch at a time
+            # under the drain, as the exchange's collapse does
+            src_node, claimed = _fused_filter_source(child, ctx)
             parts = src_node.executed_partitions(ctx)
-            batches = [b for p in parts for b in p()]
+            batches, masks, _ = _drain_claimed(parts, claimed)
             if not batches:
                 return _concat_device(batches, child.output_schema(),
                                       growth, coarse=True)
-            masks = None
-            if mask_kernel is not None:
-                masks = [mask_kernel(b) for b in batches]
-                if out_sel is not None:
-                    batches = [_select_view(b, out_sel) for b in batches]
             out = _concat_device(batches, child.output_schema(), growth,
                                  masks, coarse=True)
             if ctx.metrics_enabled:

@@ -275,23 +275,49 @@ def gather_batch(batch: DeviceBatch, perm: jnp.ndarray,
     return DeviceBatch(batch.schema, cols, num_rows.astype(jnp.int32))
 
 
+def sort_compactable(columns: Sequence[DeviceColumn]) -> bool:
+    """Can ``filter_batch`` compact these columns by one carrying sort? At
+    most four columns (a wider batch would compile for minutes:
+    sort_carrying), each fixed-width or a dictionary string, which moves as
+    ``(validity, dict_codes)`` and comes out codes-only, as a gather leaves
+    it. Strings with chars or a slab take the gather. Read from the batch
+    alone: ``filter_batch`` and a collapse that has claimed a filter
+    (exec/tpu._fused_filter_source) both ask it."""
+    return len(columns) <= 4 and all(
+        c.dict_values is not None and c.dict_codes is not None
+        if c.dtype.is_string else c.dict_values is None
+        for c in columns)
+
+
 def filter_batch(batch: DeviceBatch, keep: jnp.ndarray) -> DeviceBatch:
     """Compact rows where ``keep`` (bool capacity-vector) is True to the
-    front. keep is pre-masked to live rows by the caller or here."""
+    front, in their order; the output is prefix-compact with a device-side
+    ``num_rows``. keep is pre-masked to live rows by the caller or here.
+    A ``sort_compactable`` batch rides one stable sort by "dropped" (5 ns a
+    slot at 2^26 slots on a v5e, PERF.md PR 28); any other pays
+    ``compact_permutation`` and a packed gather a dtype group."""
     keep = keep & batch.row_mask()
-    plain = all(not c.dtype.is_string and c.dict_values is None
-                for c in batch.columns)
-    if plain and len(batch.columns) <= 4:
-        # a few fixed-width columns: one stable sort by "dropped" carries
-        # data and validity to the front (a wider batch would compile for
-        # minutes: sort_carrying)
+    if sort_compactable(batch.columns):
         _, moved = sort_carrying(
             (~keep).astype(jnp.uint8),
-            [v for c in batch.columns for v in (c.data, c.validity)])
+            [v for c in batch.columns for v in (
+                (c.validity, c.dict_codes) if c.dtype.is_string
+                else (c.data, c.validity))])
         new_rows = keep.sum().astype(jnp.int32)
         live = jnp.arange(batch.capacity, dtype=jnp.int32) < new_rows
-        cols = [DeviceColumn(c.dtype, moved[2 * i], moved[2 * i + 1] & live)
-                for i, c in enumerate(batch.columns)]
+        cols = []
+        for i, c in enumerate(batch.columns):
+            a, b = moved[2 * i], moved[2 * i + 1]
+            if c.dtype.is_string:
+                # codes-only against the same dictionary; dead slots read
+                # the NULL code, as gather_columns leaves them
+                cols.append(DeviceColumn(
+                    c.dtype, None, a & live,
+                    dict_codes=jnp.where(
+                        live, b, jnp.asarray(c.dict_card, jnp.int32)),
+                    dict_values=c.dict_values))
+            else:
+                cols.append(DeviceColumn(c.dtype, a, b & live))
         return DeviceBatch(batch.schema, cols, new_rows)
     # stable partition via the O(n) prefix-count kernel
     from spark_rapids_tpu.ops.tablekernels import compact_permutation
@@ -317,17 +343,22 @@ def concat_batches(batches: Sequence[DeviceBatch],
     measured ~770ms for a 4-part 5-column concat at 4M rows; this one
     runs the same shape in ~1/3 of that.
 
-    ``keep_masks``: optional per-part bool keep vectors (a fused Filter
-    below the exchange collapse): kept rows compact to the front in part
-    order via ONE O(n) compact_permutation — the standalone filter's
-    per-batch compaction gathers disappear into the concat's single
-    gather."""
+    ``keep_masks``: optional per-part bool keep vectors, the mask form of
+    a Filter a collapse has claimed (exec/tpu._fused_filter_source), for
+    parts no carrying sort can compact (strings with chars or a slab, five
+    columns or more): kept rows compact to the front in part order via ONE
+    O(n) compact_permutation and one gather a dtype group over the whole
+    output capacity, about 48 ns a slot on a v5e (PERF.md, PR 36). A part
+    the claimed filter already compacted has ``None`` for a mask and keeps
+    its live rows. With no masks at all, the form every sort-compactable
+    collapse takes, the parts move by block copies (below)."""
     schema = batches[0].schema
     idx = jnp.arange(out_capacity, dtype=jnp.int32)
     if keep_masks is not None:
         from spark_rapids_tpu.ops.tablekernels import compact_permutation
         flat_keep = jnp.concatenate(
-            [k & b.row_mask() for k, b in zip(keep_masks, batches)])
+            [b.row_mask() if k is None else k & b.row_mask()
+             for k, b in zip(keep_masks, batches)])
         perm, total = compact_permutation(flat_keep)
         total = total.astype(jnp.int32)
         flat_n = perm.shape[0]

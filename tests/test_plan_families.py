@@ -21,15 +21,24 @@ QUERY_DIR = os.path.join(os.path.dirname(os.path.dirname(
 SF = 0.002
 
 AGG = {"aggupd", "aggmrg", "aggfin"}
+# A filter a collapse has claimed launches ``filter`` where its batch is
+# sort-compactable (exec/tpu._fused_filter_source: every such collapse of
+# q3, q5, q13 and q18 here), and the collapse is then the unmasked
+# ``concat``; it launches ``filtermask`` + ``concatmask`` where the batch
+# is not: q16's ``part`` at this size, whose ``p_type`` has too many values
+# a row for a dictionary and uploads as a slab (on the chip it is a
+# dictionary column and compacts too).
 FAMILIES = {
-    "q1": AGG | {"sort", "shrink", "packfetch"},
-    "q3": AGG | {"filter", "filtermask", "join", "concatmask", "shrink",
-                 "sort", "limitstep", "packfetch"},
-    "q5": AGG | {"filter", "filtermask", "join", "concatmask", "shrink",
-                 "sort", "packfetch"},
-    "q6": AGG | {"packfetch"},
-    "q18": AGG | {"filtermask", "join", "concatmask", "shrink", "sort",
-                  "limitstep", "packfetch"},
+    "q1": AGG | {"concat", "sort", "shrink", "packfetch"},
+    "q3": AGG | {"filter", "join", "concat", "shrink", "sort", "limitstep",
+                 "packfetch"},
+    "q5": AGG | {"filter", "join", "concat", "sort", "packfetch"},
+    "q6": AGG | {"concat", "packfetch"},
+    "q13": AGG | {"filter", "join", "concat", "shrink", "sort", "packfetch"},
+    "q16": {"filter", "filtermask", "concat", "concatmask", "join", "cdist",
+            "shrink", "sort", "packfetch"},
+    "q18": AGG | {"filter", "join", "concat", "shrink", "sort", "limitstep",
+                  "packfetch"},
 }
 
 
@@ -42,7 +51,9 @@ def _benchmark_query(name):
 
 
 def _tables(session, tmp_path, reads):
-    """The tables a query reads, as Parquet scans. ``tpch_data`` draws
+    """The tables a query reads, as Parquet scans of three row groups
+    each, so a table is several batches as it is on the chip and a
+    collapse has something to concatenate. ``tpch_data`` draws
     ``l_orderkey`` over four times as many keys as there are lines, so the
     lines are folded onto 400 of the orders (about 30 lines an order):
     Q18's ``sum(l_quantity) > 300`` then keeps rows and every operator
@@ -56,7 +67,8 @@ def _tables(session, tmp_path, reads):
     tables = {}
     for t, df in frames.items():
         path = str(tmp_path / f"{t}.parquet")
-        df.to_parquet(path, index=False)
+        df.to_parquet(path, index=False,
+                      row_group_size=max(len(df) // 3, 1))
         tables[t] = session.read.parquet(path)
     return tables
 
