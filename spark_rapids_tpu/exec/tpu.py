@@ -230,13 +230,19 @@ def _drain_claimed(parts: Sequence[Partition], claimed):
     the unmasked one."""
     batches = []
     masks = []
-    for p in parts:
-        for b in p():
-            if claimed is not None:
-                b, mask = claimed(b)
-                masks.append(mask)
-            batches.append(b)
-    compacted = sum(m is None for m in masks)
+    # the serial section before the collapse's consumer can start: it
+    # holds the children's pulls, as the operator span does
+    with TRACER.span("exchange.drain",
+                     claimed=claimed is not None) as sp:
+        for p in parts:
+            for b in p():
+                if claimed is not None:
+                    b, mask = claimed(b)
+                    masks.append(mask)
+                batches.append(b)
+        compacted = sum(m is None for m in masks)
+        if sp is not None:
+            sp.set(batches=len(batches), compacted=compacted)
     _COLLAPSE_COMPACTED.add(compacted)
     return batches, (masks if compacted < len(masks) else None), compacted
 
@@ -1224,13 +1230,26 @@ class TpuScanExec(TpuExec):
         cpu_parts = scan_raw_parts(ctx, self.source, self.pushed_filters)
         declared = frozenset()
         if cpu_parts is None:
-            if self.pushed_filters and hasattr(self.source,
-                                               "prune_splits"):
-                cpu_parts = self.source.cpu_partitions(
-                    ctx, self.pushed_filters)
-            else:
-                cpu_parts = self.source.cpu_partitions(ctx)
-            declared = self._declare_stats(ctx)
+            # the scan's share of plan.partitions, apart from the
+            # recursion over the other operators: laying out the splits
+            # (pruning by footer statistics, starting the prefetcher) and
+            # declaring the footers' integer bounds
+            with TRACER.span("scan.plan.splits") as sp:
+                if self.pushed_filters and hasattr(self.source,
+                                                   "prune_splits"):
+                    cpu_parts = self.source.cpu_partitions(
+                        ctx, self.pushed_filters)
+                else:
+                    cpu_parts = self.source.cpu_partitions(ctx)
+                if sp is not None:
+                    total = len(getattr(self.source, "splits", cpu_parts))
+                    sp.set(files=len(getattr(self.source, "paths", ())),
+                           splits=len(cpu_parts),
+                           pruned=max(0, total - len(cpu_parts)))
+            with TRACER.span("scan.plan.stats") as sp:
+                declared = self._declare_stats(ctx)
+                if sp is not None:
+                    sp.set(columns=len(declared))
         max_rows = ctx.conf.batch_size_rows
         schema = self._schema
 
@@ -1524,7 +1543,7 @@ class TpuShuffleExchangeExec(TpuExec):
 
             def single() -> Iterator[DeviceBatch]:
                 import jax as _jax
-                batches = [b for p in child_parts for b in p()]
+                batches = _drain_claimed(child_parts, None)[0]
                 if not batches:
                     yield DeviceBatch.empty(schema)
                     return

@@ -53,18 +53,24 @@ def note_scan_stats(session, df: pd.DataFrame, declared=()) -> None:
     if session is None:
         return
     from spark_rapids_tpu.exec.statsutil import note_bounds
-    for name in df.columns:
-        if name in declared:
-            continue
-        s = df[name]
-        if not (pd.api.types.is_integer_dtype(s.dtype)
-                and not pd.api.types.is_bool_dtype(s.dtype)):
-            continue
-        # min/max skip NA natively; count() avoids the dropna() copy this
-        # scan-upload hot path would otherwise pay per column
-        if not int(s.count()):
-            continue
-        note_bounds(session, str(name), int(s.min()), int(s.max()))
+    from spark_rapids_tpu.obs.trace import TRACER
+    with TRACER.span("scan.host.stats", rows=len(df)) as sp:
+        measured = 0
+        for name in df.columns:
+            if name in declared:
+                continue
+            s = df[name]
+            if not (pd.api.types.is_integer_dtype(s.dtype)
+                    and not pd.api.types.is_bool_dtype(s.dtype)):
+                continue
+            measured += 1
+            # min/max skip NA natively; count() avoids the dropna() copy
+            # this scan-upload hot path would otherwise pay per column
+            if not int(s.count()):
+                continue
+            note_bounds(session, str(name), int(s.min()), int(s.max()))
+        if sp is not None:
+            sp.set(columns=measured)
 
 
 def upload_blocked_chars(ctx: ExecContext) -> int:
@@ -167,9 +173,13 @@ def upload_partition(ctx: ExecContext, part: Partition, schema: Schema,
     blocked = upload_blocked_chars(ctx)
 
     def uploads():
+        df = chunk = prepared = None
         for df in part():
             fname = taskctx.input_file()
-            if getattr(df, "is_raw_rowgroup", False):
+            # asked of the class: a frame's own __getattr__ hashes its
+            # column index at the first name it is asked for (0.15 ms a
+            # fresh frame), which belongs inside the spans below
+            if getattr(type(df), "is_raw_rowgroup", False):
                 # deviceDecode path: the split is a RawRowGroup of
                 # encoded-page decode plans, not a pandas frame — decode
                 # on device (ops/parquet_decode.py). Owns its own
@@ -253,17 +263,28 @@ def upload_partition(ctx: ExecContext, part: Partition, schema: Schema,
                 if PROGRESS.enabled:  # live upload progress
                     PROGRESS.scan_upload(len(chunk))
                 yield fname, batch
+        # the partition's generator has ended and dropped its reference:
+        # the last decoded frame (30-100 MB of a scan's split, and the
+        # buffers its decode worker prepared) is freed by these three
+        # names, so the free has a span (bytes: what the prefetcher
+        # charged for the split, carried in the frame's attrs)
+        with TRACER.span("scan.host.release", partition=i,
+                         bytes=getattr(df, "attrs", {}).get("srt_nbytes")):
+            df = chunk = prepared = None
 
     def account(fname: str, batch: DeviceBatch) -> None:
-        if out is not None:
-            # cached batches live in the spillable catalog
-            # (budget-metered, evictable)
-            from spark_rapids_tpu.memory.spill import SpillPriorities
-            bid = ctx.session.buffer_catalog.add_batch(
-                batch, SpillPriorities.CACHED_SCAN)
-            out.append((fname, bid))
-        elif dm is not None:
-            dm.meter_batch(batch)
+        with TRACER.span("scan.host.meter", partition=i) as sp:
+            if out is not None:
+                # cached batches live in the spillable catalog
+                # (budget-metered, evictable)
+                from spark_rapids_tpu.memory.spill import SpillPriorities
+                bid = ctx.session.buffer_catalog.add_batch(
+                    batch, SpillPriorities.CACHED_SCAN)
+                out.append((fname, bid))
+            elif dm is not None:
+                dm.meter_batch(batch)
+            if sp is not None:
+                sp.set(bytes=batch.device_memory_size())
 
     try:
         gen = uploads()

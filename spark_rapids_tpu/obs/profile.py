@@ -148,12 +148,6 @@ def build_profile(plan, ctx, global_delta: Optional[Dict[str, Any]] = None,
             if m.kind == "gauge" and m.name.startswith("shuffle.skew."):
                 summary["shuffleSkew"].setdefault(m.name, m.value)
     if summary["scan"]:
-        # gauges are state, not flow — excluded from the delta, but the
-        # pipeline's depth gauges are exactly what a scan profile needs
-        from spark_rapids_tpu.obs.metrics import REGISTRY
-        for m in REGISTRY.metrics():
-            if m.kind == "gauge" and m.name.startswith("scan.prefetch."):
-                summary["scan"].setdefault(m.name, m.value)
         summary["scan"]["scan.decode.mode"] = scan_decode_mode(
             summary["scan"])
     if summary["pageCache"]:

@@ -555,12 +555,6 @@ class TpuSparkSession:
                           obs_events.EVENTS.rotate_failures,
                           _LEDGER.seq, _SYNCS.seq) \
                 if ctx.metrics_enabled else None
-            if ctx.metrics_enabled:
-                # the scan pipeline's peak gauge is state, not flow: reset it
-                # per query so the profile's queueDepthPeak is THIS query's
-                # peak, not the process's all-time high (obs/profile.py)
-                obs_metrics.REGISTRY.gauge("scan.prefetch.queueDepthPeak") \
-                    .set(0)
             t_query0 = time.perf_counter()
             # durable event journal (obs/events.py): the query window opens
             # HERE so planning failures are on record too; the failure path

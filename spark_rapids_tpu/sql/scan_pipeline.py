@@ -42,6 +42,7 @@ is metered against the HBM budget (memory/device.py meter_batch).
 from __future__ import annotations
 
 import threading
+import time
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 from typing import Callable, List, Optional, Tuple
 
@@ -63,10 +64,16 @@ _POOL_LOCK = threading.Lock()
 # future.result() per split; metrics must not add registry lookups)
 _STALL_TIME = REGISTRY.timer("scan.prefetch.stallTime")
 _DECODE_TIME = REGISTRY.timer("scan.prefetch.decodeTime")
-_QUEUE_DEPTH = REGISTRY.gauge("scan.prefetch.queueDepth")
-_QUEUE_PEAK = REGISTRY.gauge("scan.prefetch.queueDepthPeak")
+# a split's life: pool.submit -> a worker enters _decode (queueTime) ->
+# decoded (decodeTime) -> taken by get(), which either found it done
+# (hits) or waited (stallTime). By Little's law (queueTime + decodeTime)
+# / activeTime is the mean number of splits submitted and not yet decoded
+# while a scan was active: to be read against the window (depth + 1) and
+# the pool's size.
+_QUEUE_TIME = REGISTRY.timer("scan.prefetch.queueTime")
+_ACTIVE_TIME = REGISTRY.timer("scan.prefetch.activeTime")
 _SPLITS = REGISTRY.counter("scan.prefetch.splits")
-_CANCELLED = REGISTRY.counter("scan.prefetch.cancelled")
+_HITS = REGISTRY.counter("scan.prefetch.hits")
 _BYTES = REGISTRY.counter("scan.prefetch.bytesDecoded")
 _BUDGET_STALLS = REGISTRY.counter("scan.prefetch.budgetStalls")
 
@@ -150,12 +157,19 @@ class ScanPrefetcher:
         self._max_bytes = max(1, max_bytes)
         self._lock = threading.Lock()
         self._futures: dict = {}          # split index -> Future
+        # split index -> [submitted at, a worker has taken it], beside its
+        # future: the stall span reads it, _decode writes it
+        self._life: dict = {}
         self._submitted: set = set()
         self._cancelled = False
         self._failed = False
         self._pending_bytes = 0           # decoded, not yet consumed
-        self._inflight = 0
+        self._charged: dict = {}          # ... by split, as _decode charged
+        self._inflight = 0                # submitted, not yet decoded
         self._skip: set = set()           # submitted splits never consumed
+        # scan.prefetch.activeTime is recorded up to here (None: not
+        # active — before the first get(), after cancel())
+        self._active_since: Optional[float] = None
         # journal sampling state: the event log records rare facts, not
         # per-split streams — budget stalls emit on the entering
         # transition only, decode stalls emit the first _EVENT_CAP per
@@ -169,19 +183,28 @@ class ScanPrefetcher:
     _EVENT_CAP = 16
 
     # -- worker side --------------------------------------------------------
-    def _decode(self, i: int):
+    def _decode(self, i: int, life: list):
+        queued_s = time.perf_counter() - life[0]
         path, fn = self._tasks[i]
         try:
             with self._lock:
                 if self._cancelled:
                     return None
+            life[1] = True
+            _QUEUE_TIME.record(queued_s)
             if TRACER.enabled:
                 TRACER.bind_query(self._query)
             with _DECODE_TIME.time():
                 with TRACER.span("scan.decode", split=i,
-                                 file=path or "<memory>"):
+                                 file=path or "<memory>",
+                                 queued_s=round(queued_s, 6)):
                     df = fn()
             nbytes = _nbytes(df)
+            attrs = getattr(df, "attrs", None)
+            if attrs is not None:
+                # carried to the consumer, whose scan.host.release span
+                # names the free of this many bytes (exec/transitions.py)
+                attrs["srt_nbytes"] = nbytes
             with self._lock:
                 if self._cancelled or i in self._skip:
                     # raced a cancel (or a skip of a never-consumed
@@ -190,6 +213,7 @@ class ScanPrefetcher:
                     self._skip.discard(i)
                     return None
                 self._pending_bytes += nbytes
+                self._charged[i] = nbytes
             _BYTES.add(nbytes)
             if PROGRESS.enabled:  # live scan progress (/api/query/<id>)
                 PROGRESS.scan_split(nbytes)
@@ -197,7 +221,6 @@ class ScanPrefetcher:
         finally:
             with self._lock:
                 self._inflight -= 1
-                _QUEUE_DEPTH.set(self._inflight)
 
     # -- consumer side ------------------------------------------------------
     def _over_budget_locked(self) -> bool:
@@ -210,12 +233,15 @@ class ScanPrefetcher:
         dm = TpuDeviceManager.current()
         return dm is not None and dm.allocated > dm.hbm_budget
 
-    def _submit_window_locked(self, i: int) -> None:
+    def _submit_window_locked(self, i: int) -> int:
+        """Submit what of splits ``i .. i+depth`` is not submitted yet;
+        returns how many that was."""
         if self._cancelled or self._failed:
             # the requested split itself must still decode
             hi = i
         else:
             hi = min(i + self._depth, len(self._tasks) - 1)
+        n = 0
         for j in range(i, hi + 1):
             if j in self._submitted:
                 continue
@@ -231,102 +257,129 @@ class ScanPrefetcher:
                 break
             self._submitted.add(j)
             self._inflight += 1
-            _QUEUE_DEPTH.set(self._inflight)
-            if self._inflight > int(_QUEUE_PEAK.value):
-                _QUEUE_PEAK.set(self._inflight)
-            self._futures[j] = self._pool.submit(self._decode, j)
+            n += 1
+            life = self._life[j] = [time.perf_counter(), False]
+            self._futures[j] = self._pool.submit(self._decode, j, life)
         else:
             # full window submitted without hitting the budget: the next
             # budget trip is a NEW stall episode and journals again
             self._budget_stalled = False
+        return n
 
     def get(self, i: int):
         """Decoded frame of split ``i`` (blocking). Re-raises the split's
         decode exception; marks the pipeline failed so no later splits are
         submitted after the first error."""
-        with self._lock:
-            # earlier splits submitted but never consumed (device-scan-
-            # cache replay bypasses their partitions entirely): reclaim
-            # their budget, or their frames would pin _pending_bytes for
-            # the scan's lifetime and starve the window. A genuinely
-            # out-of-order consumer just re-decodes inline (fut-is-None
-            # path below) — correctness over overlap for that rare case.
-            for j in [k for k in self._futures if k < i]:
-                f = self._futures.pop(j)
-                if f.cancel():
-                    self._inflight -= 1
-                    _QUEUE_DEPTH.set(self._inflight)
-                    _CANCELLED.add(1)
-                elif f.done():
-                    try:
-                        dfj = f.result()
-                    except BaseException:
-                        dfj = None
-                    if dfj is not None:
-                        self._pending_bytes -= _nbytes(dfj)
-                else:
-                    # running: drop its result on finish. The done
-                    # callback reclaims the budget if the decode raced
-                    # past its own skip check before the marker landed.
-                    self._skip.add(j)
-                    f.add_done_callback(
-                        lambda fr, j=j: self._reclaim_skipped(j, fr))
-            self._submit_window_locked(i)
-            fut = self._futures.pop(i, None)
+        t_in = time.perf_counter()
+        # scan.host.take: get() less the wait itself, two pieces a split
+        with TRACER.span("scan.host.take", split=i) as sp:
+            with self._lock:
+                if self._active_since is None:
+                    self._active_since = t_in
+                # earlier splits submitted but never consumed (device-
+                # scan-cache replay bypasses their partitions entirely):
+                # reclaim their budget, or their frames would pin
+                # _pending_bytes for the scan's lifetime and starve the
+                # window. A genuinely out-of-order consumer just
+                # re-decodes inline (fut-is-None path below) —
+                # correctness over overlap for that rare case.
+                for j in [k for k in self._futures if k < i]:
+                    f = self._futures.pop(j)
+                    self._life.pop(j, None)
+                    if f.cancel():
+                        self._inflight -= 1
+                    elif f.done():
+                        self._pending_bytes -= self._charged.pop(j, 0)
+                    else:
+                        # running: drop its result on finish. The done
+                        # callback reclaims the budget if the decode
+                        # raced past its own skip check before the marker
+                        # landed.
+                        self._skip.add(j)
+                        f.add_done_callback(
+                            lambda fr, j=j: self._reclaim_skipped(j))
+                submitted = self._submit_window_locked(i)
+                fut = self._futures.pop(i, None)
+                life = self._life.pop(i, None)
+                inflight = self._inflight
+            if sp is not None:
+                sp.set(submitted=submitted)
         _SPLITS.add(1)
-        if fut is None:
-            # split consumed before (a concurrently re-driven partition,
-            # e.g. a racing device-scan-cache filler): decode inline —
-            # correctness over overlap for the rare second consumer
-            return self._tasks[i][1]()
-        if not fut.done():
-            import time
-            t0 = time.perf_counter()
-            if PROGRESS.enabled:  # live stall state, cleared below
-                PROGRESS.scan_stalled(True)
-            from spark_rapids_tpu.obs.syncledger import sync_scope
-            with TRACER.span("scan.prefetch.stall", split=i), \
-                    sync_scope("scan.stall", detail=f"split={i}"):
-                wait([fut], return_when=FIRST_COMPLETED)
-            if PROGRESS.enabled:
-                PROGRESS.scan_stalled(False)
-            stall_s = time.perf_counter() - t0
-            _STALL_TIME.record(stall_s)
-            with self._lock:
-                self._stall_events += 1
-                sample = self._stall_events <= self._EVENT_CAP
-            if sample:
-                # bounded sample per scan: a thousand-split scan must not
-                # flood the journal/flight ring (scan.prefetch.stallTime
-                # carries the exact aggregate)
-                EVENTS.emit("scanStall", split=i,
-                            stall_s=round(stall_s, 6))
         try:
-            df = fut.result()
-        except BaseException:
-            with self._lock:
-                self._failed = True
-            raise
-        if df is not None:
-            with self._lock:
-                self._pending_bytes -= _nbytes(df)
-        return df
+            if fut is None:
+                # split consumed before (a concurrently re-driven
+                # partition, e.g. a racing device-scan-cache filler):
+                # decode inline — correctness over overlap for the rare
+                # second consumer
+                return self._tasks[i][1]()
+            hit = fut.done()
+            if hit:
+                _HITS.add(1)
+            else:
+                self._stall(i, fut, life, inflight)
+            with TRACER.span("scan.host.take", split=i, hit=hit):
+                try:
+                    df = fut.result()
+                except BaseException:
+                    with self._lock:
+                        self._failed = True
+                    raise
+                with self._lock:
+                    self._pending_bytes -= self._charged.pop(i, 0)
+                return df
+        finally:
+            self._settle_active()
 
-    def _reclaim_skipped(self, j: int, fr) -> None:
+    def _stall(self, i: int, fut, life: list, inflight: int) -> None:
+        """Split ``i`` is not decoded yet: wait for it. The span says what
+        the wait met: ``inflight`` decodes submitted and not done (this
+        one among them), how long ago this one was submitted, and whether
+        a worker had taken it (``running``) or it still sat in the pool's
+        queue."""
+        t0 = time.perf_counter()
+        if PROGRESS.enabled:  # live stall state, cleared below
+            PROGRESS.scan_stalled(True)
+        from spark_rapids_tpu.obs.syncledger import sync_scope
+        with TRACER.span("scan.prefetch.stall", split=i, inflight=inflight,
+                         submitted_ago_s=round(t0 - life[0], 6),
+                         running=life[1]), \
+                sync_scope("scan.stall", detail=f"split={i}"):
+            wait([fut], return_when=FIRST_COMPLETED)
+        if PROGRESS.enabled:
+            PROGRESS.scan_stalled(False)
+        stall_s = time.perf_counter() - t0
+        _STALL_TIME.record(stall_s)
+        with self._lock:
+            self._stall_events += 1
+            sample = self._stall_events <= self._EVENT_CAP
+        if sample:
+            # bounded sample per scan: a thousand-split scan must not
+            # flood the journal/flight ring (scan.prefetch.stallTime
+            # carries the exact aggregate)
+            EVENTS.emit("scanStall", split=i, stall_s=round(stall_s, 6))
+
+    def _settle_active(self, end: bool = False) -> None:
+        """Record scan.prefetch.activeTime up to now: every get() does on
+        its way out, so the timer's total runs from the entry of the first
+        get() to the return of the last; ``end`` (cancel()) stops it."""
+        now = time.perf_counter()
+        with self._lock:
+            since = self._active_since
+            if since is None:
+                return
+            self._active_since = None if end else now
+        _ACTIVE_TIME.record(now - since)
+
+    def _reclaim_skipped(self, j: int) -> None:
         """Done-callback for a skipped-while-running decode: if _decode
         raced past its skip check (frame returned, bytes accounted),
         reclaim the budget here — otherwise the orphaned bytes would pin
         _pending_bytes for the scan's lifetime."""
-        try:
-            df = fr.result()
-        except BaseException:  # noqa: BLE001 — skipped split, error moot
-            df = None
         with self._lock:
             if self._cancelled or j not in self._skip:
                 return  # _decode saw the marker (or cancel reset budget)
             self._skip.discard(j)
-            if df is not None:
-                self._pending_bytes -= _nbytes(df)
+            self._pending_bytes -= self._charged.pop(j, 0)
 
     def cancel(self) -> None:
         """Early consumer exit: cancel unstarted decodes, drop every
@@ -336,15 +389,16 @@ class ScanPrefetcher:
             self._cancelled = True
             futures = list(self._futures.values())
             self._futures.clear()
+            self._life.clear()
+            self._charged.clear()
             self._pending_bytes = 0
         n = sum(1 for f in futures if f.cancel())
         if n:
-            _CANCELLED.add(n)
             with self._lock:
                 # cancelled-before-start futures never run _decode's
-                # accounting; settle the in-flight gauge for them here
+                # accounting; settle the in-flight count for them here
                 self._inflight -= n
-                _QUEUE_DEPTH.set(self._inflight)
+        self._settle_active(end=True)
 
     def drain(self, timeout: float = 30.0) -> bool:
         """Wait for in-flight decodes to finish (tests; bounded)."""

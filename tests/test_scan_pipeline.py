@@ -2,6 +2,7 @@
 exception propagation, early-exit cancellation, depth bound, pandas-vs-
 direct decode value equality, serial-rollback equivalence."""
 
+import contextlib
 import gc
 import threading
 import time
@@ -125,6 +126,174 @@ def test_prefetcher_cancel_leaves_no_work(session):
     # the shared daemon pool is bounded; repeated early exits must not
     # keep spawning threads
     assert threading.active_count() <= threads_before + 3
+
+
+# --------------------------------------------------------------------------
+# a split's life: submitted, started, decoded, taken
+# --------------------------------------------------------------------------
+
+_LIFE = ("scan.prefetch.splits", "scan.prefetch.hits",
+         "scan.prefetch.stallTime", "scan.prefetch.queueTime",
+         "scan.prefetch.activeTime")
+
+
+def _life_counts():
+    """(splits, hits, stalls, decodes a worker took, activeTime records)."""
+    from spark_rapids_tpu.obs.metrics import REGISTRY
+    return (REGISTRY.counter(_LIFE[0]).value, REGISTRY.counter(_LIFE[1]).value,
+            *(REGISTRY.timer(n).count for n in _LIFE[2:]))
+
+
+def _life_seconds(name):
+    from spark_rapids_tpu.obs.metrics import REGISTRY
+    return REGISTRY.timer(name).total_seconds
+
+
+@contextlib.contextmanager
+def _traced():
+    """The tracer on for the block; yields a callable that returns the
+    events so far. Off and empty afterwards, whatever the block did."""
+    from spark_rapids_tpu.obs.trace import TRACER
+    TRACER.configure(True)
+    TRACER.clear()
+    try:
+        yield TRACER.events
+    finally:
+        TRACER.configure(False)
+        TRACER.clear()
+
+
+class _Gated:
+    """Hand-made tasks whose decode of split i waits on ``gates[i]``, and
+    a ``wait`` for the prefetcher that opens the gate of the split it
+    waits for only once the consumer is inside the wait: a closed gate is
+    a stall and an open one (after ``drain``) a hit, whatever the clock
+    does."""
+
+    def __init__(self, monkeypatch, n, hold_s=0.0):
+        from spark_rapids_tpu.sql import scan_pipeline
+        self.gates = [threading.Event() for _ in range(n)]
+        self.tasks = _tasks(n, decode=self._decode)
+        self.waiting_for = None
+        real_wait = scan_pipeline.wait
+
+        def wait(futures, timeout=10, return_when=None):
+            if return_when is None:     # drain(), not a stall
+                return real_wait(futures, timeout=timeout)
+            time.sleep(hold_s)
+            self.gates[self.waiting_for].set()
+            return real_wait(futures, timeout=timeout,
+                             return_when=return_when)
+        monkeypatch.setattr(scan_pipeline, "wait", wait)
+
+    def _decode(self, i):
+        assert self.gates[i].wait(timeout=10)
+        return pd.DataFrame({"v": [i]})
+
+    def get(self, pf, i):
+        self.waiting_for = i
+        return int(pf.get(i)["v"][0])
+
+
+def _life_scenario(monkeypatch):
+    """Six splits, depth 2, four workers: stall, hit, hit, stall, an
+    inline decode of a split taken before, stall, stall. Returns the
+    growth of (splits, hits, stalls, started, activeTime records)."""
+    g = _Gated(monkeypatch, 6)
+    pf = ScanPrefetcher(g.tasks, depth=2, pool=decode_pool(4),
+                        max_bytes=1 << 30)
+    before = _life_counts()
+    assert g.get(pf, 0) == 0            # nothing decoded yet: a stall
+    g.gates[1].set()
+    g.gates[2].set()
+    assert pf.drain(timeout=10)         # 1 and 2 are decoded
+    assert g.get(pf, 1) == 1            # a hit (and submits 3)
+    assert g.get(pf, 2) == 2            # a hit (and submits 4)
+    assert g.get(pf, 3) == 3            # its gate is closed: a stall
+    assert g.get(pf, 1) == 1            # taken before: decoded inline
+    assert g.get(pf, 4) == 4            # a stall
+    assert g.get(pf, 5) == 5            # a stall
+    return tuple(b - a for a, b in zip(before, _life_counts()))
+
+
+def test_hits_stalls_and_inline_decodes_add_up_to_the_splits(monkeypatch):
+    first = _life_scenario(monkeypatch)
+    splits, hits, stalls, started, active = first
+    inline = 1
+    assert (splits, hits, stalls) == (7, 2, 4)
+    assert hits + stalls + inline == splits
+    assert started == 6 and active == splits
+    # the counts repeat exactly
+    assert _life_scenario(monkeypatch) == first
+
+
+def test_stall_and_decode_spans_say_what_the_wait_met(monkeypatch):
+    with _traced() as so_far:
+        _life_scenario(monkeypatch)
+        events = so_far()
+    stalls = {e["args"]["split"]: e["args"] for e in events
+              if e["name"] == "scan.prefetch.stall"}
+    assert sorted(stalls) == [0, 3, 4, 5]
+    # split 0 waited with the whole window 0..2 undecoded; 3 with 3 and 4
+    # (5 is submitted by get(3)'s window too); the last with itself alone
+    assert [stalls[i]["inflight"] for i in (0, 3, 4, 5)] == [3, 3, 2, 1]
+    for a in stalls.values():
+        assert a["submitted_ago_s"] >= 0 and a["running"] in (True, False)
+    decodes = [e["args"] for e in events if e["name"] == "scan.decode"]
+    assert sorted(a["split"] for a in decodes) == list(range(6))
+    assert all(a["queued_s"] >= 0 for a in decodes)
+    takes = [e["args"] for e in events if e["name"] == "scan.host.take"]
+    # two pieces a split that had a future, one for the inline decode
+    assert len(takes) == 2 * 6 + 1
+    assert sum(a.get("submitted", 0) for a in takes) == 6
+    assert sorted(a["split"] for a in takes if a.get("hit")) == [1, 2]
+
+
+@pytest.mark.parametrize("workers, at_least, at_most", [
+    # a window no wider than the pool: no split waits for a worker
+    (4, 0.0, 1e-3),
+    # one worker under a window of three: splits 1 and 2 queue behind
+    # split 0's decode, which is held for 0.2 s
+    (1, 0.2, None)], ids=["window-fits-pool", "one-worker"])
+def test_queue_time_says_when_the_pool_is_the_limit(monkeypatch, workers,
+                                                    at_least, at_most):
+    g = _Gated(monkeypatch, 3, hold_s=0.2)
+    g.gates[1].set()
+    g.gates[2].set()
+    pf = ScanPrefetcher(g.tasks, depth=2, pool=decode_pool(workers),
+                        max_bytes=1 << 30)
+    before = _life_seconds("scan.prefetch.queueTime")
+    with _traced() as so_far:
+        assert [g.get(pf, i) for i in range(3)] == [0, 1, 2]
+        queued = sorted(e["args"]["queued_s"] for e in so_far()
+                        if e["name"] == "scan.decode")
+    grown = _life_seconds("scan.prefetch.queueTime") - before
+    assert grown == pytest.approx(sum(queued), abs=1e-4)
+    assert grown >= at_least
+    if at_most is not None:
+        assert queued[1] < at_most      # the median of the three
+
+
+def test_active_time_runs_from_first_to_last_get_and_ends_on_cancel():
+    def active():
+        return _life_seconds("scan.prefetch.activeTime")
+    pf = ScanPrefetcher(_tasks(8), depth=2, pool=decode_pool(3),
+                        max_bytes=1 << 30)
+    before = active()
+    t0 = time.perf_counter()
+    pf.get(0)
+    time.sleep(0.05)                    # the consumer's own work counts
+    pf.get(1)
+    wall = time.perf_counter() - t0
+    assert 0.05 <= active() - before <= wall
+    time.sleep(0.05)
+    pf.cancel()                         # ends it, the pause included
+    ended = active() - before
+    assert ended >= 0.1
+    time.sleep(0.02)
+    pf.cancel()
+    assert pf.drain(timeout=10)
+    assert active() - before == ended   # not active after the cancel
 
 
 # --------------------------------------------------------------------------

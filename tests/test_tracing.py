@@ -265,7 +265,16 @@ def test_disabled_metrics_no_wrapping(session):
 QUERY_SPANS = ("query.begin", "plan.logical", "plan.rewrite",
                "plan.partitions", "Query", "scan.chunk", "scan.upload",
                "upload.build", "upload.put", "collect.concat",
-               "query.finish")
+               "query.finish",
+               # the scan's planning, the scan pull outside its wait and
+               # its upload, an exchange's drain
+               "scan.plan.splits", "scan.plan.stats", "scan.host.take",
+               "scan.host.stats", "scan.host.meter", "scan.host.release",
+               "exchange.drain")
+# the children of the TpuScanExec operator span: siblings, disjoint
+SCAN_PULL_SPANS = ("scan.host.take", "scan.prefetch.stall",
+                   "scan.host.stats", "scan.chunk", "scan.upload",
+                   "scan.host.meter", "scan.host.release")
 POOL_SPANS = ("scan.decode", "scan.decode.read", "scan.decode.convert")
 
 
@@ -322,7 +331,14 @@ def test_span_present_with_the_query_id(traced_events, name):
     ("upload.build", "sync.scan.upload"),
     ("scan.decode.read", "scan.decode"),
     ("scan.decode.convert", "scan.decode"),
-    ("plan.partitions", "Query"), ("scan.upload", "Query")])
+    ("plan.partitions", "Query"), ("scan.upload", "Query"),
+    ("scan.plan.splits", "plan.partitions"),
+    ("scan.plan.stats", "plan.partitions"),
+    ("scan.host.take", "TpuScanExec"), ("scan.host.stats", "TpuScanExec"),
+    ("scan.host.meter", "TpuScanExec"),
+    ("scan.host.release", "TpuScanExec"),
+    ("TpuScanExec", "exchange.drain"),
+    ("exchange.drain", "TpuShuffleExchangeExec")])
 def test_span_nests_by_time_on_its_thread(traced_events, inner, outer):
     outers = _spans(traced_events, outer)
     inners = _spans(traced_events, inner)
@@ -351,7 +367,80 @@ def test_chunk_copy_is_a_sibling_of_the_upload(traced_events):
         assert a["ts"] + a["dur"] <= b["ts"] + 1
 
 
+def test_scan_pull_spans_are_disjoint_children_of_the_operator_span(
+        traced_events):
+    pulls = sorted((e for e in traced_events
+                    if e["name"] in SCAN_PULL_SPANS and e["ph"] == "X"),
+                   key=lambda e: e["ts"])
+    assert {e["name"] for e in pulls} >= set(SCAN_PULL_SPANS) - {
+        "scan.prefetch.stall"}
+    for e in pulls:
+        assert e["args"]["parent"] == "TpuScanExec", e
+    for a, b in zip(pulls, pulls[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1, (a, b)
+
+
+def test_an_exchange_drain_precedes_its_collapse(traced_events):
+    drains = _spans(traced_events, "exchange.drain")
+    collapses = _spans(traced_events, "exchange.collapse")
+    assert len(drains) == len(collapses) == 1
+    drain, collapse = drains[0], collapses[0]
+    assert drain["ts"] + drain["dur"] <= collapse["ts"] + 1
+    assert drain["args"]["parent"] == collapse["args"]["parent"] \
+        == "TpuShuffleExchangeExec"
+    assert drain["args"]["batches"] == collapse["args"]["batches"] >= 1
+    assert drain["args"]["claimed"] is False
+    assert drain["args"]["compacted"] == 0
+
+
+def test_children_cover_a_scan_pull_of_a_full_batch(session, tmp_path, rng):
+    """A split of 2^20 rows, one batch: what the query's thread does in a
+    TpuScanExec pull lies inside the pull's child spans (at least 0.8 of
+    its seconds here; the chip's share is PERF.md's)."""
+    n = 1 << 20
+    path = str(tmp_path / "big.parquet")
+    pd.DataFrame({"k": rng.integers(0, 7, n).astype(np.int64),
+                  "q": rng.integers(0, 50, n).astype(np.int32),
+                  "v": rng.random(n)}).to_parquet(path, index=False)
+    session.set_conf("spark.rapids.sql.enabled", True)
+    session.set_conf("spark.rapids.tpu.trace.enabled", True)
+    q = (session.read.parquet(path).filter(F.col("q") < 24)
+         .group_by("k").agg(F.sum("v").alias("sv")))
+    q.collect()
+    assert len(q.collect()) == 7        # the second execution is the warm one
+    events = TRACER.events()
+    scans = _spans(events, "TpuScanExec")
+    kids = [e for e in events if e["ph"] == "X"
+            and e["args"].get("parent") == "TpuScanExec"]
+    assert {e["name"] for e in kids} <= set(SCAN_PULL_SPANS)
+    for e in kids:
+        assert any(_inside(e, s) for s in scans), e
+    covered = sum(e["dur"] for e in kids) / sum(e["dur"] for e in scans)
+    assert 0.8 <= covered <= 1.0, covered
+    release = _spans(events, "scan.host.release")[0]["args"]
+    stats = _spans(events, "scan.host.stats")[0]["args"]
+    # the frame's three columns and the worker's prepared copies of them;
+    # the footer declared k and q, so the upload measures neither again
+    assert release["bytes"] >= n * (8 + 4 + 8)
+    assert (stats["rows"], stats["columns"]) == (n, 0)
+    assert _spans(events, "scan.plan.stats")[0]["args"]["columns"] == 2
+
+
 def test_span_attributes(traced_events):
+    takes = [e["args"] for e in _spans(traced_events, "scan.host.take")]
+    assert [a["split"] for a in takes] == [0, 0]
+    assert takes[0]["submitted"] == 1 and takes[1]["hit"] in (True, False)
+    stats = _spans(traced_events, "scan.host.stats")[0]["args"]
+    assert stats["rows"] == 3000 and stats["columns"] == 0
+    assert all(e["args"]["bytes"] > 0
+               for e in _spans(traced_events, "scan.host.meter"))
+    assert _spans(traced_events, "scan.host.release")[0]["args"][
+        "bytes"] > 3000 * 8
+    splits = _spans(traced_events, "scan.plan.splits")[0]["args"]
+    assert (splits["files"], splits["splits"], splits["pruned"]) == (1, 1, 0)
+    # the query reads tag and v: no integer column to declare or measure
+    assert _spans(traced_events, "scan.plan.stats")[0]["args"][
+        "columns"] == 0
     read = _spans(traced_events, "scan.decode.read")[0]["args"]
     assert read["file"].endswith("t.parquet") and read["row_group"] == 0
     assert read["bytes"] > 0
