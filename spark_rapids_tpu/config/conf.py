@@ -368,16 +368,21 @@ _non_negative = (lambda v: None if v >= 0
 
 SCAN_PREFETCH_DEPTH = register(
     "spark.rapids.sql.scan.prefetchDepth", int, 2,
-    "How many scan splits (Parquet row groups, ORC stripes, CSV files, "
-    "in-memory slices) may decode on the shared host pool AHEAD of the "
-    "consuming task, overlapping host decode with device upload/compute "
-    "(the reference's MULTITHREADED reader, GpuParquetScan). Also gates "
-    "the double-buffered upload in the host->device transition (batch "
-    "i+1's device_put dispatched while batch i computes). 0 selects the "
-    "LEGACY serial reader end to end (the reference's PERFILE mode "
-    "analogue): synchronous full arrow->pandas decode on the consuming "
-    "thread in strict pull order, pre-pipeline behavior exactly — the "
-    "safe rollback path.",
+    "How many DECODED scan splits (Parquet row groups, ORC stripes, CSV "
+    "files, in-memory slices) may wait ahead of the consuming task, "
+    "beyond those still decoding. The shared host pool decodes ahead of "
+    "the consumer, overlapping host decode with device upload/compute "
+    "(the reference's MULTITHREADED reader, GpuParquetScan): one scan "
+    "keeps as many decodes in flight as the pool has threads "
+    "(spark.rapids.sql.scan.decodeThreads), refilled as each ends, so at "
+    "most decodeThreads + prefetchDepth splits are submitted and not yet "
+    "taken, of which at most decodeThreads are undecoded. Any positive "
+    "value also gates the double-buffered upload in the host->device "
+    "transition (batch i+1's device_put dispatched while batch i "
+    "computes). 0 selects the LEGACY serial reader end to end (the "
+    "reference's PERFILE mode analogue): synchronous full arrow->pandas "
+    "decode on the consuming thread in strict pull order, pre-pipeline "
+    "behavior exactly — the safe rollback path.",
     validator=_non_negative)
 
 SCAN_DECODE_THREADS = register(
@@ -393,7 +398,9 @@ SCAN_PREFETCH_MAX_BYTES = register(
     "Host-memory budget for decoded-but-unconsumed prefetched frames "
     "across one scan; submission stalls past it (clamped to "
     "spark.rapids.memory.host.spillStorageSize so prefetch never "
-    "outgrows the spill framework's own host budget).")
+    "outgrows the spill framework's own host budget). A split is charged "
+    "once decoded, so one scan's worst case is this budget plus the "
+    "decodeThreads splits that were decoding when it filled.")
 
 SCAN_DICT_NUMERICS = register(
     "spark.rapids.sql.scan.dictEncodeNumerics", _to_bool, False,

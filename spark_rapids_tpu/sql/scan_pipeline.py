@@ -1,5 +1,5 @@
-"""Asynchronous scan pipeline: bounded-depth split prefetch on a shared
-decode thread pool.
+"""Asynchronous scan pipeline: split prefetch on a shared decode thread
+pool, kept as full as the pool is wide.
 
 The reference closes its scan gap with a multithreaded, coalescing Parquet
 reader that overlaps host decode with device transfer (GpuParquetScan's
@@ -9,34 +9,51 @@ analogue here overlaps the three serial stages of a file scan —
     host decode (pyarrow, GIL-released)  ->  host->device upload
                                          ->  device compute
 
-— by decoding up to ``spark.rapids.sql.scan.prefetchDepth`` splits ahead of
-the consuming task on a shared daemon pool, while the upload side
-double-buffers (exec/transitions.py): batch i+1's ``device_put`` is
-dispatched while batch i computes.
+— by decoding splits ahead of the consuming task on a shared daemon pool,
+while the upload side double-buffers (exec/transitions.py): batch i+1's
+``device_put`` is dispatched while batch i computes.
+
+The window (``ScanPrefetcher``): splits are submitted in order, and one
+scan keeps as many decodes in flight (submitted, not yet decoded) as the
+pool has threads. A slot is refilled where it frees: by the worker that
+finishes a decode, by the consumer when it takes a frame (a take frees
+budget) and when it asks for one. Two bounds in splits: at most
+``threads`` undecoded, and at most ``threads + prefetchDepth`` submitted
+and not yet taken, so ``spark.rapids.sql.scan.prefetchDepth`` is how many
+decoded splits may wait ahead of the consumer beyond those decoding. One
+bound in bytes (Backpressure, below).
 
 Contract (tests/test_scan_pipeline.py):
 
   * partition order is preserved exactly — split i's frames are yielded by
     partition i, in decode order;
   * the first decode exception propagates to the consumer of the failing
-    split, and no further splits are submitted after a failure;
+    split, and no further splits are submitted after a failure (the
+    worker that saw it marks the scan failed; a split the consumer asks
+    for still decodes, and the error of a split it passed over ends
+    nothing);
   * abandoning a partition generator early (CollectLimit, errors) cancels
     every not-yet-started decode and drops decoded-frame references, so the
-    pipeline holds no buffers after GC;
+    pipeline holds no buffers after GC; a finishing worker submits nothing
+    for a cancelled scan;
   * ``prefetchDepth=0`` selects the LEGACY reader end to end (the
     reference keeps its PERFILE reader as a separate code path the same
     way): synchronous full arrow->pandas decode on the consuming thread
     in strict pull order, no hints, no direct decode — pre-pipeline
     behavior exactly (the safe rollback path).
 
-Backpressure: decoded-but-unconsumed frames are host memory; submission
-stalls once their estimated bytes exceed
-``spark.rapids.sql.scan.prefetchMaxBytes`` (clamped to the host spill
-budget) or while the device manager is over its HBM spill budget — prefetch
-can never race the spill framework for memory it is trying to free. The
-device side needs no extra gate: uploads happen on the consuming task
-thread, which already holds a TpuSemaphore permit, and every uploaded batch
-is metered against the HBM budget (memory/device.py meter_batch).
+Backpressure: decoded-but-unconsumed frames are host memory; every
+submission but that of the split the consumer is asking for stalls once
+their estimated bytes reach ``spark.rapids.sql.scan.prefetchMaxBytes``
+(clamped to the host spill budget) or while the device manager is over its
+HBM spill budget — prefetch can never race the spill framework for memory
+it is trying to free. A split is charged when it is decoded, not when it
+is submitted, so the worst case of one scan is ``prefetchMaxBytes`` plus
+the ``threads`` splits that were decoding when the budget filled (and the
+frame the consumer holds). The device side needs no extra gate: uploads
+happen on the consuming task thread, which already holds a TpuSemaphore
+permit, and every uploaded batch is metered against the HBM budget
+(memory/device.py meter_batch).
 """
 
 from __future__ import annotations
@@ -68,14 +85,17 @@ _DECODE_TIME = REGISTRY.timer("scan.prefetch.decodeTime")
 # decoded (decodeTime) -> taken by get(), which either found it done
 # (hits) or waited (stallTime). By Little's law (queueTime + decodeTime)
 # / activeTime is the mean number of splits submitted and not yet decoded
-# while a scan was active: to be read against the window (depth + 1) and
-# the pool's size.
+# while a scan was active: to be read against the pool's width, which is
+# what the window keeps in flight.
 _QUEUE_TIME = REGISTRY.timer("scan.prefetch.queueTime")
 _ACTIVE_TIME = REGISTRY.timer("scan.prefetch.activeTime")
 _SPLITS = REGISTRY.counter("scan.prefetch.splits")
 _HITS = REGISTRY.counter("scan.prefetch.hits")
 _BYTES = REGISTRY.counter("scan.prefetch.bytesDecoded")
 _BUDGET_STALLS = REGISTRY.counter("scan.prefetch.budgetStalls")
+# splits submitted by a worker that had just finished a decode, not by the
+# consumer's thread: the window is refilled where a slot frees
+_WORKER_SUBMITS = REGISTRY.counter("scan.prefetch.workerSubmits")
 
 
 def _nbytes(obj) -> int:
@@ -140,27 +160,34 @@ def pipeline_config(conf):
 
 
 class ScanPrefetcher:
-    """Bounded-depth, order-preserving prefetch over one scan's splits.
+    """Order-preserving prefetch over one scan's splits, as many decodes
+    in flight as the pool has threads.
 
-    ``get(i)`` submits splits ``i .. i+depth`` (so while the consumer
-    drains split i, up to ``depth`` later splits decode concurrently),
-    blocks on split i's future, and hands the frame over — the prefetcher
-    drops its own reference so consumed frames are GC-eligible the moment
-    the consumer releases them.
+    Splits are submitted in order while fewer than ``threads`` are
+    undecoded and fewer than ``threads + depth`` are submitted and not
+    yet taken (and the byte budget allows): by ``get(i)`` on its way in
+    and again once it has taken split i's frame, and by every worker that
+    finishes a decode. ``get(i)`` blocks on split i's future and hands
+    the frame over — the prefetcher drops its own reference so consumed
+    frames are GC-eligible the moment the consumer releases them.
     """
 
-    def __init__(self, tasks: List[ScanTask], depth: int,
-                 pool: ThreadPoolExecutor, max_bytes: int):
+    def __init__(self, tasks: List[ScanTask], depth: int, threads: int,
+                 max_bytes: int, pool: Optional[ThreadPoolExecutor] = None):
         self._tasks = tasks
         self._depth = max(1, depth)
-        self._pool = pool
+        self._threads = max(1, threads)
+        # the shared pool at that width: the width has one source (tests
+        # pass a pool of their own to stand for one that other scans hold)
+        self._pool = pool or decode_pool(self._threads)
         self._max_bytes = max(1, max_bytes)
         self._lock = threading.Lock()
-        self._futures: dict = {}          # split index -> Future
+        self._futures: dict = {}          # split index -> Future, untaken
         # split index -> [submitted at, a worker has taken it], beside its
         # future: the stall span reads it, _decode writes it
         self._life: dict = {}
         self._submitted: set = set()
+        self._next = 0                    # from here on, unsubmitted
         self._cancelled = False
         self._failed = False
         self._pending_bytes = 0           # decoded, not yet consumed
@@ -218,9 +245,25 @@ class ScanPrefetcher:
             if PROGRESS.enabled:  # live scan progress (/api/query/<id>)
                 PROGRESS.scan_split(nbytes)
             return df
+        except BaseException:
+            # the first error ends prefetch here, on the worker: decodes
+            # that finish after it must not refill the window. Not so for
+            # a split the consumer passed over: its error is nobody's
+            with self._lock:
+                if i not in self._skip:
+                    self._failed = True
+            raise
         finally:
             with self._lock:
                 self._inflight -= 1
+                try:
+                    _WORKER_SUBMITS.add(self._top_up_locked())
+                except RuntimeError:
+                    # the pool was displaced by a session with another
+                    # decodeThreads and takes no new work: prefetch ends,
+                    # and this decode's frame is not lost to it. A split
+                    # the consumer still has to ask for raises there.
+                    self._failed = True
 
     # -- consumer side ------------------------------------------------------
     def _over_budget_locked(self) -> bool:
@@ -233,43 +276,49 @@ class ScanPrefetcher:
         dm = TpuDeviceManager.current()
         return dm is not None and dm.allocated > dm.hbm_budget
 
-    def _submit_window_locked(self, i: int) -> int:
-        """Submit what of splits ``i .. i+depth`` is not submitted yet;
-        returns how many that was."""
-        if self._cancelled or self._failed:
-            # the requested split itself must still decode
-            hi = i
-        else:
-            hi = min(i + self._depth, len(self._tasks) - 1)
+    def _submit_locked(self, j: int) -> None:
+        life = [time.perf_counter(), False]
+        # the worker's first line takes the lock this thread holds, so
+        # the bookkeeping below is in place before _decode reads it
+        self._futures[j] = self._pool.submit(self._decode, j, life)
+        self._life[j] = life
+        self._submitted.add(j)
+        self._inflight += 1
+
+    def _top_up_locked(self) -> int:
+        """Submit the next unsubmitted splits, in order, while fewer than
+        ``threads`` are undecoded, fewer than ``threads + depth`` are
+        submitted and untaken, the budget allows and the scan is neither
+        cancelled nor failed; returns how many that was. Called wherever
+        a slot frees: a decode ends (the worker), a frame is taken or
+        asked for (the consumer)."""
         n = 0
-        for j in range(i, hi + 1):
-            if j in self._submitted:
-                continue
-            if j > i and self._over_budget_locked():
+        while (not (self._cancelled or self._failed)
+               and self._next < len(self._tasks)
+               and self._inflight < self._threads
+               and len(self._futures) < self._threads + self._depth):
+            if self._over_budget_locked():
                 _BUDGET_STALLS.add(1)
                 if not self._budget_stalled:
                     # backpressure fact, on the ENTERING transition only
-                    # (sustained pressure re-trips per split): prefetch
+                    # (sustained pressure re-trips per attempt): prefetch
                     # submission stopped here, the pipeline runs at
                     # consumer speed until the budget drains
                     self._budget_stalled = True
-                    EVENTS.emit("scanBudgetStall", split=j)
+                    EVENTS.emit("scanBudgetStall", split=self._next)
                 break
-            self._submitted.add(j)
-            self._inflight += 1
-            n += 1
-            life = self._life[j] = [time.perf_counter(), False]
-            self._futures[j] = self._pool.submit(self._decode, j, life)
-        else:
-            # full window submitted without hitting the budget: the next
-            # budget trip is a NEW stall episode and journals again
+            # a submission the budget let through: the next budget trip
+            # is a NEW stall episode and journals again
             self._budget_stalled = False
+            self._submit_locked(self._next)
+            self._next += 1
+            n += 1
         return n
 
     def get(self, i: int):
         """Decoded frame of split ``i`` (blocking). Re-raises the split's
-        decode exception; marks the pipeline failed so no later splits are
-        submitted after the first error."""
+        decode exception (the worker that met it has marked the pipeline
+        failed, so no later splits are submitted after the first error)."""
         t_in = time.perf_counter()
         # scan.host.take: get() less the wait itself, two pieces a split
         with TRACER.span("scan.host.take", split=i) as sp:
@@ -298,9 +347,19 @@ class ScanPrefetcher:
                         self._skip.add(j)
                         f.add_done_callback(
                             lambda fr, j=j: self._reclaim_skipped(j))
-                submitted = self._submit_window_locked(i)
+                submitted = 0
+                if i not in self._submitted:
+                    # whatever the budget says, and when cancelled or
+                    # failed too: the requested split itself must decode
+                    self._submit_locked(i)
+                    submitted = 1
+                # splits the consumer passed over are never submitted
+                self._next = max(self._next, i + 1)
+                # taken out before the top-up: the split asked for is not
+                # one of the untaken that bound the window
                 fut = self._futures.pop(i, None)
                 life = self._life.pop(i, None)
+                submitted += self._top_up_locked()
                 inflight = self._inflight
             if sp is not None:
                 sp.set(submitted=submitted)
@@ -317,15 +376,14 @@ class ScanPrefetcher:
                 _HITS.add(1)
             else:
                 self._stall(i, fut, life, inflight)
-            with TRACER.span("scan.host.take", split=i, hit=hit):
-                try:
-                    df = fut.result()
-                except BaseException:
-                    with self._lock:
-                        self._failed = True
-                    raise
+            with TRACER.span("scan.host.take", split=i, hit=hit) as sp:
+                df = fut.result()
                 with self._lock:
                     self._pending_bytes -= self._charged.pop(i, 0)
+                    # a take is what frees budget
+                    submitted = self._top_up_locked()
+                if sp is not None:
+                    sp.set(submitted=submitted)
                 return df
         finally:
             self._settle_active()
@@ -436,8 +494,7 @@ def build_partitions(ctx, tasks: List[ScanTask]) -> List["Partition"]:  # noqa: 
             return run
         return [make_serial(p, fn) for p, fn in tasks]
 
-    prefetcher = ScanPrefetcher(tasks, depth, decode_pool(threads),
-                                max_bytes)
+    prefetcher = ScanPrefetcher(tasks, depth, threads, max_bytes)
 
     def make(i: int, path: Optional[str]) -> "Partition":  # noqa: F821
         def run():

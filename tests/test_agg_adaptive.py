@@ -154,11 +154,11 @@ def test_skip_decision_journaled_with_measured_rate(session, rng):
     df = (session.create_dataframe(pdf, 4).group_by("k")
           .agg(F.sum("v").alias("sv")))
     # the flight ring is bounded: cut by seq, not by index
-    seq0 = max((ev["seq"] for ev in EVENTS.flight_events()),
+    seq0 = max((ev.get("seq", 0) for ev in EVENTS.flight_events()),
                default=0)
     df.collect()
     first = [ev for ev in EVENTS.flight_events()
-             if ev["seq"] > seq0
+             if ev.get("seq", 0) > seq0
              and ev["kind"] == "aggSkipDecision"]
     assert first, "first execution journaled no decision"
     # the first partition decides from measurement; later partitions of
@@ -171,11 +171,11 @@ def test_skip_decision_journaled_with_measured_rate(session, rng):
         assert 0.0 < ev["threshold"] < 1.0
     assert first[0]["batches"] >= 1
     # the flight ring is bounded: cut by seq, not by index
-    seq0 = max((ev["seq"] for ev in EVENTS.flight_events()),
+    seq0 = max((ev.get("seq", 0) for ev in EVENTS.flight_events()),
                default=0)
     df.collect()
     second = [ev for ev in EVENTS.flight_events()
-              if ev["seq"] > seq0
+              if ev.get("seq", 0) > seq0
               and ev["kind"] == "aggSkipDecision"]
     assert second and all(ev["source"] == "cache" for ev in second)
     assert all(ev["decision"] == "skip" for ev in second)

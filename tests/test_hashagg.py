@@ -90,7 +90,7 @@ def test_hash_agg_forced_fanout_matches(session, rng):
     from spark_rapids_tpu.obs.events import EVENTS
     pdf = _click_frame(rng, n=5000)
     # the flight ring is bounded: cut by seq, not by index
-    seq0 = max((ev["seq"] for ev in EVENTS.flight_events()),
+    seq0 = max((ev.get("seq", 0) for ev in EVENTS.flight_events()),
                default=0)
     hsh = _hash_vs_sort_vs_cpu(
         session,
@@ -101,7 +101,7 @@ def test_hash_agg_forced_fanout_matches(session, rng):
         sort_leg=False)
     assert len(hsh) > 0
     splits = [ev for ev in EVENTS.flight_events()
-              if ev["seq"] > seq0 and ev["kind"] == "outOfCore"
+              if ev.get("seq", 0) > seq0 and ev["kind"] == "outOfCore"
               and ev.get("op") == "hashAggSplit"]
     assert splits, "forced fan-out never engaged"
 
