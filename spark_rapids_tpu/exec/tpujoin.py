@@ -36,6 +36,8 @@ _INPUT_BYTES = REGISTRY.counter("join.inputBytes")
 _COND_PAIRS = REGISTRY.counter("join.cond.pairs")
 _COND_PIECES = REGISTRY.counter("join.cond.pieces")
 _COND_INPUT_BYTES = REGISTRY.counter("join.cond.inputBytes")
+# stream rows the extent form decided (rows the host knows without a sync)
+_COND_EXTENT_ROWS = REGISTRY.counter("join.cond.extentRows")
 
 
 def _start_host_copies(arrays) -> None:
@@ -202,16 +204,50 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
         if condition is not None:
             self._init_conditioned(sig)
 
+    def _extent_plan(self, n_left: int):
+        """(op, build column, stream column) where the extent form decides
+        this join: a semi or anti join whose residual is one ``<>``, ``<``,
+        ``<=``, ``>`` or ``>=`` of a build column with a stream column, in
+        either order (``op`` is read as ``build <op> stream``), both of
+        integer kind and compared without a scaling (a date against a
+        timestamp is); else None."""
+        from spark_rapids_tpu.columnar import dtype as dtypes
+        from spark_rapids_tpu.sql.exprs import predicates as P
+        from spark_rapids_tpu.sql.exprs.core import BoundRef
+        ops = {P.Neq: ("ne", "ne"), P.Lt: ("lt", "gt"), P.Le: ("le", "ge"),
+               P.Gt: ("gt", "lt"), P.Ge: ("ge", "le")}
+        c = self.condition
+        if self.join_type == "inner" or type(c) not in ops or not all(
+                isinstance(x, BoundRef) for x in c.children):
+            return None
+        left, right = (x.index for x in c.children)
+        if left >= n_left > right:
+            op, b, s = ops[type(c)][0], left - n_left, right
+        elif right >= n_left > left:
+            op, b, s = ops[type(c)][1], right - n_left, left
+        else:
+            return None
+        dts = (self.children[1].output_schema().dtypes[b],
+               self.children[0].output_schema().dtypes[s])
+        scaled = dts[0] != dts[1] and bool(
+            {dtypes.DATE32, dtypes.TIMESTAMP_US} & set(dts))
+        if scaled or not all(map(join_ops.cond_packable, dts)):
+            return None
+        return op, b, s
+
     def _init_conditioned(self, sig: str) -> None:
         """The programs of the residual's evaluation, family ``cjoin``:
-        ``layout`` once a stream batch (the one fetch's sizes), ``prep``
-        (the words and the pairs' slots), then one ``piece`` (semi, anti)
-        or ``pairs`` (inner) program a piece of at most
-        ``join_ops.COND_PIECE_PAIRS`` pairs."""
+        ``layout`` once a stream batch (the one fetch's sizes); then, in
+        the extent form (``_extent_plan``), one ``extent`` program a
+        stream batch; else ``prep`` (the words and the pairs' slots) and
+        one ``piece`` (semi, anti) or ``pairs`` (inner) program a piece of
+        at most ``join_ops.COND_PIECE_PAIRS`` pairs."""
         from spark_rapids_tpu.sql.exprs.core import BoundRef, walk
         from spark_rapids_tpu.utils.kernelcache import expr_signature
         jt = self.join_type
         n_left = len(self.children[0].output_schema().names)
+        extent = self._extent_plan(n_left)
+        self._cform = "pieces" if extent is None else "extent"
         refs = sorted({e.index for e in walk(self.condition)
                        if isinstance(e, BoundRef)})
         # the columns the residual reads, stream (left) side first, and
@@ -235,10 +271,19 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
         self._cstate = {}
 
         def attrs(*_a):
-            return {"type": jt, **self._cstate}
+            return {"type": jt, "form": self._cform, **self._cstate}
         self._clayout = cached_jit(csig + "|layout", lambda: jax.jit(
             lambda b, s, c, bs, bp: join_ops.cond_layout(
                 b, s, c, bs, bp, cb, cs)), attrs)
+        if extent is not None:
+            op, b_col, s_col = extent
+            bkey = self._bkey[0]
+            self._cextent = cached_jit(csig + "|extent", lambda: jax.jit(
+                lambda b, s, c, bs, bp, low, lo, narrow, table:
+                join_ops.cond_extent(b, s, c, bs, bp, low, lo, b_col, s_col,
+                                     op, narrow, bkey, table),
+                static_argnums=(7, 8)), attrs)
+            return
         self._cprep = cached_jit(csig + "|prep", lambda: jax.jit(
             lambda b, s, bp, c, bs, lows, narrow: join_ops.cond_prep(
                 b, s, bp, c, bs, lows, cs, cb, narrow),
@@ -469,7 +514,8 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                     streams = list(sp_local())
                     count_streams(streams)
                     for out in self._conditioned(
-                            build, streams, dense and (dkern, lo_arr)):
+                            build, streams,
+                            dense and (dkern, lo_arr, dense[1])):
                         emitted = True
                         yield out
                     if not emitted:
@@ -636,11 +682,12 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
 
     def _words_plan(self, bounds):
         """(narrow flags, lows) from the residual's value bounds: a column
-        narrows to one word when its valid values span less than 2^32
-        (NULL takes the last word); an all-NULL one too."""
+        narrows to one word when its valid values span less than 2^32 - 2
+        (NULL takes the last word, the extent form's mark the one before);
+        an all-NULL one too."""
         import numpy as np
         pairs = list(zip(bounds[0::2], bounds[1::2]))
-        narrow = tuple(hi < lo or hi - lo < 0xFFFFFFFF for lo, hi in pairs)
+        narrow = tuple(hi < lo or hi - lo < 0xFFFFFFFE for lo, hi in pairs)
         lows = np.asarray([lo if hi >= lo else 0 for lo, hi in pairs] or [0],
                           np.int64)
         return narrow, lows
@@ -660,13 +707,14 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                      ) -> Iterator[DeviceBatch]:
         """The join with a residual: probe every stream batch as the
         plain join does, fetch every batch's sizes in ONE round trip, then
-        evaluate the residual over every key-equal pair in pieces. No
+        decide each stream row from its key's extremes (the extent form)
+        or evaluate the residual over every key-equal pair in pieces. No
         capacity speculation: the pieces need the pair counts."""
         import numpy as np
         jt = self.join_type
         inner = jt == "inner"
         if dense:
-            dkern, lo_arr = dense
+            dkern, lo_arr, table = dense
             raw = [dkern(build, s, lo_arr) for s in streams]
             probes, oks = [r[:3] for r in raw], [r[3] for r in raw]
             del raw
@@ -699,6 +747,21 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
             sz = [int(x) for x in sizes[i]]
             pairs = sz[0]
             narrow, lows = self._words_plan(sz[1:1 + 2 * n_pk])
+            _COND_PAIRS.add(pairs)
+            _COND_INPUT_BYTES.add(4 * pairs)
+            if self._cform == "extent":
+                _COND_EXTENT_ROWS.add(stream.num_rows_hint())
+                passes = zeros
+                if pairs:
+                    # the build's column is the residual's last; the runs
+                    # are the dense table's where this batch probed it
+                    on_table = bool(dense) and bool(oks[i])
+                    passes = self._cextent(
+                        build, stream, counts, bstart, bperm, lows[-1],
+                        lo_arr if on_table else np.int64(0), narrow[-1],
+                        table if on_table else 0)
+                yield self._semi(stream, passes)
+                continue
             at = 1 + 2 * n_pk
             chars = [(sz[at + 2 * j], sz[at + 2 * j + 1])
                      for j in range(len(strs))]
@@ -709,8 +772,6 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                     f"a conditioned join of {pairs} pairs in one stream "
                     "batch (int32 slots)")
             pieces = -(-pairs // pair_cap)
-            _COND_PAIRS.add(pairs)
-            _COND_INPUT_BYTES.add(4 * pairs)
             _COND_PIECES.add(pieces)
             self._cstate = {"pieces": pieces, "pair_cap": pair_cap}
             # chars of a piece's string columns the residual reads, its

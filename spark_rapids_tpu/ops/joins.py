@@ -260,6 +260,19 @@ def join_probe(build: DeviceBatch, stream: DeviceBatch,
     return counts, bstart, bperm
 
 
+def _dense_offsets(build: DeviceBatch, build_key: int, bkv, lo, table_size):
+    """(each build row's table offset, ``key - lo``, or ``table_size`` for
+    a NULL key or one outside the table; whether every valid key is in
+    the table). The dense probe's ``bperm`` is the stable order of these
+    offsets."""
+    boff = build.columns[build_key].data.astype(jnp.int64) - lo
+    in_tbl = (boff >= 0) & (boff < table_size)
+    ok = jnp.all(in_tbl | ~bkv)
+    off_key = jnp.where(in_tbl & bkv, boff,
+                        table_size).astype(jnp.int32)
+    return off_key, ok
+
+
 def join_probe_dense(build: DeviceBatch, stream: DeviceBatch,
                      build_key: int, stream_key: int, lo_arr: jnp.ndarray,
                      table_size: int):
@@ -290,11 +303,7 @@ def join_probe_dense(build: DeviceBatch, stream: DeviceBatch,
     bkv = _key_valid(build, [build_key])
     skv = _key_valid(stream, [stream_key])
     lo = lo_arr.astype(jnp.int64)
-    boff = build.columns[build_key].data.astype(jnp.int64) - lo
-    in_tbl = (boff >= 0) & (boff < table_size)
-    ok = jnp.all(in_tbl | ~bkv)
-    off_key = jnp.where(in_tbl & bkv, boff,
-                        table_size).astype(jnp.int32)
+    off_key, ok = _dense_offsets(build, build_key, bkv, lo, table_size)
     off_sorted, bperm = jax.lax.sort(
         (off_key, jnp.arange(nb, dtype=jnp.int32)), num_keys=1,
         is_stable=True)
@@ -475,12 +484,21 @@ def semi_anti_filter(stream: DeviceBatch, counts: jnp.ndarray,
 # COND_PIECE_PAIRS (join_expand's layout: slot -> stream row by a histogram
 # of the row ends and a prefix sum): a semi or anti join adds each pass to
 # its stream row's count, an inner join emits the pairs that pass.
+#
+# A semi or anti join whose residual is one comparison of a build column
+# with a stream column (cond_extent) enumerates no pair: some key-equal
+# pair passes ``b <op> s`` exactly when the key's valid build values V are
+# not empty and min V < s (<, <=), max V > s (>, >=), or V holds a value
+# other than s (<>), so a stream row is decided by one gather of its key's
+# extremes.
 # ---------------------------------------------------------------------------
 
 # pairs one piece evaluates: the pieces bound the HBM a conditioned join
 # holds, whatever its pair count
 COND_PIECE_PAIRS = 1 << 24
 _NULL_WORD = 0xFFFFFFFF
+# the extent form's word for a key with two distinct valid values
+_MARK_WORD = 0xFFFFFFFE
 
 
 def _copies_chars(c: DeviceColumn) -> bool:
@@ -737,3 +755,82 @@ def cond_piece_pairs(build: DeviceBatch, stream: DeviceBatch, bperm,
                list(stream.schema.dtypes) + list(build.schema.dtypes)),
         cols, pair_cap)
     return filter_batch(out, ok)
+
+
+def cond_extent(build: DeviceBatch, stream: DeviceBatch, counts, bstart,
+                bperm, low, lo_arr, b_col: int, s_col: int, op: str,
+                narrow: bool, build_key: int, table_size: int):
+    """The pass (0 or 1) of every stream row of a semi or anti join whose
+    residual is ``build[b_col] <op> stream[s_col]`` (``op``: ne, lt, le,
+    gt or ge), from the valid build values of its key alone.
+
+    The build's rows of a key hold the slots [bstart, bstart + counts) of
+    the probe's order. Sorting the build by (run, value) keeps every run
+    in its slots and puts its least valid value first (its largest, for
+    > and >=; NULLs last), so one gather at ``bstart`` reads the extreme,
+    and for <> whether the run holds two distinct valid values. The run
+    of a row is the dense probe's table offset where ``table_size`` is
+    given (``lo_arr`` its low key), else the slot order is taken from
+    ``bperm`` and the runs from the stream rows' ranges. ``narrow``: the
+    build column's valid values span less than 2^32 - 2 and ride as
+    ``value - low`` in one word. ``counts``: cond_layout's, zero past the
+    live rows."""
+    nb = build.capacity
+    col = build.columns[b_col]
+    v = col.data.astype(jnp.int64)
+    desc = op in ("gt", "ge")
+    if narrow:
+        w = (v - low).astype(jnp.uint32)
+        keys = [jnp.where(col.validity,
+                          jnp.uint32(_NULL_WORD - 1) - w if desc else w,
+                          jnp.uint32(_NULL_WORD))]
+    else:
+        keys = [(~col.validity).astype(jnp.uint8), ~v if desc else v]
+    hit = counts > 0
+    if table_size:
+        run, _ok = _dense_offsets(build, build_key,
+                                  _key_valid(build, [build_key]),
+                                  lo_arr.astype(jnp.int64), table_size)
+    else:
+        # a run's first slot and the slot after its last start a segment;
+        # slots of keys no stream row probes fall in segments of their own
+        edges = jnp.concatenate([jnp.where(hit, bstart, nb),
+                                 jnp.where(hit, bstart + counts, nb)])
+        run = jnp.cumsum(jnp.zeros((nb + 1,), jnp.int32).at[edges].set(1)[
+            :nb])
+        keys = [k[bperm] for k in keys]
+    srt = jax.lax.sort((run, *keys), num_keys=1 + len(keys))
+    run, key = srt[0], srt[-1]
+    valid = key != jnp.uint32(_NULL_WORD) if narrow else srt[1] == 0
+    multi = jnp.zeros((nb,), jnp.bool_)
+    if op == "ne":
+        # two distinct valid values in a run: some adjacent valid pair of
+        # it differs (values ascend), read at the run's first slot as the
+        # least run at or after it that holds such a pair
+        differs = ((run[1:] == run[:-1]) & valid[1:] & (key[1:] != key[:-1]))
+        at = jnp.concatenate([jnp.where(differs, run[:-1],
+                                        jnp.iinfo(jnp.int32).max),
+                              jnp.full((1,), jnp.iinfo(jnp.int32).max,
+                                       jnp.int32)])
+        multi = jax.lax.cummin(at, reverse=True) == run
+    b = jnp.clip(bstart, 0, nb - 1)
+    if narrow:
+        # one word a slot: the extreme, NULL (no valid value) or the mark
+        # (two distinct values), which no valid word reaches (_words_plan)
+        got = jnp.where(multi, jnp.uint32(_MARK_WORD), key)[b]
+        has = got != jnp.uint32(_NULL_WORD)
+        two = got == jnp.uint32(_MARK_WORD)
+        w = jnp.uint32(_NULL_WORD - 1) - got if desc else got
+        ext = w.astype(jnp.int64) + low
+    else:
+        flags = valid.astype(jnp.uint32) | (multi.astype(jnp.uint32) << 1)
+        got = jnp.concatenate([jax.lax.bitcast_convert_type(key, jnp.uint32),
+                               flags[:, None]], axis=1)[b]
+        has, two = (got[:, 2] & 1) == 1, (got[:, 2] >> 1) == 1
+        k = jax.lax.bitcast_convert_type(got[:, :2], jnp.int64)
+        ext = ~k if desc else k
+    s = stream.columns[s_col]
+    sv = s.data.astype(jnp.int64)
+    hold = {"ne": (ext != sv) | two, "lt": ext < sv, "le": ext <= sv,
+            "gt": ext > sv, "ge": ext >= sv}[op]
+    return (hit & s.validity & has & hold).astype(jnp.int32)

@@ -2,12 +2,17 @@
 condition; TPC-H Q21's EXISTS / NOT EXISTS with ``l2.l_suppkey <>
 l1.l_suppkey``): the split of a condition into keys and residual, the plan,
 and the device operator (``cjoin``) against the CPU operator and a
-brute-force pandas evaluation of every key-equal pair."""
+brute-force pandas evaluation of every key-equal pair, in both its forms:
+the pieces (every pair) and the extent form (a semi or anti join decided
+from each key's least and largest build value)."""
+
+import operator
 
 import numpy as np
 import pandas as pd
 import pytest
 
+from spark_rapids_tpu.exec import tpujoin
 from spark_rapids_tpu.exec.tpujoin import TpuShuffledHashJoinExec
 from spark_rapids_tpu.obs.metrics import REGISTRY
 from spark_rapids_tpu.ops import joins as join_ops
@@ -44,9 +49,9 @@ def _sides(rng, kname, n=300, nb=260, keys=40, scale=1):
     return left, right
 
 
-def _brute(left, right, kname, how, rcol_l, rcol_r):
-    """Every key-equal pair, its residual ``l.rcol_l <> r.rcol_r`` under
-    SQL NULL semantics (NULL is not a pass), then the join type."""
+def _brute(left, right, kname, how, rcol_l, rcol_r, holds=operator.ne):
+    """Every key-equal pair, its residual ``holds(l.rcol_l, r.rcol_r)``
+    under SQL NULL semantics (NULL is not a pass), then the join type."""
     passed = set()
     pairs = []
     for i, (k, x) in enumerate(zip(left["k"], left[rcol_l])):
@@ -55,7 +60,7 @@ def _brute(left, right, kname, how, rcol_l, rcol_r):
         for j, (k2, y) in enumerate(zip(right[kname], right[rcol_r])):
             if pd.isna(k2) or k != k2 or pd.isna(x) or pd.isna(y):
                 continue
-            if x != y:
+            if holds(x, y):
                 passed.add(i)
                 pairs.append((i, j))
     if how == "leftsemi":
@@ -88,6 +93,14 @@ def _cut(monkeypatch, piece):
         monkeypatch.setattr(join_ops, "COND_PIECE_PAIRS", piece)
 
 
+def _on_pieces(how, rl, rr):
+    """``rl <> rr`` as a residual that a semi or anti join evaluates in
+    pieces: a conjunction, which the extent form declines, that passes the
+    same pairs (a NULL ``rr`` fails both)."""
+    ne = F.col(rl) != F.col(rr)
+    return ne if how == "inner" else ne & F.col(rr).isNotNull()
+
+
 @pytest.mark.parametrize("how", ["inner", "leftsemi", "leftanti"])
 @pytest.mark.parametrize("conf", [None, NO_BROADCAST],
                          ids=["broadcast", "shuffled"])
@@ -103,12 +116,13 @@ def test_residual_join_matches_cpu_and_every_pair(session, rng, monkeypatch,
     chosen = _watch_probe(monkeypatch)
     pieces = REGISTRY.counter("join.cond.pieces")
     pairs = REGISTRY.counter("join.cond.pairs")
-    before = (pieces.value, pairs.value)
+    extent = REGISTRY.counter("join.cond.extentRows")
+    before = (pieces.value, pairs.value, extent.value)
 
     def q(s):
         return s.create_dataframe(left, 2).join(
             s.create_dataframe(right, 2),
-            on=(F.col("k") == F.col(kname)) & (F.col("a") != F.col("b")),
+            on=(F.col("k") == F.col(kname)) & _on_pieces(how, "a", "b"),
             how=how)
     got = assert_tpu_and_cpu_equal(q, conf=conf, ignore_order=True)
     assert_frames_equal(got, _brute(left, right, kname, how, "a", "b"),
@@ -123,6 +137,7 @@ def test_residual_join_matches_cpu_and_every_pair(session, rng, monkeypatch,
         assert pieces.value - before[0] >= 20
     else:
         assert pieces.value > before[0]
+    assert extent.value == before[2]
     if how == "leftanti":
         # a row whose every pair reads NULL in the residual is kept
         assert got["a"].isna().any()
@@ -169,13 +184,179 @@ def test_residual_join_edges(session, rng, monkeypatch, how, case):
     else:
         rl, rr = "v", "bv"
 
+    extent = REGISTRY.counter("join.cond.extentRows")
+    before = extent.value
+
     def q(s):
         return s.create_dataframe(left, 2).join(
             s.create_dataframe(right, 1), left_on=["k"], right_on=["k2e"],
-            condition=F.col(rl) != F.col(rr), how=how)
+            condition=_on_pieces(how, rl, rr), how=how)
     got = assert_tpu_and_cpu_equal(q, ignore_order=True)
     assert_frames_equal(got, _brute(left, right, "k2e", how, rl, rr),
                         ignore_order=True)
+    assert extent.value == before
+
+
+def _extent_sides(rng, kname, scale=1):
+    """``_sides`` and two keys more: one whose build values are all NULL,
+    one whose build values are one value that some of its stream rows
+    equal."""
+    left, right = _sides(rng, kname, scale=scale)
+    null_key, one_key = 60 * scale, 61 * scale
+
+    def ints(*xs):
+        return pd.array(xs, dtype="Int64")
+    left = pd.concat([left, pd.DataFrame({
+        "k": ints(null_key, null_key, one_key, one_key, one_key),
+        "a": ints(1, None, 2, 1, 3), "name": "supp_0", "v": 1.0})],
+        ignore_index=True)
+    right = pd.concat([right, pd.DataFrame({
+        kname: ints(null_key, null_key, one_key, one_key),
+        "b": ints(None, None, 2, 2), "bname": "supp_1", "bv": 1.0})],
+        ignore_index=True)
+    return left, right
+
+
+def _residual(op, build_left):
+    """(the residual over stream column ``a`` and build column ``b``, the
+    same comparison of a stream value x and a build value y)."""
+    if build_left:
+        return op(F.col("b"), F.col("a")), lambda x, y: op(y, x)
+    return op(F.col("a"), F.col("b")), op
+
+
+def _extent_counters():
+    return [REGISTRY.counter(f"join.cond.{n}")
+            for n in ("extentRows", "pieces", "pairs")]
+
+
+_COMPARISONS = [(op, build_left)
+                for op in (operator.ne, operator.lt, operator.le, operator.gt,
+                           operator.ge)
+                for build_left in (True, False)]
+
+
+@pytest.mark.parametrize("how", ["leftsemi", "leftanti"])
+@pytest.mark.parametrize("path", ["dense-broadcast", "sort-shuffled"])
+@pytest.mark.parametrize(
+    "op,build_left", _COMPARISONS,
+    ids=[f"{'b' if bl else 's'}-{op.__name__}-{'s' if bl else 'b'}"
+         for op, bl in _COMPARISONS])
+def test_extent_form_matches_cpu_and_every_pair(session, rng, monkeypatch,
+                                                op, build_left, path, how):
+    """Each comparison, with the build column on either side, decided from
+    each key's extremes: the CPU operator's rows and the brute force's,
+    NULLs on both sides, a key with only NULL build values and a run of one
+    value equal to some stream values; the pairs counted as the pieces
+    count them, and no piece run."""
+    probe, placing = path.split("-")
+    kname = f"k3_{probe}"
+    left, right = _extent_sides(rng, kname,
+                                scale=1 if probe == "dense" else WIDE)
+    chosen = _watch_probe(monkeypatch)
+    cond, holds = _residual(op, build_left)
+    counters = _extent_counters()
+    before = [c.value for c in counters]
+
+    def q(s):
+        return s.create_dataframe(left, 2).join(
+            s.create_dataframe(right, 2),
+            on=(F.col("k") == F.col(kname)) & cond, how=how)
+    got = assert_tpu_and_cpu_equal(
+        q, conf=NO_BROADCAST if placing == "shuffled" else None)
+    assert_frames_equal(got, _brute(left, right, kname, how, "a", "b",
+                                    holds), ignore_order=True)
+    assert chosen and all(c == (probe == "dense") for c in chosen)
+    extent, pieces, pairs = (c.value - b for c, b in zip(counters, before))
+    assert extent > 0 and pieces == 0
+    assert pairs == sum(int((right[kname] == k).sum())
+                        for k in left["k"].dropna())
+
+
+@pytest.mark.parametrize("how", ["leftsemi", "leftanti"])
+@pytest.mark.parametrize("conf", [None, NO_BROADCAST],
+                         ids=["broadcast", "shuffled"])
+@pytest.mark.parametrize("case", ["wide-ne", "wide-ge", "empty-build",
+                                  "no-match", "bounds-miss"])
+def test_extent_form_edges(session, rng, monkeypatch, how, conf, case):
+    """Values spanning more than 2^32 (two words), an empty build, no
+    key-equal pair, and advisory bounds that miss a build key, so the dense
+    probe falls back to the sort probe and its runs."""
+    kname = "k3e"
+    left, right = _extent_sides(rng, kname)
+    op, build_left = {"wide-ge": (operator.ge, True),
+                      "no-match": (operator.le, False),
+                      "bounds-miss": (operator.lt, True)}.get(
+                          case, (operator.ne, False))
+    if case.startswith("wide"):
+        left = left.assign(a=left["a"] * WIDE - 7)
+        right = right.assign(b=right["b"] * WIDE - 7)
+    elif case == "empty-build":
+        right = right.iloc[:0]
+    elif case == "no-match":
+        right = right.assign(k3e=right["k3e"] + 1000)
+    planned = []
+    if case == "bounds-miss":
+        orig = TpuShuffledHashJoinExec._dense_plan
+
+        def missing(self, ctx, schema):
+            got = orig(self, ctx, schema)
+            planned.append(got)
+            return got and (got[0] + 3, got[1])
+        monkeypatch.setattr(TpuShuffledHashJoinExec, "_dense_plan", missing)
+    cond, holds = _residual(op, build_left)
+    counters = _extent_counters()
+    before = [c.value for c in counters]
+
+    def q(s):
+        return s.create_dataframe(left, 2).join(
+            s.create_dataframe(right, 1), left_on=["k"], right_on=[kname],
+            condition=cond, how=how)
+    got = assert_tpu_and_cpu_equal(q, conf=conf, ignore_order=True)
+    assert_frames_equal(got, _brute(left, right, kname, how, "a", "b",
+                                    holds), ignore_order=True)
+    extent, pieces, _pairs = (c.value - b for c, b in zip(counters, before))
+    assert extent > 0 and pieces == 0
+    if case == "bounds-miss":
+        assert planned and all(planned)
+
+
+@pytest.mark.parametrize("how,residual", [
+    ("leftsemi", "conjunction"), ("leftanti", "expression"),
+    ("leftsemi", "float"), ("leftanti", "string"),
+    ("inner", "ne"), ("inner", "lt")])
+def test_declined_residuals_keep_the_pieces(session, rng, monkeypatch, how,
+                                            residual):
+    """A semi or anti join whose residual is not one comparison of two
+    integer columns, and every inner join, take the pieces' programs
+    (``layout``, ``prep``, then ``piece`` or ``pairs``) and no extent."""
+    asked = []
+    orig = tpujoin.cached_jit
+
+    def spy(sig, *a, **kw):
+        asked.append(sig)
+        return orig(sig, *a, **kw)
+    monkeypatch.setattr(tpujoin, "cached_jit", spy)
+    left, right = _sides(rng, "k3d")
+    a, b = F.col("a"), F.col("b")
+    cond = {"conjunction": (a != b) & (b >= 1), "expression": a + 1 != b,
+            "float": F.col("v") != F.col("bv"),
+            "string": F.col("name") < F.col("bname"), "ne": a != b,
+            "lt": b < a}[residual]
+    counters = _extent_counters()
+    before = [c.value for c in counters]
+
+    def q(s):
+        return s.create_dataframe(left, 2).join(
+            s.create_dataframe(right, 1), left_on=["k"], right_on=["k3d"],
+            condition=cond, how=how)
+    assert_tpu_and_cpu_equal(q, ignore_order=True)
+    forms = {sig.rsplit("|", 1)[1] for sig in asked
+             if sig.startswith("cjoin|")}
+    assert forms == {"layout", "prep",
+                     "pairs" if how == "inner" else "piece"}
+    extent, pieces, _pairs = (c.value - b for c, b in zip(counters, before))
+    assert extent == 0 and pieces > 0
 
 
 def test_condition_splits_into_keys_and_residual(session, rng):
@@ -262,21 +443,20 @@ def _bench_module(name):
     return mod
 
 
-def test_q21_as_the_specification_writes_it(session, monkeypatch, tmp_path):
+def test_q21_as_the_specification_writes_it(session, tmp_path):
     """The benchmark's Q21 (the two existence subqueries as a leftsemi and
     a leftanti join with the residual ``<>``) on the engine, every
     operator on the TPU, equals its plain pandas reference, on the
     benchmark's own generator and Parquet files at SF 0.01 (lines an
     order a Poisson draw of mean 4; ``s_name`` uploads as a slab), where
-    EXISTS and NOT EXISTS each keep and drop late lines; the pieces are
-    cut small so their pairs cross them."""
+    EXISTS and NOT EXISTS each keep and drop late lines; both joins take
+    the extent form and no piece runs."""
     import os
 
     import pyarrow.parquet as pq
     data = _bench_module("data")
     q21 = _bench_module("queries/q21")
     from match import results_match
-    _cut(monkeypatch, 1 << 12)
     root = data.ensure_tables(str(tmp_path), 0.01, 2**31 + 21,
                               list(q21.READS))[0]
     frames = {t: pq.read_table(os.path.join(root, f"{t}.parquet"),
@@ -294,7 +474,8 @@ def test_q21_as_the_specification_writes_it(session, monkeypatch, tmp_path):
     assert len(want) > 0
     session.set_conf("spark.rapids.sql.test.enabled", True)
     pieces = REGISTRY.counter("join.cond.pieces")
-    before = pieces.value
+    extent = REGISTRY.counter("join.cond.extentRows")
+    before = (pieces.value, extent.value)
     tables = {t: session.read.parquet(os.path.join(root, f"{t}.parquet"))
               for t in frames}
     got = q21.build(session, tables).collect()
@@ -303,4 +484,4 @@ def test_q21_as_the_specification_writes_it(session, monkeypatch, tmp_path):
              for n in session.last_plan.walk()
              if isinstance(n, TpuShuffledHashJoinExec)]
     assert ("leftsemi", True) in kinds and ("leftanti", True) in kinds
-    assert pieces.value - before > 2
+    assert pieces.value == before[0] and extent.value > before[1]
