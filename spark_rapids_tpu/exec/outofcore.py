@@ -357,11 +357,13 @@ def _join_bucket(ctx, exec_, build: DeviceBatch,
 
     NB: this is deliberately the SIMPLIFIED twin of
     TpuShuffledHashJoinExec's main emission loop (exec/tpujoin.py run():
-    batched one-fetch totals, capacity speculation, dense probe
-    selection). Changes to join emission semantics there (new join
-    types, size/cap layout of _totals, _expand's contract) must be
-    mirrored here — the out-of-core tests diff both paths against the
-    oracle, which is the drift tripwire."""
+    the stream taken in rounds within the collapse bound, one totals fetch
+    a round, capacity speculation keyed by the round, dense probe
+    selection); here every stream batch is its own round, since a bucket
+    fits the budget by construction. Changes to join emission semantics
+    there (new join types, size/cap layout of _totals, _expand's
+    contract) must be mirrored here — the out-of-core tests diff both
+    paths against the oracle, which is the drift tripwire."""
     growth = ctx.conf.capacity_growth
     jt = exec_.join_type
     matched_acc = None

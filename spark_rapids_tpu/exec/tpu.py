@@ -117,6 +117,9 @@ def _concat_device(batches: List[DeviceBatch], schema: Schema,
 _COLLAPSE_BYTES = REGISTRY.counter("exchange.collapse.bytes")
 _COLLAPSE_BATCHES = REGISTRY.counter("exchange.collapse.batches")
 _COLLAPSE_COMPACTED = REGISTRY.counter("exchange.collapse.compactedBatches")
+# the batches collapses emitted: 1 a collapse within its bound, one a
+# piece where the bound cut it (_collapse_bound_bytes)
+_COLLAPSE_PIECES = REGISTRY.counter("exchange.collapse.pieces")
 _MERGE_ROWS = REGISTRY.counter("agg.merge.inputRows")
 _MERGE_BYTES = REGISTRY.counter("agg.merge.inputBytes")
 _PASSTHROUGH_ROWS = REGISTRY.counter("agg.partial.passthroughRows")
@@ -144,22 +147,42 @@ def _row_bytes(schema: Schema) -> int:
                for dt in schema.dtypes)
 
 
+def _collapse_bound_bytes() -> int:
+    """The most one batch of a local exchange collapse may hold, in bytes
+    at ``_row_bytes`` a slot of its output capacity: a quarter of the
+    metered HBM budget. A batch at the bound lives beside the batches it is
+    concatenated from (as many bytes again) and, under a join, beside the
+    build, the probe's sort over build and stream, and the probe's
+    (counts, starts, perm) of 12 bytes a stream slot; a quarter leaves
+    room for all of them. On a v5e (15.2 GB budget, 3.8 GB bound) that
+    lets every collapse of a 2^26-slot batch of at most 56 bytes a row
+    through whole (lineitem's four columns in Q5 at SF10 are 36), and cuts
+    Q5's 180M lineitem rows at SF30 into 2^26-slot pieces."""
+    from spark_rapids_tpu.memory.device import TpuDeviceManager
+    dm = TpuDeviceManager.current()
+    return dm.hbm_budget // 4 if dm is not None else 1 << 62
+
+
 def _collapse_concat(batches: List[DeviceBatch], schema: Schema,
                      growth: float, keep_masks=None,
-                     compacted: int = 0) -> DeviceBatch:
-    """The one concat of a local exchange collapse, counted by what the host
-    knows without a sync: ``exchange.collapse.batches`` input batches and
+                     compacted: int = 0, pieces: int = 1) -> DeviceBatch:
+    """One concat of a local exchange collapse, counted by what the host
+    knows without a sync: ``exchange.collapse.batches`` input batches,
     ``exchange.collapse.bytes`` of device storage at their capacity
-    (padding included, rows a mask will drop included). The span
-    ``exchange.collapse`` covers the concat's dispatch alone (``compacted``:
-    how many of its batches a claimed filter had already compacted,
-    _drain_claimed); the drain of the children above it belongs to the
-    operator spans."""
+    (padding included, rows a mask will drop included) and one
+    ``exchange.collapse.pieces``. The span ``exchange.collapse`` covers the
+    concat's dispatch alone (``compacted``: how many of its batches a
+    claimed filter had already compacted, _Drain; ``pieces``: the batches
+    the collapse has emitted with this one, 1 where its bound did not cut
+    it); the drain of the children above it belongs to the operator
+    spans."""
     nbytes = sum(b.device_memory_size() for b in batches)
     _COLLAPSE_BATCHES.add(len(batches))
     _COLLAPSE_BYTES.add(nbytes)
+    _COLLAPSE_PIECES.add(1)
     with TRACER.span("exchange.collapse", batches=len(batches),
-                     bytes=nbytes, compacted=compacted):
+                     bytes=nbytes, compacted=compacted, pieces=pieces,
+                     bound_bytes=_collapse_bound_bytes()):
         return _concat_device(batches, schema, growth, keep_masks)
 
 
@@ -220,31 +243,60 @@ def _fused_filter_source(node: PhysicalPlan, ctx: ExecContext):
     return node, None
 
 
+class _Drain:
+    """A collapse's child partitions, pulled in order a group of batches
+    at a time (``take``). A claimed filter (_fused_filter_source) runs on
+    every batch as it arrives, so its program is queued on the device
+    while the host decodes the next split."""
+
+    def __init__(self, parts: Sequence[Partition], claimed):
+        self.claimed = claimed
+        self._source = (claimed(b) if claimed is not None else (b, None)
+                        for p in parts for b in p())
+        # the (batch, mask) that would have carried the last group past
+        # its bound: the first of the next
+        self._held = None
+        self.exhausted = False
+
+    def take(self, full=None):
+        """(batches, keep masks, compacted) of the next group: every batch
+        left, or, where ``full(batches, batch)`` says ``batch`` would carry
+        the group past its bound, the batches before it (one at the
+        least). ``compacted`` counts the batches that came back compacted
+        (``exchange.collapse.compactedBatches``); where that is all of
+        them, or nothing is claimed, the masks are None and the concat is
+        the unmasked one."""
+        batches, masks = [], []
+        # the serial section before the collapse's consumer can start: it
+        # holds the children's pulls, as the operator span does
+        with TRACER.span("exchange.drain",
+                         claimed=self.claimed is not None) as sp:
+            while True:
+                got = (self._held if self._held is not None
+                       else next(self._source, None))
+                self._held = None
+                if got is None:
+                    self.exhausted = True
+                    break
+                if batches and full is not None and full(batches, got[0]):
+                    self._held = got
+                    break
+                batches.append(got[0])
+                masks.append(got[1])
+            compacted = (sum(m is None for m in masks)
+                         if self.claimed is not None else 0)
+            if sp is not None:
+                sp.set(batches=len(batches), compacted=compacted)
+        _COLLAPSE_COMPACTED.add(compacted)
+        if self.claimed is None or compacted == len(masks):
+            masks = None
+        return batches, masks, compacted
+
+
 def _drain_claimed(parts: Sequence[Partition], claimed):
-    """(batches, keep masks, compacted) of a collapse's child partitions,
-    in order. A claimed filter (_fused_filter_source) runs on every batch
-    as it arrives, so its program is queued on the device while the host
-    decodes the next split. ``compacted`` counts the batches that came back
-    compacted (``exchange.collapse.compactedBatches``); where that is all
-    of them, or nothing is claimed, the masks are None and the concat is
-    the unmasked one."""
-    batches = []
-    masks = []
-    # the serial section before the collapse's consumer can start: it
-    # holds the children's pulls, as the operator span does
-    with TRACER.span("exchange.drain",
-                     claimed=claimed is not None) as sp:
-        for p in parts:
-            for b in p():
-                if claimed is not None:
-                    b, mask = claimed(b)
-                    masks.append(mask)
-                batches.append(b)
-        compacted = sum(m is None for m in masks)
-        if sp is not None:
-            sp.set(batches=len(batches), compacted=compacted)
-    _COLLAPSE_COMPACTED.add(compacted)
-    return batches, (masks if compacted < len(masks) else None), compacted
+    """(batches, keep masks, compacted) of every batch of a collapse's
+    child partitions, in order (_Drain.take)."""
+    return _Drain(parts, claimed).take()
 
 
 def _select_view(batch: DeviceBatch, out_sel) -> DeviceBatch:
@@ -1517,12 +1569,19 @@ class TpuShuffleExchangeExec(TpuExec):
             # sync-free collapse: when no aggregate feeds this exchange,
             # the producer batches are NOT systematically over-padded, so
             # the count-fetch sync + per-batch shrink gathers cost more
-            # than they save — ONE capacity-based concat (zero round
-            # trips) hands the consumer a single big batch, keeping joins
-            # and aggregates on one wide kernel instead of per-fragment
-            # dispatches. Aggregate producers keep the shrink (their
-            # outputs carry pre-agg padding worth removing before the
-            # merge/sort).
+            # than they save — capacity-based concats (zero round trips)
+            # hand the consumer one big batch, keeping joins and
+            # aggregates on one wide kernel instead of per-fragment
+            # dispatches. Where one batch would pass the bound
+            # (_collapse_bound_bytes), the drain is cut into consecutive
+            # groups and each is concatenated as the drain reaches its
+            # end: the consumer gets pieces within the bound, and the
+            # drain never holds more than one group's inputs beside them.
+            # A join's stream takes the pieces in rounds (exec/tpujoin.py
+            # _rounds); a consumer that needs one batch (a build, an
+            # aggregate's merge, a sort) concatenates them again.
+            # Aggregate producers keep the shrink (their outputs carry
+            # pre-agg padding worth removing before the merge/sort).
             if not self._padded_producer(self.children[0]):
                 # a deterministic Filter directly below is claimed and run
                 # a batch at a time under the drain (_fused_filter_source)
@@ -1532,13 +1591,27 @@ class TpuShuffleExchangeExec(TpuExec):
                                if claimed is not None else child_parts)
 
                 def nosync_concat() -> Iterator[DeviceBatch]:
-                    batches, masks, compacted = _drain_claimed(
-                        fused_parts, claimed)
-                    if not batches:
-                        yield DeviceBatch.empty(schema)
-                        return
-                    yield _collapse_concat(batches, schema, growth, masks,
-                                           compacted)
+                    drain = _Drain(fused_parts, claimed)
+                    bound, row = _collapse_bound_bytes(), _row_bytes(schema)
+
+                    def full(batches, batch):
+                        cap = batch.capacity + sum(b.capacity
+                                                   for b in batches)
+                        return bucket_capacity(cap, growth) * row > bound
+                    pieces = 0
+                    while not drain.exhausted:
+                        batches, masks, compacted = drain.take(full)
+                        if not batches:  # the children had no batch
+                            yield DeviceBatch.empty(schema)
+                            return
+                        pieces += 1
+                        piece = _collapse_concat(batches, schema, growth,
+                                                 masks, compacted, pieces)
+                        # the inputs go before the consumer takes the
+                        # piece, and the piece before the next group
+                        del batches, masks
+                        yield piece
+                        del piece
                 return [nosync_concat]
 
             def single() -> Iterator[DeviceBatch]:

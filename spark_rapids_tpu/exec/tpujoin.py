@@ -38,6 +38,57 @@ _COND_PIECES = REGISTRY.counter("join.cond.pieces")
 _COND_INPUT_BYTES = REGISTRY.counter("join.cond.inputBytes")
 # stream rows the extent form decided (rows the host knows without a sync)
 _COND_EXTENT_ROWS = REGISTRY.counter("join.cond.extentRows")
+# stream rows the sort probe took (rows the host knows without a sync); a
+# join that probes the dense table adds 0, so the count is never missing
+_SORT_ROWS = REGISTRY.counter("join.probe.sortRows")
+
+
+def _rounds(batches, row_bytes: int):
+    """A join's stream in rounds, each probed and its sizes fetched at
+    once: a round takes consecutive batches until its bytes (capacity x
+    ``row_bytes``) and those of one more batch as large as its largest
+    would pass the collapse bound (exec/tpu._collapse_bound_bytes). The
+    next batch is pulled after the round is emitted, so a stream a
+    collapse cut into pieces is not drained ahead of its probe; a stream
+    of one batch, or whose bytes and its largest batch's stay within the
+    bound, is one round."""
+    from spark_rapids_tpu.exec.tpu import _collapse_bound_bytes
+    bound = _collapse_bound_bytes()
+    rnd, size, top = [], 0, 0
+    for b in batches:
+        nbytes = b.capacity * row_bytes
+        rnd.append(b)
+        size, top = size + nbytes, max(top, nbytes)
+        del b  # the round's batches are the consumer's to drop
+        if size + top > bound:
+            yield rnd
+            rnd, size, top = [], 0, 0
+    if rnd:
+        yield rnd
+
+
+def _pull_build(batches, row_bytes: int, swappable: bool):
+    """(the build's batches, None); or, where ``swappable`` and the bytes
+    of the batches pulled (capacity x ``row_bytes``) pass the collapse
+    bound, (those batches, an iterator of the rest): the join then streams
+    them (TpuShuffledHashJoinExec._swapped)."""
+    from spark_rapids_tpu.exec.tpu import _collapse_bound_bytes
+    bound = _collapse_bound_bytes()
+    it = iter(batches)
+    held, size = [], 0
+    for b in it:
+        held.append(b)
+        size += b.capacity * row_bytes
+        if swappable and size > bound:
+            return held, it
+    return held, None
+
+
+def _popping(held: list, rest):
+    """``held`` then ``rest``, each batch let go of as it is handed on."""
+    while held:
+        yield held.pop(0)
+    yield from rest
 
 
 def _start_host_copies(arrays) -> None:
@@ -150,44 +201,25 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
         # side the same way, GpuHashJoin.scala:60-76)
         self._stream_is_left = join_type != "right"
         jt = join_type
-        cross = jt == "cross"
         skey = tuple(self.left_keys if self._stream_is_left
                      else self.right_keys)
         bkey = tuple(self.right_keys if self._stream_is_left
                      else self.left_keys)
         sig = f"join|{jt}|{skey}|{bkey}|x{int(exact_long_strings)}"
-        self._sig = sig
         self._skey, self._bkey = skey, bkey
+        # the probe, totals and expand with the sides as planned, and
+        # (built on first use, _swapped) with an inner join's sides
+        # exchanged
+        self._planned = self._oriented(skey, bkey, not self._stream_is_left,
+                                       sig)
+        self._probe = self._planned.probe
+        self._totals = self._planned.totals
+        self._expand = self._planned.expand
+        self._swap = None
         # what every ``dispatch.join`` span of this join says of itself
 
         def span_attrs(*_a):
             return {"type": jt}
-        self._span_attrs = span_attrs
-        self._probe = cached_jit(sig + "|probe", lambda: jax.jit(
-            lambda b, s: join_ops.join_probe(
-                b, s, bkey, skey, cross=cross,
-                exact_long_strings=exact_long_strings)), span_attrs)
-        outer = jt in ("left", "right", "full")
-        swap = not self._stream_is_left
-
-        def expand(build, stream, counts, bstart, bperm, out_cap, s_caps,
-                   b_caps):
-            adj = (join_ops.outer_adjusted_counts(stream, counts)
-                   if outer else counts)
-            return join_ops.join_expand(build, stream, counts, adj, bstart,
-                                        bperm, out_cap, swap, s_caps, b_caps)
-        self._expand = cached_jit(
-            sig + "|expand",
-            lambda: jax.jit(expand, static_argnums=(5, 6, 7)),
-            lambda *a: {"type": jt, "out_cap": a[5]})
-
-        def totals(build, stream, counts, bstart, bperm):
-            adj = (join_ops.outer_adjusted_counts(stream, counts)
-                   if outer else counts)
-            return join_ops.expand_totals(build, stream, counts, adj, bperm,
-                                          bstart)
-        self._totals = cached_jit(sig + "|totals", lambda: jax.jit(totals),
-                                  span_attrs)
         if jt == "full":
             # a lambda of its own: cached_jit names the jitted function
             # after the family, and the module's function keeps its name
@@ -203,6 +235,62 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                     s, c, anti=jt == "leftanti")), span_attrs)
         if condition is not None:
             self._init_conditioned(sig)
+
+    def _oriented(self, skey, bkey, swap: bool, sig: str):
+        """The probe, totals and expand of this join streaming the side
+        whose keys are ``skey`` against a build keyed by ``bkey``;
+        ``swap``: the build is the left side, so the expand puts its
+        columns first."""
+        from types import SimpleNamespace
+        jt = self.join_type
+        cross = jt == "cross"
+        exact = self.exact_long_strings
+        outer = jt in ("left", "right", "full")
+
+        def span_attrs(*_a):
+            return {"type": jt}
+        probe = cached_jit(sig + "|probe", lambda: jax.jit(
+            lambda b, s: join_ops.join_probe(
+                b, s, bkey, skey, cross=cross, exact_long_strings=exact)),
+            lambda *_a: {"type": jt, "form": "sort"})
+
+        def sort_probe(build, stream):
+            _SORT_ROWS.add(stream.num_rows_hint())
+            return probe(build, stream)
+
+        def expand(build, stream, counts, bstart, bperm, out_cap, s_caps,
+                   b_caps):
+            adj = (join_ops.outer_adjusted_counts(stream, counts)
+                   if outer else counts)
+            return join_ops.join_expand(build, stream, counts, adj, bstart,
+                                        bperm, out_cap, swap, s_caps, b_caps)
+
+        def totals(build, stream, counts, bstart, bperm):
+            adj = (join_ops.outer_adjusted_counts(stream, counts)
+                   if outer else counts)
+            return join_ops.expand_totals(build, stream, counts, adj, bperm,
+                                          bstart)
+        return SimpleNamespace(
+            sig=sig, skey=skey, bkey=bkey, probe=sort_probe,
+            expand=cached_jit(
+                sig + "|expand",
+                lambda: jax.jit(expand, static_argnums=(5, 6, 7)),
+                lambda *a: {"type": jt, "out_cap": a[5]}),
+            totals=cached_jit(sig + "|totals", lambda: jax.jit(totals),
+                              span_attrs))
+
+    def _swapped(self):
+        """The orientation of an unconditioned inner join whose planned
+        build, the right side, passed the collapse bound
+        (exec/tpu._collapse_bound_bytes): the right side streams, in the
+        pieces its collapse cut it into, against a build of the left, and
+        the output keeps the left side's columns first."""
+        if self._swap is None:
+            sk, bk = tuple(self.right_keys), tuple(self.left_keys)
+            self._swap = self._oriented(
+                sk, bk, True, f"join|{self.join_type}|{sk}|{bk}"
+                f"|x{int(self.exact_long_strings)}|swap")
+        return self._swap
 
     def _extent_plan(self, n_left: int):
         """(op, build column, stream column) where the extent form decides
@@ -339,13 +427,15 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
     # hold of HBM: 2 GB (s32 counts and starts, and their stack).
     _DENSE_MAX_RANGE = 1 << 27
 
-    def _dense_plan(self, ctx, build_schema):
-        """(lo, table_size) when the dense path applies, else None."""
-        if self.join_type == "cross" or len(self._bkey) != 1:
+    def _dense_plan(self, ctx, build_schema, k=None):
+        """(lo, table_size) when the dense path applies to orientation
+        ``k`` (_oriented; the planned one where None), else None."""
+        k = k or self._planned
+        if self.join_type == "cross" or len(k.bkey) != 1:
             return None
         if ctx.session is None:
             return None
-        bk = self._bkey[0]
+        bk = k.bkey[0]
         dt = build_schema.dtypes[bk]
         if dt.is_string or not jnp.issubdtype(
                 jnp.dtype(dt.np_dtype), jnp.integer):
@@ -367,13 +457,14 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
             table_size <<= 1
         return lo, bucket_dim(table_size)
 
-    def _dense_kernel(self, table_size: int):
-        bk, sk = self._bkey[0], self._skey[0]
+    def _dense_kernel(self, k, table_size: int):
+        bk, sk = k.bkey[0], k.skey[0]
         return cached_jit(
-            f"{self._sig}|dense{table_size}",
+            f"{k.sig}|dense{table_size}",
             lambda: jax.jit(
                 lambda b, s, lo: join_ops.join_probe_dense(
-                    b, s, bk, sk, lo, table_size)), self._span_attrs)
+                    b, s, bk, sk, lo, table_size)),
+            lambda *_a: {"type": self.join_type, "form": "dense"})
 
     def partitions(self, ctx: ExecContext) -> List[Partition]:
         si, bi = self._sides()
@@ -427,10 +518,10 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
         stream_row_bytes = _row_bytes(self.children[si].output_schema())
         build_row_bytes = _row_bytes(build_schema)
 
-        def count_streams(streams):
+        def count_streams(streams, row_bytes=stream_row_bytes):
             rows = sum(s.num_rows_hint() for s in streams)
             stream_rows.add(rows)
-            _INPUT_BYTES.add(rows * stream_row_bytes)
+            _INPUT_BYTES.add(rows * row_bytes)
 
         dense = None
 
@@ -499,86 +590,113 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                         return
                     bp_local = lambda bl=bpre: iter(bl)  # noqa: E731
                     sp_local = lambda sl=spre: iter(sl)  # noqa: E731
-                build = _concat_device(list(bp_local()), build_schema,
-                                       growth, coarse=True)
-                _INPUT_BYTES.add(build.num_rows_hint() * build_row_bytes)
+                # an unconditioned inner join whose build passes the
+                # collapse bound streams that side instead, in the pieces
+                # its collapse cut it into, against a build of the other
+                # (_swapped); any other join concatenates its build whole
+                held, rest = _pull_build(
+                    bp_local(), build_row_bytes,
+                    jt == "inner" and self.condition is None)
+                nonlocal dense
+                if rest is None:
+                    k, s_row, b_row = (self._planned, stream_row_bytes,
+                                       build_row_bytes)
+                    build = _concat_device(held, build_schema, growth,
+                                           coarse=True)
+                    stream_src = sp_local()
+                    if dense is None:
+                        dense = self._dense_plan(ctx,
+                                                 build_schema) or False
+                    dplan = dense
+                else:
+                    k, s_row, b_row = (self._swapped(), build_row_bytes,
+                                       stream_row_bytes)
+                    left_schema = self.children[si].output_schema()
+                    build = _concat_device(list(sp_local()), left_schema,
+                                           growth, coarse=True)
+                    stream_src = _popping(held, rest)
+                    dplan = self._dense_plan(ctx, left_schema, k) or False
+                del held
+                _INPUT_BYTES.add(build.num_rows_hint() * b_row)
                 matched_acc = None
                 emitted = False
-                nonlocal dense
-                if dense is None:
-                    dense = self._dense_plan(ctx, build_schema) or False
-                if dense:
-                    lo_arr = jnp.asarray(dense[0], jnp.int64)
-                    dkern = self._dense_kernel(dense[1])
+                if dplan:
+                    lo_arr = jnp.asarray(dplan[0], jnp.int64)
+                    dkern = self._dense_kernel(k, dplan[1])
+                else:
+                    _SORT_ROWS.add(0)
                 if self.condition is not None:
-                    streams = list(sp_local())
-                    count_streams(streams)
-                    for out in self._conditioned(
-                            build, streams,
-                            dense and (dkern, lo_arr, dense[1])):
-                        emitted = True
-                        yield out
+                    for streams in _rounds(stream_src, s_row):
+                        count_streams(streams)
+                        for out in self._conditioned(
+                                build, streams,
+                                dplan and (dkern, lo_arr, dplan[1])):
+                            emitted = True
+                            yield out
                     if not emitted:
                         yield DeviceBatch.empty(self.output_schema())
                     return
                 key = spec_key(pidx)
+                if key is not None and rest is not None:
+                    key += "|swap"
                 cache = (ctx.session.capacity_cache
                          if key is not None else None)
-                if jt in ("leftsemi", "leftanti"):
-                    if dense:
-                        # probe every batch first, ONE ok-flag fetch for
-                        # all of them (a per-batch device_get would block
-                        # on a full round trip each)
-                        streams = list(sp_local())
-                        count_streams(streams)
-                        raw = [dkern(build, s, lo_arr) for s in streams]
-                        oks_d = [r[3] for r in raw]
-                        entry = cache.get(key) if cache is not None else None
-                        if (entry is not None and entry.get("dense_ok")
-                                and entry.get("n") == len(streams)):
-                            # speculate: last run's advisory bounds held;
-                            # defer the ok-flag check to query end
-                            _start_host_copies(oks_d)
-                            ctx.session.capacity_spec_hits += 1
-                            ctx.spec_pending.append((key, [], [], oks_d, None))
-                            for stream, r in zip(streams, raw):
-                                emitted = True
-                                yield self._semi(stream, r[0])
-                        else:
-                            oks = jax.device_get(oks_d)
-                            if cache is not None:
-                                cache[key] = {"dense_ok": all(map(bool, oks)),
-                                              "n": len(streams)}
-                            for stream, r, ok in zip(streams, raw, oks):
-                                emitted = True
-                                counts = (r[0] if bool(ok)
-                                          else self._probe(build, stream)[0])
-                                yield self._semi(stream, counts)
-                    else:
-                        for stream in sp_local():
-                            emitted = True
-                            count_streams([stream])
-                            yield self._semi(stream,
-                                             self._probe(build, stream)[0])
-                else:
-                    # probe EVERY stream batch first (dispatch is async and
-                    # nearly free), then fetch all expansion totals in ONE
-                    # device->host round trip — a per-batch fetch would
-                    # block dispatch on every batch.
-                    # NB: exec/outofcore.py _join_bucket is this loop's
-                    # simplified per-bucket twin — semantic changes to the
-                    # probe/totals/expand contract must be mirrored there
-                    streams = list(sp_local())
+
+                def round_key(r: int) -> Optional[str]:
+                    # the speculation cache's n and sizes are a round's
+                    return key if r == 0 or key is None else f"{key}|r{r}"
+
+                def semi_round(streams, key):
+                    # probe every batch first, ONE ok-flag fetch for all
+                    # of them (a per-batch device_get would block on a
+                    # full round trip each)
+                    nonlocal emitted
                     count_streams(streams)
+                    raw = [dkern(build, s, lo_arr) for s in streams]
+                    oks_d = [r[3] for r in raw]
+                    entry = cache.get(key) if cache is not None else None
+                    if (entry is not None and entry.get("dense_ok")
+                            and entry.get("n") == len(streams)):
+                        # speculate: last run's advisory bounds held;
+                        # defer the ok-flag check to query end
+                        _start_host_copies(oks_d)
+                        ctx.session.capacity_spec_hits += 1
+                        ctx.spec_pending.append((key, [], [], oks_d, None))
+                        oks = [True] * len(streams)
+                    else:
+                        oks = jax.device_get(oks_d)
+                        if cache is not None:
+                            cache[key] = {"dense_ok": all(map(bool, oks)),
+                                          "n": len(streams)}
+                    for i, ok in enumerate(oks):
+                        stream, counts = streams[i], raw[i][0]
+                        streams[i] = raw[i] = None
+                        if not bool(ok):
+                            counts = self._probe(build, stream)[0]
+                        emitted = True
+                        yield self._semi(stream, counts)
+
+                def expand_round(streams, key):
+                    # probe EVERY stream batch of the round first (dispatch
+                    # is async and nearly free), then fetch all expansion
+                    # totals in ONE device->host round trip — a per-batch
+                    # fetch would block dispatch on every batch.
+                    # NB: exec/outofcore.py _join_bucket is this loop's
+                    # simplified per-bucket twin (one fetch a batch, no
+                    # rounds: a bucket is within the budget by
+                    # construction) — semantic changes to the
+                    # probe/totals/expand contract must be mirrored there
+                    nonlocal emitted, matched_acc
+                    count_streams(streams, s_row)
                     oks_d = []
-                    if dense:
+                    if dplan:
                         raw = [dkern(build, s, lo_arr) for s in streams]
                         probes = [r[:3] for r in raw]
                         oks_d = [r[3] for r in raw]
                         del raw  # or probes[i]=None below frees nothing
                     else:
-                        probes = [self._probe(build, s) for s in streams]
-                    totals_d = [self._totals(build, s, *pr)
+                        probes = [k.probe(build, s) for s in streams]
+                    totals_d = [k.totals(build, s, *pr)
                                 for s, pr in zip(streams, probes)]
                     entry = cache.get(key) if cache is not None else None
                     spec_hit = (
@@ -595,7 +713,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                         caps_used: list = []
                         ctx.spec_pending.append(
                             (key, totals_d, caps_used, oks_d, None))
-                    elif dense:
+                    elif dplan:
                         fetch = jax.device_get(
                             list(zip(totals_d, oks_d)))
                         sizes_all = []
@@ -607,10 +725,10 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                             all_ok = False
                             # advisory bounds were wrong for this build:
                             # exact sort probe, one extra fetch (rare)
-                            pr = self._probe(build, streams[bi_])
+                            pr = k.probe(build, streams[bi_])
                             probes[bi_] = pr
                             sizes_all.append(jax.device_get(
-                                self._totals(build, streams[bi_], *pr)))
+                                k.totals(build, streams[bi_], *pr)))
                         if cache is not None:
                             cache[key] = {
                                 "dense_ok": all_ok, "n": len(streams),
@@ -629,7 +747,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                         # free consumed inputs as the loop advances: with
                         # many large stream batches, holding every batch +
                         # probe triple for the whole emission loop would
-                        # grow peak HBM from O(batch) to O(partition)
+                        # grow peak HBM from O(batch) to O(round)
                         streams[bi_] = probes[bi_] = None
                         sizes = [int(x) for x in sizes_d]
                         total = sizes[0]
@@ -656,7 +774,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                             caps_used.append((out_cap, s_caps, b_caps))
                         emitted = True
                         expand_rows.add(out_cap)
-                        expanded = self._expand(build, stream, counts,
+                        expanded = k.expand(build, stream, counts,
                                                 bstart, bperm, out_cap,
                                                 s_caps, b_caps)
                         from spark_rapids_tpu.memory.device import (
@@ -666,6 +784,21 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                         if dm is not None:
                             dm.meter_batch(expanded)
                         yield expanded
+
+                # a stream past the collapse bound comes as pieces, taken
+                # a round at a time (_rounds); a full join's matched flags
+                # are ORed across every round
+                if jt in ("leftsemi", "leftanti") and not dplan:
+                    for stream in stream_src:
+                        emitted = True
+                        count_streams([stream])
+                        yield self._semi(stream,
+                                         self._probe(build, stream)[0])
+                else:
+                    emit = (semi_round if jt in ("leftsemi", "leftanti")
+                            else expand_round)
+                    for r, streams in enumerate(_rounds(stream_src, s_row)):
+                        yield from emit(streams, round_key(r))
                 if jt == "full":
                     if matched_acc is None:
                         matched_acc = jnp.zeros((build.capacity,), jnp.bool_)

@@ -186,6 +186,9 @@ def test_claimed_filter_gives_the_mask_forms_rows(case, kind, session, rng,
     if kind == "exchange":
         assert [s["compacted"] for s in spans] == [compacted]
         assert spans[0]["batches"] == 3
+        # under its bound the collapse is one piece
+        assert [s["pieces"] for s in spans] == [1]
+        assert spans[0]["bound_bytes"] > 0
     # the mask form of the same plan over the same batches
     monkeypatch.setattr(rowops, "sort_compactable", lambda cols: False)
     want, masked, _, _ = _run(
@@ -358,3 +361,52 @@ def test_a_dictionary_on_a_fixed_width_column_keeps_the_gather(rng):
     keep = jnp.asarray(rng.random(batch.capacity) < 0.5)
     _same_rows(rowops.filter_batch(batch, keep).to_pandas(),
                _gather_form(batch, keep).to_pandas())
+
+
+@pytest.mark.parametrize("case", ["two_columns", "five_columns",
+                                  "mixed_representations"])
+def test_a_claimed_filter_compacts_each_piece(case, session, rng,
+                                              monkeypatch):
+    """Where the bound cuts the collapse (exec/tpu._collapse_bound_bytes),
+    the claimed filter runs on every batch as before and each piece is its
+    group's concat, compacted or masked: the pieces hold the uncut
+    collapse's rows in its order, and the counters add up the same."""
+    from spark_rapids_tpu.exec import tpu as tpuexec
+    columns, hows, sel, compacted = _CASES[case]
+    frames = _frames(rng, columns, (40, 0, 25, 30))
+    hows = (hows,) * 4 if isinstance(hows, str) else hows + ("codes",)
+    cond = (F.col("k") > -10) & (F.col("k") < 40)
+
+    def run():
+        plan = _plan("exchange", _Source(frames, hows), cond, sel)
+        before = (REGISTRY.value(_BATCHES), REGISTRY.value(_COMPACTED),
+                  REGISTRY.value("exchange.collapse.pieces"))
+        TRACER.clear()
+        TRACER.configure(True)
+        try:
+            out = [b for p in plan.partitions(ExecContext(session.conf,
+                                                          session))
+                   for b in p()]
+        finally:
+            TRACER.configure(False)
+        spans = [e["args"] for e in TRACER.events()
+                 if e["name"] == "exchange.collapse"]
+        grown = (REGISTRY.value(_BATCHES) - before[0],
+                 REGISTRY.value(_COMPACTED) - before[1],
+                 REGISTRY.value("exchange.collapse.pieces") - before[2])
+        return pd.concat([b.to_pandas() for b in out],
+                         ignore_index=True), spans, grown
+
+    whole, spans, grown = run()
+    assert [s["pieces"] for s in spans] == [1] and grown[2] == 1
+    # a bound of 64 slots: every batch of rows is a piece of its own (the
+    # empty one rides with its neighbour)
+    schema = _plan("exchange", _Source(frames, hows), cond,
+                   sel).output_schema()
+    monkeypatch.setattr(tpuexec, "_collapse_bound_bytes",
+                        lambda: 64 * tpuexec._row_bytes(schema))
+    cut, spans, cut_grown = run()
+    assert [s["pieces"] for s in spans] == [1, 2, 3]
+    assert sum(s["batches"] for s in spans) == 4
+    assert cut_grown == (grown[0], grown[1], 3)
+    _same_rows(cut, whole)

@@ -391,6 +391,44 @@ def test_an_exchange_drain_precedes_its_collapse(traced_events):
     assert drain["args"]["batches"] == collapse["args"]["batches"] >= 1
     assert drain["args"]["claimed"] is False
     assert drain["args"]["compacted"] == 0
+    # under its bound (a quarter of the metered HBM budget) the collapse
+    # is the one piece
+    assert collapse["args"]["pieces"] == 1
+    assert collapse["args"]["bound_bytes"] > 0
+
+
+@pytest.mark.parametrize("scale,form", [(1, "dense"), (1 << 28, "sort")])
+def test_a_join_probe_says_its_form_and_counts_its_sort_rows(
+        session, rng, scale, form):
+    """``dispatch.join`` of a probe carries ``form``; ``join.probe.sortRows``
+    grows by the stream rows the sort probe took (as the host knows them)
+    and by 0 where the dense table took them; every collapse adds one
+    ``exchange.collapse.pieces``."""
+    from spark_rapids_tpu.obs.metrics import REGISTRY
+    n = 2000
+    left = pd.DataFrame({"k": rng.integers(0, 500, n) * scale,
+                         "v": rng.random(n)})
+    right = pd.DataFrame({"k2": np.arange(500, dtype=np.int64) * scale,
+                          "w": rng.random(500)})
+    session.set_conf("spark.rapids.sql.enabled", True)
+    session.set_conf("spark.rapids.sql.autoBroadcastJoinThreshold", "-1")
+    session.set_conf("spark.rapids.tpu.trace.enabled", True)
+    names = ("join.probe.sortRows", "exchange.collapse.pieces")
+    before = [REGISTRY.value(m) for m in names]
+    out = (session.create_dataframe(left, 2)
+           .join(session.create_dataframe(right, 1), left_on=["k"],
+                 right_on=["k2"]).collect())
+    assert len(out) == n
+    events = TRACER.events()
+    probes = [e["args"] for e in _spans(events, "dispatch.join")
+              if "form" in e["args"]]
+    assert probes and {a["form"] for a in probes} == {form}
+    assert {a["type"] for a in probes} == {"inner"}
+    sort_rows, pieces = (REGISTRY.value(m) - b
+                         for m, b in zip(names, before))
+    # the stream is one collapse of two batches: its capacity
+    assert sort_rows == (0 if form == "dense" else 2048)
+    assert pieces == len(_spans(events, "exchange.collapse")) == 2
 
 
 def test_children_cover_a_scan_pull_of_a_full_batch(session, tmp_path, rng):
