@@ -41,6 +41,9 @@ _COND_EXTENT_ROWS = REGISTRY.counter("join.cond.extentRows")
 # stream rows the sort probe took (rows the host knows without a sync); a
 # join that probes the dense table adds 0, so the count is never missing
 _SORT_ROWS = REGISTRY.counter("join.probe.sortRows")
+# stream rows the compact dense table probed, counted the same way; a join
+# that sets up a probe adds 0 where it takes another form
+_COMPACT_ROWS = REGISTRY.counter("join.probe.compactRows")
 
 
 def _rounds(batches, row_bytes: int):
@@ -424,8 +427,15 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
     # and a stream row one 12 ns gather, where the union sort costs 122 ns
     # a slot of build and stream alike (PERF.md, PR 28): the table wins
     # under any batch a scan hands over, so the cap is what the table may
-    # hold of HBM: 2 GB (s32 counts and starts, and their stack).
-    _DENSE_MAX_RANGE = 1 << 27
+    # hold of HBM: its bytes a slot times its slots stay within 2 GB. The
+    # packed table (s32 counts and starts, and their stack) takes 16 bytes
+    # a slot, so 2^27 slots; past that the compact one (one s32 prefix sum
+    # and the counts it is summed from) takes 8, so 2^28. The packed row
+    # gather reads a stream row in 12-20 ns at 2^26 slots, the compact
+    # pair in 30-45 (PERF.md, section 6): a table that fits packed stays so.
+    _DENSE_TABLE_BYTES = 2 << 30
+    _PACKED_SLOT_BYTES = 16
+    _COMPACT_SLOT_BYTES = 8
 
     def _dense_plan(self, ctx, build_schema, k=None):
         """(lo, table_size) when the dense path applies to orientation
@@ -450,21 +460,36 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
             return None
         lo, hi = got
         rng = hi - lo + 1
-        if rng <= 0 or rng > self._DENSE_MAX_RANGE:
+        if rng <= 0:
             return None
         table_size = 1024
         while table_size < rng:
             table_size <<= 1
-        return lo, bucket_dim(table_size)
+        table_size = bucket_dim(table_size)
+        if table_size * self._COMPACT_SLOT_BYTES > self._DENSE_TABLE_BYTES:
+            return None
+        return lo, table_size
 
     def _dense_kernel(self, k, table_size: int):
+        """The dense probe of a ``table_size``-slot table: packed where it
+        fits the table's HBM, else compact (ops/joins.join_probe_dense)."""
         bk, sk = k.bkey[0], k.skey[0]
-        return cached_jit(
-            f"{k.sig}|dense{table_size}",
+        compact = (table_size * self._PACKED_SLOT_BYTES
+                   > self._DENSE_TABLE_BYTES)
+        kern = cached_jit(
+            f"{k.sig}|dense{table_size}" + ("|compact" if compact else ""),
             lambda: jax.jit(
                 lambda b, s, lo: join_ops.join_probe_dense(
-                    b, s, bk, sk, lo, table_size)),
-            lambda *_a: {"type": self.join_type, "form": "dense"})
+                    b, s, bk, sk, lo, table_size, compact)),
+            lambda *_a: {"type": self.join_type, "form": "dense",
+                         "slots": table_size})
+        if not compact:
+            return kern
+
+        def compact_probe(build, stream, lo):
+            _COMPACT_ROWS.add(stream.num_rows_hint())
+            return kern(build, stream, lo)
+        return compact_probe
 
     def partitions(self, ctx: ExecContext) -> List[Partition]:
         si, bi = self._sides()
@@ -620,6 +645,7 @@ class TpuShuffledHashJoinExec(PhysicalPlan):
                 _INPUT_BYTES.add(build.num_rows_hint() * b_row)
                 matched_acc = None
                 emitted = False
+                _COMPACT_ROWS.add(0)
                 if dplan:
                     lo_arr = jnp.asarray(dplan[0], jnp.int64)
                     dkern = self._dense_kernel(k, dplan[1])

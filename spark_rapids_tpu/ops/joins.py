@@ -273,9 +273,47 @@ def _dense_offsets(build: DeviceBatch, build_key: int, bkv, lo, table_size):
     return off_key, ok
 
 
+def _packed_table(off_sorted, table_size: int):
+    """The (start, count) of every table offset as one (table_size, 2)
+    s32 table: 16 bytes a slot while it is built, and a stream row reads
+    its pair with one row gather."""
+    cnt = jnp.zeros((table_size + 1,), jnp.int32).at[off_sorted].add(1)[
+        :table_size]
+    starts = jnp.cumsum(cnt) - cnt
+    tbl = jnp.stack([starts, cnt], axis=1)
+
+    def lookup(sidx):
+        picked = tbl[sidx, :]
+        return picked[:, 0].astype(jnp.int32), picked[:, 1]
+    return lookup
+
+
+def _compact_table(off_sorted, table_size: int):
+    """The (start, count) of every table offset from one exclusive prefix
+    sum ``cum`` of table_size + 1 s32 entries: ``cum[o]`` is the first
+    ``bperm`` slot of offset ``o`` and ``cum[o + 1] - cum[o]`` its count,
+    so 4 bytes a slot, and 8 while the sum is taken. A stream row reads
+    entries ``o`` and ``o + 1`` with one gather of a 2-entry window, which
+    costs what two gathers do on a v5e (PERF.md, section 6)."""
+    # each offset counted one entry up, so the sum is exclusive; a NULL
+    # or out-of-table row (offset table_size) lands past the end, dropped
+    cum = jnp.cumsum(jnp.zeros((table_size + 1,), jnp.int32).at[
+        off_sorted + 1].add(1, mode="drop"))
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,))
+
+    def lookup(sidx):
+        # sidx < table_size, so the window [sidx, sidx + 1] is in bounds
+        pair = jax.lax.gather(
+            cum, sidx[:, None], dnums, slice_sizes=(2,),
+            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+        return pair[:, 0], pair[:, 1] - pair[:, 0]
+    return lookup
+
+
 def join_probe_dense(build: DeviceBatch, stream: DeviceBatch,
                      build_key: int, stream_key: int, lo_arr: jnp.ndarray,
-                     table_size: int):
+                     table_size: int, compact: bool = False):
     """Dense-key direct-index probe: the sort-free fast path for the
     PK-FK joins that dominate analytic schemas (every TPC-H/TPCxBB equi
     join is on dense contiguous int keys).
@@ -298,7 +336,10 @@ def join_probe_dense(build: DeviceBatch, stream: DeviceBatch,
     back to the exact sort probe when verification fails. Out-of-range
     STREAM keys need no verification: when ok holds, every build key is
     in-table, so an out-of-range stream key matching nothing is correct
-    SQL semantics, not data loss."""
+    SQL semantics, not data loss. ``compact``: the table is one prefix
+    sum, 8 bytes a slot while built (_compact_table), instead of the
+    packed [start, count] rows, 16 (_packed_table): a table twice as
+    large in the same HBM, at a slower read."""
     nb, ns = build.capacity, stream.capacity
     bkv = _key_valid(build, [build_key])
     skv = _key_valid(stream, [stream_key])
@@ -309,16 +350,13 @@ def join_probe_dense(build: DeviceBatch, stream: DeviceBatch,
         is_stable=True)
     # scatter-add over SORTED offsets (random-index scatters serialize on
     # TPU; the build-side sort just above makes this one cheap)
-    cnt = jnp.zeros((table_size + 1,), jnp.int32).at[off_sorted].add(1)[
-        :table_size]
-    starts = jnp.cumsum(cnt) - cnt
-    tbl = jnp.stack([starts, cnt], axis=1)
+    lookup = (_compact_table if compact else _packed_table)(off_sorted,
+                                                            table_size)
     soff = stream.columns[stream_key].data.astype(jnp.int64) - lo
     s_in = skv & (soff >= 0) & (soff < table_size)
     sidx = jnp.clip(soff, 0, table_size - 1).astype(jnp.int32)
-    picked = tbl[sidx, :]
-    bstart = picked[:, 0].astype(jnp.int32)
-    counts = jnp.where(s_in, picked[:, 1], 0).astype(jnp.int32)
+    bstart, cnt = lookup(sidx)
+    counts = jnp.where(s_in, cnt, 0).astype(jnp.int32)
     return counts, bstart, bperm, ok
 
 

@@ -144,6 +144,48 @@ def test_an_inner_join_streams_a_build_past_the_bound(big, session, rng,
     assert sort_rows.value - before == 4 * 1024
 
 
+@pytest.mark.parametrize("top,form", [
+    (1023, "packed"), (2047, "compact"), (2048, "sort")])
+def test_a_swapped_join_takes_the_table_its_key_range_fits(
+        top, form, session, rng, bound, monkeypatch):
+    """With the dense table's HBM cut to 16 KiB, a build key range of
+    1024 fits the packed table (16 bytes a slot), one of 2048 only the
+    compact one (8 bytes a slot), and one of 2049 neither: the sort
+    probe. Each gives the oracle's rows; the stream's pieces count where
+    their probe took them."""
+    monkeypatch.setattr(TpuShuffledHashJoinExec, "_DENSE_TABLE_BYTES",
+                        2048 * 8)
+    # the bounds of this test's scans alone (the session's registry
+    # unions every scan of a column name it has seen)
+    monkeypatch.setattr(session, "column_stats", {})
+    monkeypatch.setattr(session, "column_aliases", {})
+    left, right = _sides(rng, "right", scale=top // 200)
+    # the build (the left side, swapped in) spans exactly 0..top
+    left.loc[0, "k"], left.loc[1, "k"] = 0, top
+    bound(_bound_of(2, _row_bytes(Schema.from_pandas(left))))
+    names = ("join.probe.compactRows", "join.probe.sortRows")
+    before = [REGISTRY.value(n) for n in names]
+    session.set_conf("spark.rapids.tpu.trace.enabled", True)
+
+    def q(s):
+        return s.create_dataframe(left, 1).join(
+            s.create_dataframe(right, BIG_PARTS), left_on=["k"],
+            right_on=["k2"], how="inner")
+    assert_tpu_and_cpu_equal(q, conf=NO_BROADCAST, ignore_order=True)
+    (join,) = _joins(session)
+    assert join._swap is not None
+    compact_rows, sort_rows = (REGISTRY.value(n) - b
+                               for n, b in zip(names, before))
+    # the stream's rows as the host knows them: 4 pieces of 1024 slots
+    assert compact_rows == (4 * 1024 if form == "compact" else 0)
+    assert sort_rows == (4 * 1024 if form == "sort" else 0)
+    probes = {(e["args"]["form"], e["args"].get("slots"))
+              for e in TRACER.events() if e["name"] == "dispatch.join"
+              and "form" in e["args"]}
+    assert probes == {{"packed": ("dense", 1024), "compact": ("dense", 2048),
+                       "sort": ("sort", None)}[form]}
+
+
 def test_a_collapse_within_its_bound_is_the_one_concat(session, rng,
                                                        monkeypatch):
     """Under the bound the drain takes every batch and one _collapse_concat

@@ -354,7 +354,9 @@ def test_inner_join_with_a_build_side_far_wider_than_its_stream(
     (1 << 20, True),
     ((1 << 24) + 5, True),      # above the cap that was: Q18's o_orderkey
     (1 << 27, True),
-    ((1 << 27) + 1, False)])    # a table of more than 2 GB
+    ((1 << 27) + 1, True),      # past the packed table: the compact one
+    (1 << 28, True),
+    ((1 << 28) + 1, False)])    # a table of more than 2 GB, even compact
 def test_dense_probe_takes_a_key_range_up_to_its_cap(session, top_key,
                                                      dense):
     from spark_rapids_tpu.columnar.batch import Schema

@@ -102,7 +102,7 @@ def _check_join(bk, bv, sk, sv):
     _check_probe(got, len(bk), len(sk), groups, ocounts, sk)
 
 
-def _check_join_dense(bk, bv, sk, sv):
+def _check_join_dense(bk, bv, sk, sv, compact):
     import jax.numpy as jnp
     from spark_rapids_tpu.ops.joins import join_probe_dense
     groups, ocounts = _join_oracle(bk, bv, sk, sv)
@@ -111,7 +111,7 @@ def _check_join_dense(bk, bv, sk, sv):
     assert int(live.max()) - lo < table_size
     *got, ok = join_probe_dense(
         _key_batch([bk], bv), _key_batch([sk], sv), 0, 0,
-        jnp.asarray(lo, jnp.int64), table_size)
+        jnp.asarray(lo, jnp.int64), table_size, compact)
     assert bool(ok)
     _check_probe(got, len(bk), len(sk), groups, ocounts, sk)
 
@@ -191,32 +191,40 @@ def test_join_probe_typed_key_images(np_dtype, rng):
     _check_join(vals, rng.random(nb) < 0.9, svals, rng.random(ns) < 0.9)
 
 
-def test_join_probe_dense_matches_oracle(rng):
-    _check_join_dense(*_random_keys(rng))
-
-
-def test_join_probe_dense_skewed_single_key():
-    _check_join_dense(*_skewed_keys())
-
-
-def test_join_probe_dense_all_null_and_empty(rng):
-    for case in _null_and_empty_keys(rng):
-        _check_join_dense(*case)
-
-
-def test_join_probe_dense_int64_far_from_zero(rng):
-    """int64 keys beyond int32: the table is indexed by key - lo."""
+def _dense_case(case, rng):
+    """(build keys, build validity, stream keys, stream validity)."""
+    if case == "random":          # some stream keys outside the table
+        return _random_keys(rng)
+    if case == "duplicate_build_keys":   # an FK-side build: one big run
+        return _skewed_keys()
+    if case in ("all_null_build", "all_null_stream"):
+        return _null_and_empty_keys(rng)[case == "all_null_stream"]
+    if case == "empty_build":
+        _bk, _bv, sk, sv = _random_keys(rng)
+        return np.zeros(0, np.int64), np.zeros(0, bool), sk, sv
+    # int64 keys beyond int32: the table is indexed by key - lo
     bk, bv, sk, sv = _random_keys(rng)
-    _check_join_dense(bk + (1 << 40), bv, sk + (1 << 40), sv)
+    return bk + (1 << 40), bv, sk + (1 << 40), sv
 
 
-def test_join_probe_dense_reports_a_build_key_outside_the_table(rng):
+@pytest.mark.parametrize("compact", [False, True],
+                         ids=["packed", "compact"])
+@pytest.mark.parametrize("case", [
+    "random", "duplicate_build_keys", "all_null_build", "all_null_stream",
+    "empty_build", "int64_far_from_zero", "build_key_outside_the_table"])
+def test_join_probe_dense_matches_oracle(case, compact, rng):
+    """Both forms of the dense table (the packed [start, count] rows and
+    the compact prefix sum) give the sort probe's contract; a valid build
+    key outside the table reports ``ok`` False."""
+    if case != "build_key_outside_the_table":
+        _check_join_dense(*_dense_case(case, rng), compact)
+        return
     import jax.numpy as jnp
     from spark_rapids_tpu.ops.joins import join_probe_dense
     bk, bv, sk, sv = _random_keys(rng)
     bk[0], bv[0] = 5000, True
     ok = join_probe_dense(_key_batch([bk], bv), _key_batch([sk], sv), 0, 0,
-                          jnp.asarray(-10, jnp.int64), 1024)[3]
+                          jnp.asarray(-10, jnp.int64), 1024, compact)[3]
     assert not bool(ok)
 
 

@@ -399,12 +399,18 @@ def test_an_exchange_drain_precedes_its_collapse(traced_events):
 
 @pytest.mark.parametrize("scale,form", [(1, "dense"), (1 << 28, "sort")])
 def test_a_join_probe_says_its_form_and_counts_its_sort_rows(
-        session, rng, scale, form):
-    """``dispatch.join`` of a probe carries ``form``; ``join.probe.sortRows``
-    grows by the stream rows the sort probe took (as the host knows them)
-    and by 0 where the dense table took them; every collapse adds one
+        session, rng, scale, form, monkeypatch):
+    """``dispatch.join`` of a probe carries ``form`` (and, dense, the
+    table's ``slots``); ``join.probe.sortRows`` grows by the stream rows
+    the sort probe took (as the host knows them) and by 0 where the dense
+    table took them; ``join.probe.compactRows`` by 0 in either, as
+    neither probed a compact table; every collapse adds one
     ``exchange.collapse.pieces``."""
     from spark_rapids_tpu.obs.metrics import REGISTRY
+    # the bounds of this test's scans alone (the session's registry
+    # unions every scan of a column name it has seen)
+    monkeypatch.setattr(session, "column_stats", {})
+    monkeypatch.setattr(session, "column_aliases", {})
     n = 2000
     left = pd.DataFrame({"k": rng.integers(0, 500, n) * scale,
                          "v": rng.random(n)})
@@ -413,7 +419,8 @@ def test_a_join_probe_says_its_form_and_counts_its_sort_rows(
     session.set_conf("spark.rapids.sql.enabled", True)
     session.set_conf("spark.rapids.sql.autoBroadcastJoinThreshold", "-1")
     session.set_conf("spark.rapids.tpu.trace.enabled", True)
-    names = ("join.probe.sortRows", "exchange.collapse.pieces")
+    names = ("join.probe.sortRows", "exchange.collapse.pieces",
+             "join.probe.compactRows")
     before = [REGISTRY.value(m) for m in names]
     out = (session.create_dataframe(left, 2)
            .join(session.create_dataframe(right, 1), left_on=["k"],
@@ -424,10 +431,16 @@ def test_a_join_probe_says_its_form_and_counts_its_sort_rows(
               if "form" in e["args"]]
     assert probes and {a["form"] for a in probes} == {form}
     assert {a["type"] for a in probes} == {"inner"}
-    sort_rows, pieces = (REGISTRY.value(m) - b
-                         for m, b in zip(names, before))
+    # keys 0..499: the smallest table, 1024 slots
+    assert {a.get("slots") for a in probes} \
+        == {1024 if form == "dense" else None}
+    sort_rows, pieces, compact_rows = (REGISTRY.value(m) - b
+                                       for m, b in zip(names, before))
     # the stream is one collapse of two batches: its capacity
     assert sort_rows == (0 if form == "dense" else 2048)
+    assert compact_rows == 0
+    assert any(m.name == "join.probe.compactRows"
+               for m in REGISTRY.metrics())
     assert pieces == len(_spans(events, "exchange.collapse")) == 2
 
 
